@@ -1,6 +1,8 @@
 """Benchmark entry point — one section per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows.
+Prints ``name,us_per_call,derived`` CSV rows.  A section that raises
+prints an ``<name>_ERROR`` row, the other sections still run, and the
+command exits non-zero.
 
     PYTHONPATH=src python -m benchmarks.run [--only table4 ...]
 """
@@ -14,7 +16,7 @@ from . import (breakdown, convergence, flops_byte, kernels_bench,
 
 SECTIONS = {
     "table1": flops_byte.run,       # Flops/Byte characterization
-    "table4": throughput.run,       # tokens/sec (+ v5e projection)
+    "table4": throughput.run,       # tokens/sec
     "fig8": convergence.run,        # LL vs iterations
     "fig9": scaling.run,            # multi-device scaling
     "table5": breakdown.run,        # time breakdown
@@ -24,20 +26,25 @@ SECTIONS = {
 }
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", nargs="*", default=None,
                     choices=sorted(SECTIONS))
     args = ap.parse_args()
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in SECTIONS.items():
         if args.only and name not in args.only:
             continue
         try:
             fn()
-        except Exception as e:  # noqa: BLE001 — keep the harness running
+        except Exception as e:  # noqa: BLE001 — report, run the rest, fail
             print(f"{name}_ERROR,0,{type(e).__name__}: {e}")
+            failed.append(name)
+    if failed:
+        print(f"failed sections: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

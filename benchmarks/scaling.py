@@ -1,6 +1,8 @@
 """Paper Fig 9: multi-device scaling (1/2/4/8 host devices, 1D partition)
 plus the beyond-paper 2D partition at 4x2.  Subprocess per device count
-(jax fixes the device count at init)."""
+(jax fixes the device count at init), so this runs on forced CPU host
+devices only: on a TPU backend the parent already holds the chip and the
+children could not reach it, so it refuses."""
 import json
 import os
 import subprocess
@@ -44,6 +46,12 @@ def _run(devices, mode, shape):
 
 
 def run():
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "fig9 scaling starts one child process per device count, and "
+            "this process already holds the TPU; run it with "
+            "JAX_PLATFORMS=cpu (forced host devices)")
     base = None
     for g in (1, 2, 4, 8):
         r = _run(g, "1d", [g])

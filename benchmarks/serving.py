@@ -55,7 +55,7 @@ import numpy as np
 
 from .common import emit, paired_overhead_pct, timeit, write_bench_json
 
-IMPLS = ("xla", "pallas", "ref")
+IMPLS = ("xla", "pallas")
 
 _ROWS: list | None = None   # row recorder for --json
 

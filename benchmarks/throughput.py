@@ -5,9 +5,7 @@ paper improves on), ``sq`` (the sparsity-aware S/Q sampler as an XLA scan)
 and ``pallas`` (the fused ``repro.kernels.lda_sample`` sweep; off-TPU it
 times the *interpreter*, validating the path end to end — the on-chip win
 is a hardware number).  Timings are of the AOT-compiled iteration only
-(compile time never pollutes a row; see ``repro.train.fit``), plus the
-TPU-v5e projected tokens/sec from the compiled HLO bytes (LDA is memory
-bound, so tokens/sec ~ HBM_BW / bytes-per-token).
+(compile time never pollutes a row; see ``repro.train.fit``).
 
 The sweep ends with an ``obs_overhead_training`` row — the measured
 observer effect of the ``repro.obs`` instrumentation on the training loop:
@@ -160,7 +158,6 @@ def run(samplers=SAMPLERS, tiny=False):
     from repro.core import trainer
     from repro.core.corpus import ell_capacity, tile_corpus
     from repro.data.synthetic import zipf_corpus
-    from repro.launch.mesh import HBM_BW
 
     # paper regime: K >> avg doc length (sparsity pays), T/V >~ 100 so the
     # per-word p*/tree work amortizes over that word's tokens.  The pallas
@@ -189,20 +186,6 @@ def run(samplers=SAMPLERS, tiny=False):
               f"tokens_per_sec={tps:.3g};T={corpus.num_tokens}",
               sampler=which, tokens_per_sec=tps, num_tokens=corpus.num_tokens)
 
-        # TPU projection: bytes/token from compiled HLO, memory-bound model
-        # (interpret-mode pallas lowers through callbacks — no cost model)
-        try:
-            ca = compiled.cost_analysis()
-            if isinstance(ca, list):
-                ca = ca[0]
-            bpt = float(ca.get("bytes accessed", 0) or 0) / corpus.num_tokens
-        except Exception:
-            bpt = 0.0
-        if bpt > 0:
-            proj = HBM_BW / bpt
-            _emit(f"table4_v5e_projected_{which}_K{K}", 0.0,
-                  f"bytes_per_token={bpt:.0f};projected_tokens_per_sec={proj:.3g}",
-                  sampler=which, projected_tokens_per_sec=proj)
 
     # mesh-sharded sweep (sq + pallas, overlapped vs serialized sync) —
     # skipped silently on single-device hosts

@@ -531,8 +531,8 @@ def check_partition_contracts() -> list[Finding]:
     """Executed CC001/CC004 over the partition-mode x sampler matrix: build
     DistributedLDA on device-free meshes (1d data=4; 2d data=2 x model=2,
     compressed sync on so the heavy-row int32 path traces too; pallas
-    variants with micro_chunks + sync_overlap so the stacked chunk plans and
-    the per-chunk sync collective trace too), check the spec tables, and
+    variants with micro_chunks + sync_overlap so the per-chunk sync
+    collective traces too), check the spec tables, and
     eval_shape init -> step -> likelihood; any trace failure means a
     collective's axis does not resolve on that mesh."""
     import dataclasses
@@ -550,12 +550,10 @@ def check_partition_contracts() -> list[Finding]:
     corpus = Corpus(doc_ids, word_ids, D, V)
     cfg = core_trainer.LDAConfig(num_topics=8, tile_tokens=16,
                                  compressed_sync=True)
-    # the mesh-sharded fused sweep: stacked per-shard chunk plans ride
-    # through shard_map as data, and the overlapped per-micro-chunk
+    # the mesh-sharded fused sweep: the overlapped per-micro-chunk
     # phi_delta sync replaces the end-of-iteration collective
     cfg_pallas = dataclasses.replace(cfg, sampler="pallas", micro_chunks=2,
-                                     sync_overlap=True,
-                                     tiles_per_step=4)
+                                     sync_overlap=True)
 
     findings: list[Finding] = []
     modes = (
@@ -583,8 +581,7 @@ def check_partition_contracts() -> list[Finding]:
         try:
             key = jax.random.key(0)
             state = jax.eval_shape(dl._init_fn, dl.stacked, key)
-            jax.eval_shape(dl._step_fn, dl.stacked, dl._plans, dl._heavy,
-                           state, key)
+            jax.eval_shape(dl._step_fn, dl.stacked, dl._heavy, state, key)
             jax.eval_shape(dl._ll_fn, dl.stacked, state)
         except Exception as exc:
             findings.append(Finding(

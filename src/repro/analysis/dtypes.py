@@ -13,8 +13,8 @@ evaluated at Table-3 geometry (NYTimes / PubMed sizes from
    (``DECLARED``) whose witness proves the value range fits (DT001);
 *  chained ``astype`` casts that lose width mid-chain are flat errors
    (DT002);
-*  flattened index arithmetic (``b_idx * B + in_b``, tile-index maps,
-   chunk-plan slices) must be declared against a bound witness showing the
+*  flattened index arithmetic (``b_idx * B + in_b``, 128-lane
+   block slices) must be declared against a bound witness showing the
    product stays under 2^31 at full corpus scale (DT003);
 *  count scatters must accumulate in integers — float32 is exact only to
    2^24, far below both corpora's token counts (DT004).
@@ -71,32 +71,22 @@ DECLARED: dict[tuple[str, str, str], str] = {
         "topic-id-fits-dtype",
     ("src/repro/core/dense_sampler.py", "sample_one_tile_dense", "DT001"):
         "topic-id-fits-dtype",
-    ("src/repro/kernels/lda_sample/ops.py", "_lda_sample", "DT001"):
+    ("src/repro/kernels/lda_sample/ops.py", "lda_sample", "DT001"):
         "topic-id-fits-dtype",
     # int16 delta sync: exact below the flux bound, int32 heavy-row path
     # above it — the witness executes both
     ("src/repro/core/sync.py", "compressed_sync_phi", "DT001"):
         "compressed-flux-int32-path",
-    # two-level search flattening: b_idx * B + in_b == k < K
-    ("src/repro/core/sampler.py", "blocked_search", "DT003"):
+    # two-level search flattening: b_idx * B + in_b == k < K, and the
+    # kernels' 128-lane block slices of the K topics / P ELL entries
+    ("src/repro/core/sampler.py", "blocked_draw", "DT003"):
         "index-topic-bound",
-    ("src/repro/kernels/lda_sample/ref.py", "lda_sample_tiles_ref", "DT003"):
+    ("src/repro/kernels/lanes.py", "dense_draw", "DT003"):
         "index-topic-bound",
-    ("src/repro/kernels/lda_sample/kernel.py", "_kernel._sample", "DT003"):
+    ("src/repro/kernels/lanes.py", "search_rows", "DT003"):
         "index-topic-bound",
-    ("src/repro/kernels/fold_in/ref.py", "fold_in_docs_ref.sweep", "DT003"):
+    ("src/repro/kernels/lanes.py", "gather_lanes", "DT003"):
         "index-topic-bound",
-    ("src/repro/kernels/fold_in/kernel.py", "_kernel.sweep", "DT003"):
-        "index-topic-bound",
-    # scalar-prefetch tile index c*C + s and host chunk-plan slices
-    ("src/repro/kernels/lda_sample/kernel.py", "grid_layout.<lambda>",
-     "DT003"): "index-tile-bound",
-    ("src/repro/kernels/lda_sample/ops.py", "build_chunk_plan", "DT003"):
-        "index-tile-bound",
-    # WS2 micro-chunk slices m*nc:(m+1)*nc: max index is the padded tile
-    # count itself, the exact bound _w_index_tile executes
-    ("src/repro/kernels/lda_sample/ops.py", "build_sweep_plans", "DT003"):
-        "index-tile-bound",
 }
 
 
@@ -345,8 +335,8 @@ def _w_compressed_flux() -> list[str]:
     def fixed(d):
         return sync.compressed_sync_phi(d, ("data",), heavy)
 
-    sm = functools.partial(partition.shard_map_compat, mesh=mesh,
-                           in_specs=P(), out_specs=P())
+    sm = functools.partial(jax.shard_map, mesh=mesh, in_specs=P(),
+                           out_specs=P(), check_vma=False)
     wrapped = np.asarray(jax.jit(sm(wrap16))(delta))
     exact = np.asarray(jax.jit(sm(fixed))(delta))
     if wrapped[1, 2] == 40000:
@@ -391,22 +381,6 @@ def _w_index_topic() -> list[str]:
     return probs
 
 
-def _w_index_tile() -> list[str]:
-    """Tile/chunk index arithmetic (c*C + s, chunk-plan slices) stays under
-    2^31 at full Table-3 scale, including worst-case per-word padding."""
-    probs = []
-    for name, mod in _corpora():
-        t = mod.CONFIG.tile_tokens
-        T, V = mod.FULL["num_tokens"], mod.FULL["num_words"]
-        n_tiles = -(-T // t) + V        # one short tile per word, worst case
-        for C in (64, 256):
-            n_pad = n_tiles + (-n_tiles % C)
-            if n_pad * 1 >= 1 << 31 or n_pad * t >= 1 << 62:
-                probs.append(f"{name}: padded tile count {n_pad} (C={C}) "
-                             "overflows the int32 tile index")
-    return probs
-
-
 def _w_count_scatter() -> list[str]:
     """Count accumulators are integer-typed (float32 is exact only to 2^24
     < both corpora's T) and int32 still covers the Table-3 token counts."""
@@ -448,10 +422,8 @@ WITNESSES = (
      "topic-id-fits-dtype", _w_topic_fits),
     ("DT001", "src/repro/core/sync.py", "compressed_sync_phi",
      "compressed-flux-int32-path", _w_compressed_flux),
-    ("DT003", "src/repro/core/sampler.py", "blocked_search",
+    ("DT003", "src/repro/core/sampler.py", "blocked_draw",
      "index-topic-bound", _w_index_topic),
-    ("DT003", "src/repro/kernels/lda_sample/kernel.py", "grid_layout",
-     "index-tile-bound", _w_index_tile),
     ("DT004", "src/repro/core/updates.py", "phi_from_z",
      "count-scatter-int32", _w_count_scatter),
 )

@@ -89,7 +89,7 @@ def _serve_buffer_audit() -> JitAudit:
     phi_sum = jnp.asarray(phi.sum(0, dtype=np.int32))
     hyper = jnp.asarray([0.1, 0.01], jnp.float32)
     buckets = ((2, 12), (3, 12), (2, 20))
-    impls = ("xla", "pallas", "ref")
+    impls = ("xla", "pallas")
 
     def run():
         for B, L in buckets:
@@ -166,23 +166,22 @@ def _train_sweep_audit() -> JitAudit:
                 tile_word, token_doc, token_mask, z, phi, phi_sum,
                 ell_counts, ell_topics, key,
                 alpha=0.5, beta=0.01, num_words_total=V,
-                impl=impl, interpret=True, tiles_per_step=2)
+                impl=impl, interpret=True)
 
     return JitAudit(
         name="train.lda_sample[impl matrix]",
         path="src/repro/kernels/lda_sample/ops.py",
-        cache_size=lda_ops._lda_sample._cache_size, run=run, max_compiles=2)
+        cache_size=lda_ops.lda_sample._cache_size, run=run, max_compiles=2)
 
 
 def _train_sharded_sweep_audit() -> JitAudit:
     """The sharded-sampler matrix: one kernel compile per shard GEOMETRY,
     never per shard index or shard count.  build_shards pads every shard of
-    a partition to a common tile count and the driver pads chunk plans to a
-    common docs-per-chunk width, so running the fused sweep over each shard
-    of 1-, 2- and 4-way partitions must land on at most one compile per
-    distinct (n, t, dpc) signature — a recompile across shard counts here
-    is exactly the cache leak that would multiply mesh compile time by the
-    device count."""
+    a partition to a common tile count, so running the fused sweep over
+    each shard of 1-, 2- and 4-way partitions must land on at most one
+    compile per distinct (n, t, D) signature — a recompile across shard
+    counts here is exactly the cache leak that would multiply mesh compile
+    time by the device count."""
     import jax
     import numpy as np
 
@@ -199,26 +198,20 @@ def _train_sharded_sweep_audit() -> JitAudit:
     shard_counts = (1, 2, 4)
     P = 3
 
-    cases = []   # (shards, plans) per shard count, shared dpc per count
+    cases = []   # the shards of each partition
     geometries = set()
     for S in shard_counts:
         shards, _, _ = partition.build_shards(corpus, S, 1, "1d", t)
-        per_shard = [lda_ops.build_sweep_plans(np.asarray(s.token_doc), 1, 4)
-                     for s in shards]
-        dpc = max(p.chunk_docs.shape[1] for ps in per_shard for p in ps)
-        per_shard = [lda_ops.build_sweep_plans(np.asarray(s.token_doc), 1, 4,
-                                               docs_per_chunk=dpc)
-                     for s in shards]
-        cases.append((shards, per_shard))
+        cases.append(shards)
         d_max = max(s.num_docs_local for s in shards)
-        geometries.add((shards[0].tile_word.shape[0], dpc, d_max))
+        geometries.add((shards[0].tile_word.shape[0], d_max))
 
     def run():
-        for shards, per_shard in cases:
+        for shards in cases:
             d_max = max(s.num_docs_local for s in shards)
             ell_c = np.zeros((d_max, P), np.int32)
             ell_t = np.zeros((d_max, P), np.int32)
-            for s, plans in zip(shards, per_shard):
+            for s in shards:
                 phi = np.ones((s.num_words, K), np.int32)
                 phi_sum = np.full((K,), s.num_words, np.int32)
                 lda_ops.lda_sample(
@@ -226,12 +219,12 @@ def _train_sharded_sweep_audit() -> JitAudit:
                     np.zeros(s.token_doc.shape, np.int32), phi, phi_sum,
                     ell_c, ell_t, key,
                     alpha=0.5, beta=0.01, num_words_total=V,
-                    impl="pallas", interpret=True, plan=plans[0])
+                    impl="pallas", interpret=True)
 
     return JitAudit(
         name="train.lda_sample[sharded geometry matrix]",
         path="src/repro/kernels/lda_sample/ops.py",
-        cache_size=lda_ops._lda_sample._cache_size, run=run,
+        cache_size=lda_ops.lda_sample._cache_size, run=run,
         max_compiles=len(geometries))
 
 

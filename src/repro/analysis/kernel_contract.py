@@ -5,11 +5,14 @@ Each kernel package ships a ``contract.py`` (built on
 the SAME ``grid_layout()`` the production ``pallas_call`` launches from.
 For every case this checker verifies:
 
-- **KC001** — VMEM footprint: sum of declared operand blocks + scratch
-  buffers within the kernel's byte budget.
+- **KC001** — VMEM footprint: declared VMEM operand blocks (double
+  buffered) + VMEM scratch, each padded to the TPU's (8, 128) tiles,
+  within the kernel's byte budget.  SMEM blocks, HBM (``pl.ANY``)
+  operands and semaphores take no VMEM.
 - **KC002** — index-map bounds: every BlockSpec index map, evaluated at
   every grid point (with the case's real scalar-prefetch operands),
-  yields block coordinates whose block lies fully inside the operand.
+  yields block coordinates whose block lies fully inside the operand
+  (a squeezed ``None`` block dim has size 1; HBM operands have no map).
 - **KC003** — grid coverage: for outputs named in ``case.coverage``, the
   set of visited blocks equals the full tiling of the array (no tile of
   the result is left unwritten).
@@ -34,11 +37,26 @@ CONTRACT_MODULES = (
 )
 
 
-def _nbytes(shape, dtype) -> int:
+def _dims(block_shape) -> tuple[int, ...]:
+    return tuple(1 if d is None else int(d) for d in block_shape)
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """Bytes of a VMEM buffer: the last two dims pad to (8, 128) tiles."""
+    dims = list(_dims(shape)) or [1]
+    dims[-1] = -(-dims[-1] // 128) * 128
+    if len(dims) > 1:
+        dims[-2] = -(-dims[-2] // 8) * 8
     n = 1
-    for d in shape:
-        n *= int(d)
+    for d in dims:
+        n *= d
     return n * np.dtype(dtype).itemsize
+
+
+def _in_vmem(spec) -> bool:
+    return (spec.block_shape is not None
+            and str(getattr(spec, "memory_space", None)) not in ("smem",
+                                                                 "any"))
 
 
 def _eval_index_map(spec, coords, scalar_args):
@@ -60,8 +78,10 @@ def check_contract(contract, relpath: str) -> list[Finding]:
         operands = list(case.inputs) + list(case.outputs)
 
         # KC001 — declared VMEM footprint vs budget
-        vmem = sum(_nbytes(op.spec.block_shape, op.dtype) for op in operands)
-        vmem += sum(_nbytes(s.shape, s.dtype) for s in case.scratch)
+        vmem = sum(2 * _vmem_bytes(op.spec.block_shape, op.dtype)
+                   for op in operands if _in_vmem(op.spec))
+        vmem += sum(_vmem_bytes(s.shape, s.dtype) for s in case.scratch
+                    if str(s.dtype) != "dma_sem")
         if vmem > contract.vmem_budget_bytes:
             emit("KC001", scope,
                  f"declared VMEM footprint {vmem} B exceeds the "
@@ -74,10 +94,10 @@ def check_contract(contract, relpath: str) -> list[Finding]:
         reported: set[str] = set()
         for coords in itertools.product(*(range(g) for g in case.grid)):
             for op in operands:
-                if op.label in reported:
+                if op.label in reported or op.spec.block_shape is None:
                     continue
                 idx = _eval_index_map(op.spec, coords, case.scalar_args)
-                bs = op.spec.block_shape
+                bs = _dims(op.spec.block_shape)
                 bad = None
                 if len(idx) != len(bs) or len(bs) != len(op.shape):
                     bad = (f"index map arity {len(idx)} vs block rank "
@@ -100,7 +120,7 @@ def check_contract(contract, relpath: str) -> list[Finding]:
         for op in operands:
             if op.label not in case.coverage or op.label in reported:
                 continue
-            bs = op.spec.block_shape
+            bs = _dims(op.spec.block_shape)
             required = set(itertools.product(
                 *(range(s // b) for s, b in zip(op.shape, bs))))
             missing = required - visited[op.label]

@@ -63,28 +63,59 @@ def pick_search_block(K: int) -> int:
     return SEARCH_BLOCK if K % SEARCH_BLOCK == 0 else _pick_block(K)
 
 
-def blocked_search(pstar: Array, u: Array) -> Array:
-    """C5: draw k ~ multinomial(pstar) via the two-level blocked search.
+def prefix_sum(x: Array, block: int | None = None, roll=jnp.roll) -> Array:
+    """Inclusive prefix sum along the last axis, as log2(n) shifted adds.
 
-    pstar: (K,), u: (t,) uniforms in [0,1).  Returns (t,) int32 topics.
-    Works for any non-negative weight vector, not just p*; the serving path
-    reuses it to draw from theta-weighted distributions.
+    The Hillis-Steele form: step ``d`` adds the element ``d`` lanes back.
+    Only elementwise float adds, so the association is fixed by the
+    algorithm and not by the backend — XLA on CPU or TPU, the Pallas
+    kernels under Mosaic (``roll=pltpu.roll``) and in interpret mode all
+    produce bit-identical sums.  This is the ONE prefix sum of every
+    sampling path; ``jnp.cumsum`` is lowered differently per backend.
+
+    ``block`` restarts the sum every ``block`` lanes (a segmented scan over
+    contiguous blocks of the last axis).
     """
-    K = pstar.shape[0]
+    width = x.shape[-1] if block is None else block
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    if block is not None:
+        lane = lane % block
+    d = 1
+    while d < width:
+        x = x + jnp.where(lane >= d, roll(x, d, x.ndim - 1), 0.0)
+        d *= 2
+    return x
+
+
+def search_tables(pstar: Array) -> tuple[Array, Array, Array]:
+    """C5 level-1 "index tree" of a weight vector (last axis K).
+
+    Returns ``(local, bcum, total)``: the prefix sums inside each B-lane
+    block (..., K), the prefix sums of the block totals (..., nb), and the
+    grand total (...,) — the total is also what the S/Q split's Q uses.
+    Everything is built from ``prefix_sum``, so the Pallas kernels rebuild
+    the same tables bit for bit.
+    """
+    K = pstar.shape[-1]
     B = pick_search_block(K)
     nb = K // B
-    blocks = pstar.reshape(nb, B)
-    bsum = blocks.sum(axis=1)          # level-1 "index tree"
-    bcum = jnp.cumsum(bsum)
-    total = bcum[-1]
-    target = u * total
-    # level-1 search over nb block sums
-    b_idx = jnp.minimum(jnp.sum(bcum[None, :] <= target[:, None], axis=1), nb - 1)
-    prev = jnp.where(b_idx > 0, bcum[b_idx - 1], 0.0)
-    # level-2 search inside the winning block (B lanes)
-    seg = blocks[b_idx]                # (t, B)
-    seg_cum = jnp.cumsum(seg, axis=1) + prev[:, None]
-    in_b = jnp.minimum(jnp.sum(seg_cum <= target[:, None], axis=1), B - 1)
+    local = prefix_sum(pstar, block=B)
+    bsum = local.reshape(*pstar.shape[:-1], nb, B)[..., B - 1]
+    bcum = prefix_sum(bsum)
+    return local, bcum, bcum[..., nb - 1]
+
+
+def blocked_draw(local: Array, bcum: Array, target: Array) -> Array:
+    """C5: two-level search of ``target`` (...,) in one weight vector's
+    ``search_tables`` (``local`` (K,), ``bcum`` (nb,)).  Returns int32
+    topics like ``target``."""
+    K, nb = local.shape[-1], bcum.shape[-1]
+    B = K // nb
+    t = target[..., None]
+    b_idx = jnp.minimum(jnp.sum((bcum <= t).astype(jnp.int32), -1), nb - 1)
+    prev = jnp.where(b_idx > 0, bcum[jnp.maximum(b_idx - 1, 0)], 0.0)
+    seg_cum = local.reshape(nb, B)[b_idx] + prev[..., None]
+    in_b = jnp.minimum(jnp.sum((seg_cum <= t).astype(jnp.int32), -1), B - 1)
     return (b_idx * B + in_b).astype(jnp.int32)
 
 
@@ -97,7 +128,6 @@ def _pick_block(K: int) -> int:
 
 # Back-compat aliases (pre-serve these were module-private).
 _pstar = pstar
-_blocked_search = blocked_search
 
 
 def tile_uniforms(key: Array, t: int) -> Array:
@@ -144,27 +174,28 @@ def sample_one_tile(
     per-token S/(S+Q) sparse mass share, 0 on padding slots).
     """
     pstar = _pstar(phi_col, phi_sum, beta, num_words_total)     # (K,)
-    pstar_total = pstar.sum()
+    local, bcum, pstar_total = search_tables(pstar)             # C5 tree
     Q = alpha * pstar_total                                     # C4, per tile
 
     # --- sparse side: p1 over the ELL rows of each token's doc -------------
     tpc = ell_topics[token_doc]                                 # (t, P)
     cnt = ell_counts[token_doc].astype(jnp.float32)             # (t, P)
     p1 = cnt * pstar[tpc]                                       # (t, P)
-    p1_cum = jnp.cumsum(p1, axis=1)
+    p1_cum = prefix_sum(p1)
     S = p1_cum[:, -1]                                           # (t,)
 
     u1 = uniforms[:, 0]
     u2 = uniforms[:, 1]
     use_sparse = u1 * (S + Q) < S
 
-    # sparse draw: search the P-entry cumsum (P <= K_d bound)
+    # sparse draw: search the P-entry prefix sums (P <= K_d bound)
     t_sparse = u2 * S
-    j = jnp.minimum(jnp.sum(p1_cum <= t_sparse[:, None], axis=1), tpc.shape[1] - 1)
+    j = jnp.minimum(jnp.sum((p1_cum <= t_sparse[:, None]).astype(jnp.int32),
+                            axis=1), tpc.shape[1] - 1)
     k_sparse = jnp.take_along_axis(tpc, j[:, None], axis=1)[:, 0].astype(jnp.int32)
 
     # dense draw: two-level blocked search over p* (C5)
-    k_dense = _blocked_search(pstar, u2)
+    k_dense = blocked_draw(local, bcum, u2 * pstar_total)
 
     z_new = jnp.where(use_sparse, k_sparse, k_dense).astype(z_old.dtype)
     z_new = jnp.where(token_mask, z_new, z_old)

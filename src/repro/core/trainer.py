@@ -24,10 +24,10 @@ Per iteration (delayed-count semantics, exactly the paper's):
 Sampler backends (``LDAConfig.sampler``):
   * ``"sq"``     — the paper's sparsity-aware S/Q sampler as an XLA scan
                    (repro.core.sampler);
-  * ``"pallas"`` — the fused ``repro.kernels.lda_sample`` sweep: phi rows
-                   and the chunk's ELL rows streamed on-chip by scalar-
-                   prefetch index maps, draws bit-identical to ``"sq"``
-                   under the same key; count updates go through the
+  * ``"pallas"`` — the fused ``repro.kernels.lda_sample`` sweep: one word
+                   tile per grid step, its phi row and each token's ELL row
+                   DMA'd on-chip, draws bit-identical to ``"sq"`` under the
+                   same key; count updates go through the
                    ``repro.kernels.phi_update`` MXU kernel;
   * ``"dense"``  — the O(K) baseline.
 """
@@ -39,6 +39,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels import resolve_interpret
 from . import dense_sampler, likelihood, sampler, sync, updates
 from .corpus import Corpus, TiledCorpusShard, ell_capacity
 
@@ -84,10 +85,6 @@ class LDAConfig:
 
     def resolved_alpha(self) -> float:
         return 50.0 / self.num_topics if self.alpha is None else self.alpha
-
-    def kernel_interpret(self) -> bool:
-        """Pallas kernels run compiled on TPU, interpreted elsewhere."""
-        return jax.default_backend() != "tpu"
 
 
 def resolve_config(cfg: LDAConfig, corpus: Corpus) -> LDAConfig:
@@ -164,17 +161,8 @@ def lda_iteration(
     data_axes=None,
     model_axes=None,
     heavy_rows=None,   # (H,) int32 — int32-sync rows under compressed_sync
-    plans=None,        # tuple[ChunkPlan] x micro_chunks — pallas chunk plans
 ) -> tuple[LDAState, IterStats]:
     """One full sweep over this shard's tokens + phi sync.
-
-    ``plans`` carries the pallas sampler's host-built chunk plans.  Left
-    ``None``, they are rebuilt here from ``shard.token_doc`` — which only
-    works when the shard is a trace-time constant (the single-host driver).
-    Traced contexts (``DistributedLDA``'s shard_map) MUST prebuild them with
-    ``ops.build_sweep_plans`` and pass them in as data; the plan arrays feed
-    the kernel's scalar-prefetch index maps, which read runtime values, so
-    traced plans are fine — only their *construction* needs concrete input.
 
     ``cfg.sync_overlap`` (WorkSchedule2 only) moves the phi_delta all-reduce
     inside the micro-chunk loop: each chunk's delta is synced as soon as it
@@ -201,6 +189,7 @@ def lda_iteration(
     M = cfg.micro_chunks
     v_total = shard.num_words_total or shard.num_words
     sweep_kwargs = dict(alpha=alpha, beta=beta, num_words_total=v_total)
+    interpret = resolve_interpret()
 
     if M == 1:  # WorkSchedule1: whole shard resident, one sweep
         if cfg.sampler == "sq":
@@ -218,9 +207,7 @@ def lda_iteration(
                 z_new, stats = lda_kernel.lda_sample(
                     shard.tile_word, shard.token_doc, shard.token_mask,
                     state.z, state.phi_vk, state.phi_sum, ell_c, ell_t, key,
-                    tiles_per_step=min(cfg.tiles_per_step, n),
-                    plan=plans[0] if plans else None,
-                    interpret=cfg.kernel_interpret(), **sweep_kwargs)
+                    impl="pallas", interpret=interpret, **sweep_kwargs)
             sparse_frac = stats.sparse_frac
             mean_ssq = stats.mean_s_over_sq
         else:
@@ -247,17 +234,12 @@ def lda_iteration(
         overlap = cfg.sync_overlap and M > 1
 
         if cfg.sampler == "pallas":
-            # unrolled over the M micro-chunks (M is small and static): each
-            # chunk needs its host-built plan, and unrolling produces the
-            # exact op sequence of the "sq" scan below, so draws stay
-            # bit-identical.  theta (and the ELL re-slice from it) is carried
-            # incrementally — theta_delta, never a rebuild.
+            # unrolled over the M micro-chunks (M is small and static):
+            # unrolling produces the exact op sequence of the "sq" scan
+            # below, so draws stay bit-identical.  theta (and the ELL
+            # re-slice from it) is carried incrementally — theta_delta,
+            # never a rebuild.
             from ..kernels.lda_sample import ops as lda_kernel
-            if plans is None:
-                # host-side tiling (shard.token_doc is a trace-time constant
-                # in the single-host driver; shard_map passes plans in)
-                plans = lda_kernel.build_sweep_plans(
-                    shard.token_doc, M, cfg.tiles_per_step)
             keys_m = jax.random.split(key, M)
             theta_c = theta
             phi_acc = jnp.zeros_like(state.phi_vk) if overlap else None
@@ -269,8 +251,7 @@ def lda_iteration(
                     z_c, st = lda_kernel.lda_sample(
                         tw_a[sl], td_a[sl], tm_a[sl], z_a[sl],
                         state.phi_vk, state.phi_sum, cnts, tpcs, keys_m[m],
-                        plan=plans[m], interpret=cfg.kernel_interpret(),
-                        **sweep_kwargs)
+                        impl="pallas", interpret=interpret, **sweep_kwargs)
                 delta = updates.theta_delta(z_a[sl], z_c, td_a[sl], tm_a[sl],
                                             theta_c.shape[0], K)
                 theta_c = theta_c + sync.sync_theta(delta, model_axes)
@@ -354,7 +335,7 @@ def lda_iteration(
             delta = phi_kernel.phi_delta(
                 shard.tile_word, shard.tile_first, state.z, z_new,
                 shard.token_mask, num_words=shard.num_words, num_topics=K,
-                interpret=cfg.kernel_interpret())
+                impl="pallas", interpret=interpret)
         else:
             delta = updates.phi_delta(state.z, z_new, shard.tile_word,
                                       shard.token_mask, shard.num_words, K)

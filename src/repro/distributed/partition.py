@@ -38,23 +38,6 @@ from repro.core.corpus import (
 Array = jnp.ndarray
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-tolerant ``shard_map``.
-
-    jax >= 0.6 exposes ``jax.shard_map`` (with the ``check_vma`` kwarg); the
-    pinned 0.4.x line only has ``jax.experimental.shard_map.shard_map``,
-    whose equivalent knob is named ``check_rep``.  Every call site in this
-    repo goes through here so the distributed path works on both.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-    return legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=check_vma)
-
-
 # array leaves that travel through shard_map (leading shard axis)
 _CORPUS_FIELDS = ("tile_word", "token_doc", "token_mask", "tile_first",
                   "doc_length", "doc_global", "token_uid")
@@ -367,33 +350,6 @@ class DistributedLDA:
                                              cfg.tile_tokens)
         self.plan = dataclasses.replace(plan, doc_axes=doc_axes, word_axes=word_axes)
         self.stacked = stack_shards(shards, full_dl)
-        # pallas sampler: host-built chunk plans per shard, stacked on the
-        # same leading shard axis and passed through shard_map as *data* —
-        # the plan-as-data trick the serving all2all path uses
-        # (plan_token_routing).  The kernel's scalar-prefetch index maps read
-        # runtime values, so traced plan arrays are fine; only construction
-        # needs a concrete token_doc, which is why it happens here.  All
-        # shards share one static docs-per-chunk width so the stacked arrays
-        # are rectangular and the jit cache stays flat across shard counts.
-        if cfg.sampler == "pallas":
-            from repro.kernels.lda_sample import ops as lda_ops
-            M = max(1, cfg.micro_chunks)
-            per_shard = [lda_ops.build_sweep_plans(
-                np.asarray(s.token_doc), M, cfg.tiles_per_step)
-                for s in shards]
-            dpc = max(p.chunk_docs.shape[1] for ps in per_shard for p in ps)
-            per_shard = [lda_ops.build_sweep_plans(
-                np.asarray(s.token_doc), M, cfg.tiles_per_step,
-                docs_per_chunk=dpc) for s in shards]
-            self._plans = tuple(
-                lda_ops.ChunkPlan(
-                    chunk_docs=jnp.stack([ps[m].chunk_docs
-                                          for ps in per_shard]),
-                    token_slot=jnp.stack([ps[m].token_slot
-                                          for ps in per_shard]))
-                for m in range(M))
-        else:
-            self._plans = ()
         # int32-correction rows for the int16 compressed delta sync (empty
         # (G, 0) when off or when no word reaches the flux bound)
         self._heavy = jnp.asarray(
@@ -445,13 +401,10 @@ class DistributedLDA:
             return core_trainer.state_from_z(cfg_, unpack(c), z, iteration,
                                              data_axes=d_ax, model_axes=m_ax)
 
-        def _step(c, plans, heavy, state, key):
-            local_plans = tuple(
-                type(p)(chunk_docs=p.chunk_docs[0], token_slot=p.token_slot[0])
-                for p in plans) or None
+        def _step(c, heavy, state, key):
             st, stats = core_trainer.lda_iteration(
                 cfg_, unpack(c), state, key, data_axes=d_ax, model_axes=m_ax,
-                heavy_rows=heavy[0], plans=local_plans)
+                heavy_rows=heavy[0])
             stats = core_trainer.IterStats(
                 sparse_frac=jax.lax.pmean(stats.sparse_frac, all_ax),
                 ell_overflow=jax.lax.psum(stats.ell_overflow, all_ax)
@@ -466,14 +419,12 @@ class DistributedLDA:
             return core_trainer.log_likelihood(
                 cfg_, unpack(c), state, data_axes=d_ax, model_axes=m_ax)
 
-        plan_specs = tuple(type(p)(chunk_docs=dev, token_slot=dev)
-                           for p in self._plans)
-        sm = lambda f, ins, outs: jax.jit(shard_map_compat(
+        sm = lambda f, ins, outs: jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=ins, out_specs=outs, check_vma=False))
         self._init_fn = sm(_init, (corpus_specs, repl), state_specs)
         self._rebuild_fn = sm(_rebuild, (corpus_specs, dev, repl), state_specs)
         self._step_fn = sm(_step,
-                           (corpus_specs, plan_specs, dev, state_specs, repl),
+                           (corpus_specs, dev, state_specs, repl),
                            (state_specs, stats_specs))
         self._ll_fn = sm(_ll, (corpus_specs, state_specs), repl)
         self.state_specs = state_specs
@@ -490,8 +441,7 @@ class DistributedLDA:
         if key is None:
             key = jax.random.key(self.cfg.seed + 1)
         with self.mesh:
-            return self._step_fn(self.stacked, self._plans, self._heavy,
-                                 state, key)
+            return self._step_fn(self.stacked, self._heavy, state, key)
 
     def log_likelihood(self, state) -> float:
         with self.mesh:
@@ -613,8 +563,7 @@ class DistributedLDA:
     def lower_step(self):
         key = jax.random.key(0)
         state = jax.eval_shape(self._init_fn, self.stacked, key)
-        return self._step_fn.lower(self.stacked, self._plans, self._heavy,
-                                   state, key)
+        return self._step_fn.lower(self.stacked, self._heavy, state, key)
 
     def compile_step(self):
         """AOT-compile the mesh step; returns ``(step, compile_sec)``.
@@ -631,7 +580,6 @@ class DistributedLDA:
             if key is None:
                 key = jax.random.key(self.cfg.seed + 1)
             with self.mesh:
-                return compiled(self.stacked, self._plans, self._heavy,
-                                state, key)
+                return compiled(self.stacked, self._heavy, state, key)
 
         return step, compile_sec

@@ -1,3 +1,15 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the main path: ``lda_sample`` (the training sweep),
+``phi_update`` (count updates) and ``fold_in`` (serving sweeps)."""
+from __future__ import annotations
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """The one place Pallas interpret mode is decided.
+
+    An explicit flag wins (tests and compile checks pass one).  Otherwise the
+    kernels are compiled by Mosaic on a TPU backend and interpreted on every
+    other backend; the kernel wrappers themselves take no default."""
+    if interpret is not None:
+        return interpret
+    import jax
+    return jax.default_backend() != "tpu"

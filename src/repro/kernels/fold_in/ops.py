@@ -22,6 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.sampler import pstar
+
 from . import kernel, ref
 
 
@@ -74,22 +76,26 @@ def fold_in_sweeps_drawn(
     burn_in: int,
     samples: int,
     ell_capacity: int,
-    impl: str = "pallas",
-    interpret: bool = True,
+    impl: str,
+    interpret: bool,
 ):
     """The sweeps on pre-drawn randomness; returns per-doc partials over the
     kept sweeps: (theta_sum (b, K) int32, sparse_draws (b,) int32,
-    ssq_sum (b,) float32)."""
-    phi_tok = phi_tok.astype(jnp.int32)
-    hyper = jnp.stack([jnp.float32(alpha), jnp.float32(beta)])
-    args = (phi_tok, phi_sum.astype(jnp.int32), hyper,
-            jnp.swapaxes(uniforms, 0, 1),                 # (b, n_sweeps, L, 2)
-            mask.astype(jnp.int32), z0)
-    kw = dict(num_words_total=num_words_total, burn_in=burn_in,
-              samples=samples, ell_capacity=ell_capacity)
+    ssq_sum (b,) float32).  ``impl`` is ``"xla"`` or ``"pallas"``."""
+    uni = jnp.swapaxes(uniforms, 0, 1)                    # (b, n_sweeps, L, 2)
+    kw = dict(burn_in=burn_in, samples=samples, ell_capacity=ell_capacity)
     if impl == "pallas":
-        return kernel.fold_in_docs(*args, interpret=interpret, **kw)
-    return ref.fold_in_docs_ref(*args, **kw)
+        # C7: the per-token p* rows, by the XLA path's own division
+        pstar_tok = pstar(phi_tok, phi_sum, beta, num_words_total)
+        return kernel.fold_in_docs(
+            pstar_tok, jnp.float32(alpha), uni[..., 0], uni[..., 1],
+            mask.astype(jnp.int32), z0, interpret=interpret, **kw)
+    if impl != "xla":
+        raise ValueError(f"unknown fold-in impl {impl!r}: 'xla' | 'pallas'")
+    hyper = jnp.stack([jnp.float32(alpha), jnp.float32(beta)])
+    return ref.fold_in_docs_ref(
+        phi_tok.astype(jnp.int32), phi_sum.astype(jnp.int32), hyper, uni,
+        mask.astype(jnp.int32), z0, num_words_total=num_words_total, **kw)
 
 
 def fold_in_sweeps(
@@ -104,8 +110,8 @@ def fold_in_sweeps(
     burn_in: int,
     samples: int,
     ell_capacity: int,
-    impl: str = "pallas",
-    interpret: bool = True,
+    impl: str,
+    interpret: bool,
 ):
     """Run all fold-in sweeps from a PRNG key; returns the per-doc partials
     of ``fold_in_sweeps_drawn``."""

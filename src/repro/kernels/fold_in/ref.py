@@ -1,20 +1,19 @@
-"""Pure-jnp oracle for the fold-in kernel.
+"""Pure-jnp fold-in sweeps: the kernel's oracle and the XLA serving path.
 
 Batched mirror of ``kernel.py`` with the same decomposed contract (explicit
 z0 + per-sweep uniforms in, per-doc theta-sum / sparse / S-share partials
 out).  Uses ``jax.lax.top_k`` for the ELL slice — the kernel's iterative
-argmax selection must match it bit-for-bit, tie order included — and the
-same blocked-search math as ``repro.core.sampler.blocked_search``, so this
-oracle is also draw-identical to the XLA serving path in
-``repro.serve.infer`` given the same uniforms.
+selection must match it bit-for-bit, tie order included — and the shared
+``repro.core.sampler`` prefix sums and blocked search, so the kernel and
+this code draw identically given the same uniforms.  ``repro.serve.infer``
+runs this as its ``"xla"`` implementation.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.core import updates
-from repro.core.sampler import pick_search_block
+from repro.core import sampler, updates
 
 
 def fold_in_docs_ref(
@@ -32,20 +31,15 @@ def fold_in_docs_ref(
 ):
     nB, L, K = phi_tok.shape
     P = ell_capacity
-    Bb = pick_search_block(K)
-    nb = K // Bb
     alpha, beta = hyper[0], hyper[1]
     maskb = mask != 0                                     # (B, L)
 
-    pstar = (phi_tok.astype(jnp.float32) + beta) / (
-        phi_sum.astype(jnp.float32)[None, None, :]
-        + beta * num_words_total)                         # (B, L, K)
-    Q = alpha * pstar.sum(-1)                             # (B, L)
-
-    blocks = pstar.reshape(nB, L, nb, Bb)
-    bsum = blocks.sum(-1)
-    bcum = jnp.cumsum(bsum, axis=-1)                      # (B, L, nb)
-    total = bcum[..., -1]
+    pstar = sampler.pstar(phi_tok, phi_sum, beta, num_words_total)  # (B,L,K)
+    local, bcum, total = sampler.search_tables(pstar)     # per-token C5 tree
+    Q = alpha * total                                     # (B, L)
+    draw = jax.vmap(sampler.blocked_draw)
+    local_f = local.reshape(nB * L, K)
+    bcum_f = bcum.reshape(nB * L, bcum.shape[-1])
 
     # the training count-rebuild primitive with one "doc" per batch row
     rows = jnp.broadcast_to(jnp.arange(nB, dtype=jnp.int32)[:, None], (nB, L))
@@ -54,32 +48,22 @@ def fold_in_docs_ref(
         return updates.theta_from_z(z, rows, maskb, nB, K)
 
     def sweep(carry, u):
-        z, theta = carry
-        cnt, tpc = jax.lax.top_k(theta, P)                # (B, P)
+        z, theta = carry  # delayed counts: whole sweep vs sweep-start theta
+        cnt, tpc = jax.lax.top_k(theta, P)                # (B, P) ELL slice
         gat = jnp.broadcast_to(tpc[:, None, :], (nB, L, P))
         p1 = cnt[:, None, :].astype(jnp.float32) * jnp.take_along_axis(
             pstar, gat, axis=-1)                          # (B, L, P)
-        p1_cum = jnp.cumsum(p1, axis=-1)
+        p1_cum = sampler.prefix_sum(p1)
         S = p1_cum[..., -1]                               # (B, L)
 
         u1, u2 = u[..., 0], u[..., 1]
         use_sparse = u1 * (S + Q) < S
 
-        j = jnp.minimum((p1_cum <= (u2 * S)[..., None]).sum(-1), P - 1)
+        j = jnp.minimum(jnp.sum((p1_cum <= (u2 * S)[..., None]).astype(
+            jnp.int32), -1), P - 1)
         k_sparse = jnp.take_along_axis(tpc, j, axis=1)
-
-        target = u2 * total
-        b_idx = jnp.minimum((bcum <= target[..., None]).sum(-1), nb - 1)
-        prev = jnp.where(
-            b_idx > 0,
-            jnp.take_along_axis(bcum, jnp.maximum(b_idx - 1, 0)[..., None],
-                                axis=-1)[..., 0],
-            0.0)
-        seg = jnp.take_along_axis(blocks, b_idx[..., None, None],
-                                  axis=2)[:, :, 0]        # (B, L, Bb)
-        seg_cum = jnp.cumsum(seg, axis=-1) + prev[..., None]
-        in_b = jnp.minimum((seg_cum <= target[..., None]).sum(-1), Bb - 1)
-        k_dense = b_idx * Bb + in_b
+        k_dense = draw(local_f, bcum_f, (u2 * total).reshape(-1)).reshape(
+            nB, L)
 
         z_new = jnp.where(use_sparse, k_sparse, k_dense).astype(jnp.int32)
         z_new = jnp.where(maskb, z_new, z)

@@ -1,10 +1,9 @@
 """kernel-contract metadata for the fused training-sweep kernel.
 
 The cases re-derive the launch geometry from ``kernel.grid_layout`` (the
-same call ``lda_sample_tiles`` launches from) over a real host-built chunk
-plan, so the checker exercises the actual scalar-prefetch index maps
-against the actual plan arrays — word-id phi streaming, chunk-doc ELL
-streaming, and the token->slot on-chip gather.
+same call ``lda_sample_tiles`` launches from) and run the kernel's own
+``tile_head`` over real tilings, so the checker sees the SMEM header the
+kernel's DMA loop reads: word id, real-token count and doc ids.
 """
 from __future__ import annotations
 
@@ -12,31 +11,31 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.analysis.contracts import ContractCase, KernelContract, Operand
-from repro.kernels.lda_sample import kernel, ops
+from repro.kernels.lda_sample import kernel
 
-# Declared operand blocks + scratch only (the kernel's internal (C, t, P)
-# sparse-side temporary is the compiler's to place).
-VMEM_BUDGET_BYTES = 2 * 1024 * 1024
+# Declared VMEM blocks + scratch (the two (t, 1, P) per-token ELL tables
+# dominate: 8 MiB at t=256, P=512); the kernel raises Mosaic's scoped limit
+# to ``kernel.VMEM_LIMIT_BYTES`` for its body's temporaries.
+VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 
 def _case(name: str, *, n: int, t: int, V: int, K: int, D: int, P: int,
-          C: int) -> ContractCase:
+          fill: float = 0.6) -> ContractCase:
     token_doc = ((2 * (np.arange(n)[:, None]) + np.arange(t)[None, :] % 4)
                  % D).astype(np.int32)
     tile_word = (np.arange(n, dtype=np.int32) * 7) % V
-    return _build(name, token_doc, tile_word, V=V, K=K, D=D, P=P, C=C)
+    n_real = np.maximum(1, (fill * t * (1 + np.arange(n) % 3) / 3)
+                        ).astype(int)
+    token_mask = np.arange(t)[None, :] < n_real[:, None]
+    return _build(name, token_doc, tile_word, token_mask, V=V, K=K, D=D, P=P)
 
 
-def _shard_case(name: str, *, K: int, P: int, C: int,
+def _shard_case(name: str, *, K: int, P: int,
                 shard_index: int = 1) -> ContractCase:
-    """Shard-local geometry: one shard of a real 2d (doc x word) partition.
-
-    Unlike the synthetic cases, here the scalar-prefetch operands are
-    genuinely sharded — ``tile_word`` holds LPT-local row ids into a padded
-    per-shard vocabulary, ``token_doc`` holds shard-local doc ids over an
-    irregular doc subset, and ``docs_per_chunk`` is padded past this
-    shard's own need (SPMD shards share one static dpc, so every shard's
-    chunk plan must accept the global max)."""
+    """Shard-local geometry: one shard of a real 2d (doc x word) partition —
+    ``tile_word`` holds LPT-local row ids into a padded per-shard
+    vocabulary, ``token_doc`` shard-local doc ids over an irregular doc
+    subset, padding tiles included."""
     from repro.core.corpus import Corpus
     from repro.distributed import partition
 
@@ -47,108 +46,62 @@ def _shard_case(name: str, *, K: int, P: int, C: int,
                                  dtype=np.int32).astype(np.int32),
                     D_glob, V_glob)
     shards, _, _ = partition.build_shards(corpus, 2, 2, "2d", t)
-    shard = shards[shard_index]
-    token_doc = np.asarray(shard.token_doc)
-    probe = ops.build_chunk_plan(token_doc, C)
-    return _build(name, token_doc, np.asarray(shard.tile_word),
-                  V=shard.num_words, K=K, D=shard.num_docs_local, P=P, C=C,
-                  docs_per_chunk=probe.chunk_docs.shape[1] + 3)
-
-
-def _mesh_sweep_case(name: str, *, K: int, P: int, C: int,
-                     micro_chunks: int = 2, num_shards: int = 4,
-                     shard_index: int = 2,
-                     chunk_index: int = 1) -> ContractCase:
-    """One (shard, micro-chunk) slice of the mesh-sharded WS2 sweep.
-
-    This is the geometry ``DistributedLDA`` actually launches with
-    ``sampler="pallas"``: per-shard plans from ``ops.build_sweep_plans``,
-    padded to ONE global docs-per-chunk width across every shard of the
-    partition (SPMD shards must agree on static shapes), sliced per
-    micro-chunk exactly as ``lda_iteration``'s WorkSchedule2 loop slices
-    the tile arrays.  ``_build`` re-derives the plan with the same global
-    dpc, so the executed index-map checks run against the stacked-plan
-    layout bit for bit."""
-    from repro.core.corpus import Corpus
-    from repro.distributed import partition
-
-    rng = np.random.default_rng(11)
-    D_glob, V_glob, per_doc, t = 16, 24, 20, 8
-    corpus = Corpus(np.repeat(np.arange(D_glob, dtype=np.int32), per_doc),
-                    rng.integers(0, V_glob, D_glob * per_doc,
-                                 dtype=np.int32).astype(np.int32),
-                    D_glob, V_glob)
-    shards, _, _ = partition.build_shards(corpus, num_shards, 1, "1d", t)
-    per_shard = [ops.build_sweep_plans(np.asarray(s.token_doc), micro_chunks,
-                                       C) for s in shards]
-    dpc = max(p.chunk_docs.shape[1] for ps in per_shard for p in ps)
-
     s = shards[shard_index]
-    td = np.asarray(s.token_doc)
-    tw = np.asarray(s.tile_word)
-    n, M = td.shape[0], micro_chunks
-    n_pad = -n % M
-    if n_pad:
-        td = np.concatenate([td, np.zeros((n_pad, t), td.dtype)])
-        tw = np.concatenate([tw, np.zeros(n_pad, tw.dtype)])
-    nc = (n + n_pad) // M
-    sl = slice(chunk_index * nc, (chunk_index + 1) * nc)
-    return _build(name, td[sl], tw[sl], V=s.num_words, K=K,
-                  D=s.num_docs_local, P=P, C=min(C, nc),
-                  docs_per_chunk=dpc)
+    return _build(name, np.asarray(s.token_doc), np.asarray(s.tile_word),
+                  np.asarray(s.token_mask), V=s.num_words, K=K,
+                  D=s.num_docs_local, P=P)
 
 
-def _build(name: str, token_doc: np.ndarray, tile_word: np.ndarray, *,
-           V: int, K: int, D: int, P: int, C: int,
-           docs_per_chunk: int | None = None) -> ContractCase:
-    t = token_doc.shape[1]
-    plan = ops.build_chunk_plan(token_doc, C, docs_per_chunk=docs_per_chunk)
-    chunk_docs = np.asarray(plan.chunk_docs)
-    token_slot = np.asarray(plan.token_slot)
-    n = token_slot.shape[0]          # padded tile count (multiple of C)
-    token_doc = np.pad(token_doc,
-                       ((0, n - token_doc.shape[0]), (0, 0)))
-    tile_word = np.pad(tile_word, (0, n - tile_word.shape[0]))
-    n_chunks, dpc = chunk_docs.shape
-    grid, in_specs, out_specs, scratch = kernel.grid_layout(
-        n_chunks, t, K, P, tiles_per_step=C, docs_per_chunk=dpc)
+def _build(name: str, token_doc: np.ndarray, tile_word: np.ndarray,
+           token_mask: np.ndarray, *, V: int, K: int, D: int,
+           P: int) -> ContractCase:
+    n, t = token_doc.shape
+    grid, in_specs, out_specs, scratch = kernel.grid_layout(n, t, K, P)
+    head = np.asarray(kernel.tile_head(tile_word, token_doc, token_mask))
 
-    def plan_round_trip():
-        # the static token->slot map must re-derive token_doc exactly:
-        # chunk_docs[c][token_slot[tile]] == token_doc[tile] for every token
+    def header_round_trip():
+        # the DMA loop reads word, n_real and the first n_real doc ids from
+        # SMEM: they must re-derive the tiling, and real tokens must be
+        # left-packed (slots past n_real are never fetched)
         msgs = []
-        for c in range(n_chunks):
-            tiles = slice(c * C, (c + 1) * C)
-            got = chunk_docs[c][token_slot[tiles]]
-            if not np.array_equal(got, token_doc[tiles]):
-                bad = int(np.argwhere(got != token_doc[tiles])[0][0])
-                msgs.append(
-                    f"chunk {c}: token->slot map does not round-trip to "
-                    f"token_doc (first bad tile row {bad})")
+        n_real = token_mask.sum(1)
+        if not np.array_equal(head[:, 0, 0], tile_word):
+            msgs.append("header word ids do not round-trip to tile_word")
+        if not np.array_equal(head[:, 0, 1], n_real):
+            msgs.append("header token counts != real tokens per tile")
+        if not np.array_equal(head[:, 0, kernel.HEAD:], token_doc):
+            msgs.append("header doc ids do not round-trip to token_doc")
+        packed = np.arange(t)[None, :] < n_real[:, None]
+        if not np.array_equal(packed, token_mask.astype(bool)):
+            bad = int(np.argwhere((packed != token_mask).any(1))[0][0])
+            msgs.append(f"tile {bad}: real tokens are not left-packed")
+        if not ((token_doc[token_mask.astype(bool)] < D).all()
+                and (tile_word < V).all()):
+            msgs.append("a fetched doc or word row lies outside its table")
         return msgs
 
+    row = (n, 1, t)
     in_shapes = [
-        Operand("phi_row", (V, K), jnp.int32, in_specs[0]),
-        Operand("phi_sum", (1, K), jnp.int32, in_specs[1]),
-        Operand("ell_counts", (D, P), jnp.int32, in_specs[2]),
-        Operand("ell_topics", (D, P), jnp.int32, in_specs[3]),
-        Operand("token_slot", (n, t), jnp.int32, in_specs[4]),
-        Operand("uniforms", (n, t, 2), jnp.float32, in_specs[5]),
-        Operand("mask", (n, t), jnp.int32, in_specs[6]),
-        Operand("z_old", (n, t), jnp.int32, in_specs[7]),
+        Operand("head", (n, 1, kernel.HEAD + t), jnp.int32, in_specs[0]),
+        Operand("pstar", (V, 1, K), jnp.float32, in_specs[1]),
+        Operand("ell_counts", (D, 1, P), jnp.int32, in_specs[2]),
+        Operand("ell_topics", (D, 1, P), jnp.int32, in_specs[3]),
+        Operand("u1", row, jnp.float32, in_specs[4]),
+        Operand("u2", row, jnp.float32, in_specs[5]),
+        Operand("mask", row, jnp.int32, in_specs[6]),
+        Operand("z_old", row, jnp.int32, in_specs[7]),
     ]
     out_shapes = [
-        Operand("z_new", (n, t), jnp.int32, out_specs[0]),
-        Operand("sparse", (n, t), jnp.int32, out_specs[1]),
-        Operand("ssq", (n, t), jnp.float32, out_specs[2]),
+        Operand("z_new", row, jnp.int32, out_specs[0]),
+        Operand("sparse", row, jnp.int32, out_specs[1]),
+        Operand("ssq", row, jnp.float32, out_specs[2]),
     ]
     return ContractCase(
         name=name, grid=grid,
         inputs=tuple(in_shapes), outputs=tuple(out_shapes),
-        scalar_args=(tile_word, chunk_docs),
         scratch=tuple(scratch),
         coverage=("z_new", "sparse", "ssq"),
-        extra_checks=(plan_round_trip,))
+        extra_checks=(header_round_trip,))
 
 
 def contract() -> KernelContract:
@@ -156,17 +109,10 @@ def contract() -> KernelContract:
         kernel="lda_sample",
         vmem_budget_bytes=VMEM_BUDGET_BYTES,
         cases=(
-            _case("tiny", n=8, t=16, V=12, K=32, D=6, P=4, C=4),
-            # paper-representative shapes: NYTimes-bucket K with the default
-            # chunking (scratch (C, K) int32 + two (dpc, P) ELL tables)
-            _case("paper", n=128, t=256, V=512, K=1024, D=2048, P=128,
-                  C=64),
+            _case("tiny", n=8, t=16, V=12, K=32, D=6, P=4),
+            # the NYTimes width: K=1024, 256-token tiles, ELL width 512
+            _case("paper", n=128, t=256, V=512, K=1024, D=2048, P=512),
             # one real 2d-partition shard: local vocab rows, irregular doc
-            # subset, dpc padded past this shard's need, n not a multiple
-            # of C before plan padding
-            _shard_case("shard2d", K=48, P=6, C=4),
-            # the mesh-sharded training sweep's geometry: a micro-chunk of a
-            # 1d 4-shard partition under the global docs-per-chunk width the
-            # stacked shard_map plans share
-            _mesh_sweep_case("mesh-sweep", K=32, P=5, C=4),
+            # subset, padding tiles
+            _shard_case("shard2d", K=48, P=6),
         ))

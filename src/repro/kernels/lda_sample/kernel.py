@@ -1,41 +1,33 @@
-"""Pallas TPU kernel: the CuLDA_CGS sampler (paper §6.1), fused training
-sweep — one grid step per *chunk* of word tiles, ELL rows streamed on-chip.
+"""Pallas TPU kernel: the CuLDA_CGS sampler (paper §6.1), one word tile per
+grid step.
 
-GPU -> TPU mapping (DESIGN.md §2):
+GPU -> TPU mapping:
   * thread block sharing one word's p* in shared memory
-        -> phi rows DMA'd into a VMEM scratch table via a **scalar-prefetch
-           index map** (the word id picks the block), one row per inner grid
-           step, then shared by every token of the chunk;
-  * per-token theta/ELL reads from global memory (SaberLDA's sparsity-aware
-    layout / WarpLDA's cache-local accesses)
-        -> a **second scalar-prefetch index map** over the chunk's distinct
-           doc ids streams exactly the ELL rows this chunk touches into a
-           VMEM table; tokens then gather *on-chip* through a static
-           token->slot map.  The HBM-materialized ``ell_counts[token_doc]``
-           ``(n, t, P)`` tensor of the pre-fusion wrapper is gone — HBM
-           traffic is one (1, P) row per distinct (chunk, doc) pair instead
-           of one per token;
+        -> the tile's p* row (computed by XLA for every word) DMA'd from HBM
+           into VMEM, its C5 search tables built once there and shared by
+           every token of the tile;
+  * per-token theta/ELL reads from global memory
+        -> each real token's (1, P) ELL row DMA'd straight from HBM into a
+           per-tile VMEM table (doc ids ride in SMEM), so no per-token
+           ``(n, t, P)`` ELL tensor ever exists in HBM; padding slots of a
+           tile issue no DMA at all;
   * 32 warp-samplers per block
-        -> the whole (tiles_per_step, tile_tokens) token block sampled in
-           lock-step on the VPU;
+        -> the tile's t tokens sampled in lock-step on the VPU;
   * 32-ary shared-memory index tree (C5)
-        -> 128-wide two-level blocked search in VMEM registers, with the
-           block sums for all tiles of the chunk computed once per chunk
-           (multi-tile grid steps keep phi rows, phi_sum and the search
-           state VMEM-resident across the chunk — the fusion discipline the
-           fold_in serving kernel proved out);
+        -> 128-lane two-level blocked search (``sampler.search_tables``);
   * short-int compression (C7)
         -> int16 z widened in-register by the wrapper.
 
-Grid layout: ``(n_chunks, S)`` with ``S = max(tiles_per_step, docs_per_
-chunk)``.  Inner steps assemble the chunk's phi and ELL tables in VMEM
-scratch; the last inner step samples every token of the chunk.  Scratch
-persists across the inner dimension ("arbitrary" semantics), the sampling
-math is bit-identical to ``repro.core.sampler.sample_one_tile``.
+Mosaic lowers no ``cumsum`` and no general gather, so the body uses the
+shared ``sampler.prefix_sum`` (shifted adds via ``pltpu.roll``) and gathers
+by exact selects: p*[topic] is a 128-lane ``take_along_axis`` per 128-topic
+block, a per-token pick is a masked lane sum.  Every float op matches
+``repro.core.sampler.sample_one_tile`` one for one, so draws are
+bit-identical to the ``"sq"`` sweep wherever both run on the same backend.
 
-The kernel is validated in interpret mode on CPU (bit-identical draws vs the
-pure-jnp oracle in ``ref.py`` and vs the XLA sweep) and written against the
-TPU BlockSpec/VMEM model for real hardware.
+Array layout: per-tile rows are ``(n, 1, t)`` and tables ``(rows, 1, width)``
+so that every block or DMA takes whole trailing dims (the TPU's (8, 128)
+tiling forbids one-row slices of a 2-D array).
 """
 from __future__ import annotations
 
@@ -46,203 +38,193 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.sampler import pick_search_block
+from repro.core.sampler import prefix_sum
+from repro.kernels.lanes import (VMEM_LIMIT_BYTES, dense_draw, gather_lanes,
+                                  lane_pick, row_block, search_rows)
+
+HEAD = 128  # SMEM header lanes before a tile's doc ids: [word, n_real, 0...]
 
 
 def _kernel(
-    tile_word_ref,      # scalar prefetch 1: (n,) int32 word id per tile
-    chunk_docs_ref,     # scalar prefetch 2: (n_chunks, dpc) int32 doc ids
-    phi_row_ref,        # (1, K) int32 — tile min(s, C-1)'s word row (VMEM)
-    phi_sum_ref,        # (1, K) int32
-    ell_cnt_row_ref,    # (1, P) int32 — doc-slot min(s, dpc-1)'s ELL counts
-    ell_tpc_row_ref,    # (1, P) int32 — ... and topics
-    token_slot_ref,     # (C, t) int32 — token -> chunk doc-slot (static map)
-    uniforms_ref,       # (C, t, 2) float32
-    mask_ref,           # (C, t) int32
-    z_old_ref,          # (C, t) int32
-    z_new_ref,          # out (C, t) int32
-    sparse_ref,         # out (C, t) int32 — drew from p1?
-    ssq_ref,            # out (C, t) float32 — per-token S/(S+Q), 0 on pads
-    phi_scr,            # VMEM (C, K) int32 — chunk's phi rows
-    ell_cnt_scr,        # VMEM (dpc, P) int32 — chunk's ELL counts
-    ell_tpc_scr,        # VMEM (dpc, P) int32 — chunk's ELL topics
+    head_ref,       # SMEM (1, HEAD + t) int32: [word, n_real, 0.., doc ids]
+    pstar_hbm,      # ANY (V, 1, K) float32 — p*(k) of every word
+    ell_cnt_hbm,    # ANY (D, 1, P) int32
+    ell_tpc_hbm,    # ANY (D, 1, P) int32
+    u1_ref,         # (1, t) float32 — branch uniform
+    u2_ref,         # (1, t) float32 — search uniform
+    mask_ref,       # (1, t) int32
+    z_old_ref,      # (1, t) int32
+    z_new_ref,      # out (1, t) int32
+    sparse_ref,     # out (1, t) int32 — drew from p1?
+    ssq_ref,        # out (1, t) float32 — per-token S/(S+Q), 0 on pads
+    tok_cnt,        # VMEM (t, 1, P) int32 — each token's ELL counts
+    tok_tpc,        # VMEM (t, 1, P) int32 — ... and topics
+    pstar_scr,      # VMEM (1, K) float32 — the tile's p* row
+    local_scr,      # VMEM (1, K) float32
+    u1_col,         # VMEM (t, 1) float32 — u1 as a column
+    u2_col,         # VMEM (t, 1) float32
+    z_col,          # VMEM (t, 1) int32 — results as columns
+    sp_col,         # VMEM (t, 1) int32
+    ssq_col,        # VMEM (t, 1) float32
+    sem,            # DMA semaphores (3,)
     *,
-    tiles_per_step: int,
-    docs_per_chunk: int,
     alpha: float,
-    beta: float,
-    num_words_total: int,
 ):
-    C, dpc = tiles_per_step, docs_per_chunk
-    s = pl.program_id(1)
-    S = pl.num_programs(1)
+    t = z_old_ref.shape[1]
+    P = tok_cnt.shape[2]
+    R = row_block(t)
 
-    # ---- assembly steps: stage the fetched rows into the chunk tables ----
-    # (indices clamp once the respective table is full; the re-fetched row is
-    # identical, so the overwrite is a no-op)
-    phi_scr[pl.ds(jnp.minimum(s, C - 1), 1), :] = phi_row_ref[...]
-    j = jnp.minimum(s, dpc - 1)
-    ell_cnt_scr[pl.ds(j, 1), :] = ell_cnt_row_ref[...]
-    ell_tpc_scr[pl.ds(j, 1), :] = ell_tpc_row_ref[...]
+    # ---- stage the tile: its p* row and every real token's ELL row ----
+    pstar_copy = pltpu.make_async_copy(pstar_hbm.at[head_ref[0, 0]],
+                                       pstar_scr, sem.at[0])
+    pstar_copy.start()
+    n_real = head_ref[0, 1]
 
-    @pl.when(s == S - 1)
-    def _sample():  # ---- last inner step: the whole chunk, tables resident
-        K = phi_row_ref.shape[1]
-        P = ell_cnt_row_ref.shape[1]
-        t = z_old_ref.shape[1]
-        B = pick_search_block(K)
-        nb = K // B
+    def copies(j, doc):
+        return (pltpu.make_async_copy(ell_cnt_hbm.at[doc], tok_cnt.at[j],
+                                      sem.at[1]),
+                pltpu.make_async_copy(ell_tpc_hbm.at[doc], tok_tpc.at[j],
+                                      sem.at[2]))
 
-        # C7: p*(k) once per tile, VMEM-resident for all the chunk's tokens
-        pstar = (phi_scr[...].astype(jnp.float32) + beta) / (
-            phi_sum_ref[0, :].astype(jnp.float32)[None, :]
-            + beta * num_words_total)                         # (C, K)
-        Q = alpha * pstar.sum(-1)                             # (C,)
+    def issue(j, carry):
+        for c in copies(j, head_ref[0, HEAD + j]):
+            c.start()
+        return carry
 
-        # C5 level-1 "index tree": block sums for the whole chunk at once
-        blocks = pstar.reshape(C, nb, B)
-        bsum = blocks.sum(-1)                                 # (C, nb)
-        bcum = jnp.cumsum(bsum, axis=-1)
-        total = bcum[:, -1]
+    def drain(j, carry):
+        for c in copies(0, 0):   # waits count bytes: any same-shape copy
+            c.wait()
+        return carry
 
-        # C4 sparse side: ELL rows gathered from the on-chip table
-        slot = token_slot_ref[...]                            # (C, t)
-        flat = slot.reshape(-1)
-        cnt = jnp.take(ell_cnt_scr[...], flat, axis=0).reshape(C, t, P)
-        tpc = jnp.take(ell_tpc_scr[...], flat, axis=0).reshape(C, t, P)
-        p1 = cnt.astype(jnp.float32) * jnp.take_along_axis(
-            pstar[:, None, :], tpc, axis=2)                   # (C, t, P)
-        p1_cum = jnp.cumsum(p1, axis=-1)
-        Sm = p1_cum[..., -1]                                  # (C, t)
+    jax.lax.fori_loop(0, n_real, issue, 0)
+    jax.lax.fori_loop(0, n_real, drain, 0)
+    pstar_copy.wait()
 
-        u1 = uniforms_ref[..., 0]
-        u2 = uniforms_ref[..., 1]
-        use_sparse = u1 * (Sm + Q[:, None]) < Sm
+    # C7: p* is computed by XLA for every word (``sampler.pstar``, the same
+    # division the XLA sweep makes); C5 tables once per tile
+    bcum, total, nb = search_rows(pstar_scr[...], local_scr)
+    Q = alpha * total                                             # (1, 1)
 
+    # C4 sparse side over each token's ELL row, R tokens at a time (one
+    # fixed-size body keeps the kernel small).  Rows past n_real hold stale
+    # data; everything is row-local and masked out at the end.
+    u1_col[...] = jnp.transpose(u1_ref[...])                      # (t, 1)
+    u2_col[...] = jnp.transpose(u2_ref[...])
+
+    def rows(r, carry):
+        r0 = pl.multiple_of(r * R, R)
+        sl = pl.ds(r0, R)
+        cnt = tok_cnt[sl].reshape(R, P).astype(jnp.float32)
+        tpc = tok_tpc[sl].reshape(R, P)
+        p1 = cnt * gather_lanes(pstar_scr, tpc)                   # (R, P)
+        p1_cum = prefix_sum(p1, roll=pltpu.roll)
+        S = lane_pick(p1_cum, jnp.full((R, 1), P - 1, jnp.int32))  # (R, 1)
+        u1, u2 = u1_col[sl], u2_col[sl]
+        use_sparse = u1 * (S + Q) < S
         # sparse draw: search the P-entry prefix sums
-        t_sp = (u2 * Sm)[..., None]
         jj = jnp.minimum(
-            jnp.sum((p1_cum <= t_sp).astype(jnp.int32), axis=-1), P - 1)
-        k_sparse = jnp.take_along_axis(tpc, jj[..., None], axis=-1)[..., 0]
-
+            jnp.sum((p1_cum <= u2 * S).astype(jnp.int32), -1, keepdims=True),
+            P - 1)
+        k_sparse = lane_pick(tpc, jj)
         # dense draw: two-level blocked search (C5)
-        target = u2 * total[:, None]
-        b_idx = jnp.minimum(
-            jnp.sum((bcum[:, None, :] <= target[..., None]).astype(jnp.int32),
-                    axis=-1), nb - 1)
-        prev = jnp.where(
-            b_idx > 0,
-            jnp.take_along_axis(bcum, jnp.maximum(b_idx - 1, 0), axis=-1),
-            0.0)
-        seg = jnp.take_along_axis(blocks, b_idx[..., None], axis=1)  # (C,t,B)
-        seg_cum = jnp.cumsum(seg, axis=-1) + prev[..., None]
-        in_b = jnp.minimum(
-            jnp.sum((seg_cum <= target[..., None]).astype(jnp.int32),
-                    axis=-1), B - 1)
-        k_dense = b_idx * B + in_b
+        k_dense = dense_draw(local_scr, bcum, nb, u2 * total)
+        z_col[sl] = jnp.where(use_sparse, k_sparse, k_dense)
+        sp_col[sl] = use_sparse.astype(jnp.int32)
+        ssq_col[sl] = S / jnp.maximum(S + Q, 1e-30)
+        return carry
 
-        mask = mask_ref[...] != 0
-        z = jnp.where(use_sparse, k_sparse.astype(jnp.int32),
-                      k_dense.astype(jnp.int32))
-        z_new_ref[...] = jnp.where(mask, z, z_old_ref[...])
-        sparse_ref[...] = (use_sparse & mask).astype(jnp.int32)
-        ssq_ref[...] = jnp.where(
-            mask, Sm / jnp.maximum(Sm + Q[:, None], 1e-30), 0.0)
+    # only the row blocks holding real tokens (most tiles of the Zipf tail
+    # hold a few); the columns of the others are stale and masked out below
+    jax.lax.fori_loop(0, (n_real + R - 1) // R, rows, 0)
+    mask = mask_ref[...] != 0
+    z_new_ref[...] = jnp.where(mask, jnp.transpose(z_col[...]),
+                               z_old_ref[...])
+    sparse_ref[...] = jnp.where(mask, jnp.transpose(sp_col[...]), 0)
+    ssq_ref[...] = jnp.where(mask, jnp.transpose(ssq_col[...]), 0.0)
 
 
-def grid_layout(n_chunks: int, t: int, K: int, P: int, *,
-                tiles_per_step: int, docs_per_chunk: int):
+def grid_layout(n: int, t: int, K: int, P: int):
     """Launch geometry: ``(grid, in_specs, out_specs, scratch_shapes)``.
 
     Single source of truth — ``lda_sample_tiles`` launches from this and the
-    ``kernel-contract`` checker (``contract.py``) enumerates it, so the
-    checked BlockSpecs can never drift from the launched ones.
-    """
-    C, dpc = tiles_per_step, docs_per_chunk
-    S = max(C, dpc)
+    ``kernel-contract`` checker (``contract.py``) enumerates it."""
+    row = pl.BlockSpec((None, 1, t), lambda i: (i, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [
-        # one phi row per assembly step, picked by the tile's word id
-        pl.BlockSpec(
-            (1, K),
-            lambda c, s, tw, cd: (tw[c * C + jnp.minimum(s, C - 1)], 0)),
-        pl.BlockSpec((1, K), lambda c, s, tw, cd: (0, 0)),   # phi_sum
-        # one ELL row per assembly step, picked by the chunk's doc list
-        pl.BlockSpec(
-            (1, P),
-            lambda c, s, tw, cd: (cd[c, jnp.minimum(s, dpc - 1)], 0)),
-        pl.BlockSpec(
-            (1, P),
-            lambda c, s, tw, cd: (cd[c, jnp.minimum(s, dpc - 1)], 0)),
-        pl.BlockSpec((C, t), lambda c, s, tw, cd: (c, 0)),
-        pl.BlockSpec((C, t, 2), lambda c, s, tw, cd: (c, 0, 0)),
-        pl.BlockSpec((C, t), lambda c, s, tw, cd: (c, 0)),
-        pl.BlockSpec((C, t), lambda c, s, tw, cd: (c, 0)),
+        pl.BlockSpec((None, 1, HEAD + t), lambda i: (i, 0, 0),
+                     memory_space=pltpu.SMEM),
+        hbm,                                              # p* rows
+        hbm, hbm,                                         # ELL counts/topics
+        row, row, row, row,                               # u1, u2, mask, z
     ]
-    out_specs = [
-        pl.BlockSpec((C, t), lambda c, s, tw, cd: (c, 0)),
-        pl.BlockSpec((C, t), lambda c, s, tw, cd: (c, 0)),
-        pl.BlockSpec((C, t), lambda c, s, tw, cd: (c, 0)),
-    ]
+    out_specs = [row, row, row]
     scratch_shapes = [
-        pltpu.VMEM((C, K), jnp.int32),
-        pltpu.VMEM((dpc, P), jnp.int32),
-        pltpu.VMEM((dpc, P), jnp.int32),
+        pltpu.VMEM((t, 1, P), jnp.int32),
+        pltpu.VMEM((t, 1, P), jnp.int32),
+        pltpu.VMEM((1, K), jnp.float32),
+        pltpu.VMEM((1, K), jnp.float32),
+        pltpu.VMEM((t, 1), jnp.float32),
+        pltpu.VMEM((t, 1), jnp.float32),
+        pltpu.VMEM((t, 1), jnp.int32),
+        pltpu.VMEM((t, 1), jnp.int32),
+        pltpu.VMEM((t, 1), jnp.float32),
+        pltpu.SemaphoreType.DMA((3,)),
     ]
-    return (n_chunks, S), in_specs, out_specs, scratch_shapes
+    return (n,), in_specs, out_specs, scratch_shapes
+
+
+def tile_head(tile_word, token_doc, token_mask):
+    """The per-tile SMEM header ``(n, 1, HEAD + t)``: word id, real-token
+    count, then the doc id of every slot (real tokens are left-packed)."""
+    n, t = token_doc.shape
+    meta = jnp.zeros((n, HEAD), jnp.int32)
+    meta = meta.at[:, 0].set(tile_word).at[:, 1].set(token_mask.sum(1))
+    return jnp.concatenate([meta, token_doc], axis=1).reshape(n, 1, HEAD + t)
 
 
 def lda_sample_tiles(
-    tile_word,     # (n,) int32 — n a multiple of tiles_per_step
-    chunk_docs,    # (n_chunks, dpc) int32 — distinct doc ids per chunk
-    token_slot,    # (n, t) int32 — token -> chunk doc-slot
-    phi_vk,        # (V, K) int32
-    phi_sum,       # (K,) int32
-    ell_counts,    # (D, P) int32 — per-DOC ELL, *never* per-token gathered
+    tile_word,     # (n,) int32
+    token_doc,     # (n, t) int32
+    pstar_vk,      # (V, K) float32 — ``sampler.pstar`` of every word
+    ell_counts,    # (D, P) int32 — per-DOC ELL, fetched per real token
     ell_topics,    # (D, P) int32
-    uniforms,      # (n, t, 2) float32
+    u1,            # (n, t) float32
+    u2,            # (n, t) float32
     token_mask,    # (n, t) int32
     z_old,         # (n, t) int32
     *,
     alpha: float,
-    beta: float,
-    num_words_total: int,
-    tiles_per_step: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
-    """pallas_call wrapper: grid (chunks, assembly-steps); phi rows *and* ELL
-    rows selected by scalar-prefetch index maps — zero host/HBM gathers.
+    """pallas_call wrapper: grid over word tiles.  Returns (z_new, sparse,
+    ssq), all (n, t).
 
-    Returns (z_new, sparse, ssq), all (n, t).
-    """
+    The ELL width is padded to whole 128-lane vregs: the extra entries have
+    count 0, add exact zeros to every prefix sum and are never drawn."""
     n, t = z_old.shape
-    V, K = phi_vk.shape
+    V, K = pstar_vk.shape
+    pad = -ell_counts.shape[1] % 128
+    ell_counts = jnp.pad(ell_counts, ((0, 0), (0, pad)))
+    ell_topics = jnp.pad(ell_topics, ((0, 0), (0, pad)))
     D, P = ell_counts.shape
-    C = tiles_per_step
-    assert n % C == 0, (n, C)
-    n_chunks, dpc = chunk_docs.shape
-    assert n_chunks * C == n, (n_chunks, C, n)
-
-    grid, in_specs, out_specs, scratch_shapes = grid_layout(
-        n_chunks, t, K, P, tiles_per_step=C, docs_per_chunk=dpc)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+    grid, in_specs, out_specs, scratch_shapes = grid_layout(n, t, K, P)
+    kern = functools.partial(_kernel, alpha=alpha)
+    rows = lambda a: a.reshape(n, 1, t)  # noqa: E731
+    out = pl.pallas_call(
+        kern,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
-    )
-    kern = functools.partial(
-        _kernel, tiles_per_step=C, docs_per_chunk=dpc,
-        alpha=alpha, beta=beta, num_words_total=num_words_total)
-    z_new, sparse, ssq = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n, t), jnp.int32),
-            jax.ShapeDtypeStruct((n, t), jnp.int32),
-            jax.ShapeDtypeStruct((n, t), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1, t), jnp.int32),
+            jax.ShapeDtypeStruct((n, 1, t), jnp.int32),
+            jax.ShapeDtypeStruct((n, 1, t), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(tile_word, chunk_docs, phi_vk, phi_sum.reshape(1, K),
-      ell_counts, ell_topics, token_slot, uniforms, token_mask, z_old)
-    return z_new, sparse, ssq
+    )(tile_head(tile_word, token_doc, token_mask),
+      pstar_vk.reshape(V, 1, K), ell_counts.reshape(D, 1, P), ell_topics.reshape(D, 1, P),
+      rows(u1), rows(u2), rows(token_mask), rows(z_old))
+    return tuple(o.reshape(n, t) for o in out)

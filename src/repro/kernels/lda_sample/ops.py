@@ -1,18 +1,12 @@
 """Public wrapper for the lda_sample kernel.
 
 Adapts the trainer's data model (per-doc ELL, int16 z, bool masks) to the
-kernel's layout and exposes an ``impl={"pallas","ref"}`` switch so the
-trainer can run the kernel path end-to-end under interpret mode.
+kernel's layout and exposes an ``impl={"pallas","ref"}`` switch.
 
-The wrapper performs **no per-token HBM gather**: the pre-fusion version
-materialized ``ell_counts[token_doc]`` as an ``(n, t, P)`` tensor — per
-sweep, per iteration — which is exactly the traffic the paper's shared-
-memory design (and SaberLDA/WarpLDA's layouts) exists to avoid.  Instead a
-host-side **chunk plan** (static for the whole run: it depends only on the
-corpus tiling and the chunk width) lists each chunk's distinct doc ids and
-a token->slot map; the kernel streams those ELL rows into VMEM via a
-scalar-prefetch index map and gathers on-chip.  ``tests/test_kernels.py``
-pins the absence of any (n, t, P) intermediate by jaxpr shape accounting.
+The wrapper performs **no per-token HBM gather**: the kernel DMAs each
+real token's ELL row from the per-doc tables straight into VMEM, so the
+``(n, t, P)`` tensor ``ell_counts[token_doc]`` exists nowhere
+(``tests/test_kernels.py`` pins this by jaxpr shape accounting).
 
 Randomness contract: uniforms come from ``sampler.draw_sweep_uniforms`` —
 the same (n, t, 2) tensor the XLA sweep consumes — so kernel draws are
@@ -21,120 +15,22 @@ bit-identical to ``sampler.sample_sweep`` under the same key.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core.sampler import SamplerStats, draw_sweep_uniforms
+from repro.core.sampler import SamplerStats, draw_sweep_uniforms, pstar
 
 from . import kernel, ref
 
-DEFAULT_TILES_PER_STEP = 64
 
-
-class ChunkPlan(NamedTuple):
-    """Static per-(tiling, chunk-width) metadata for the fused sweep.
-
-    chunk_docs: (n_chunks, dpc) int32 — distinct doc ids per chunk (padded
-        by repeating the last real id: re-fetching a resident row is free).
-    token_slot: (n_pad, t) int32 — each token's row in its chunk's doc table.
-    """
-
-    chunk_docs: np.ndarray | jnp.ndarray
-    token_slot: np.ndarray | jnp.ndarray
-
-    @property
-    def tiles_per_step(self) -> int:
-        return self.token_slot.shape[0] // self.chunk_docs.shape[0]
-
-
-def build_chunk_plan(token_doc, tiles_per_step: int,
-                     docs_per_chunk: int | None = None) -> ChunkPlan:
-    """Host-side (numpy) chunk plan for ``lda_sample``.
-
-    ``token_doc`` must be concrete — the plan is built once per run from the
-    static corpus tiling (under jit the shard rides in as a closure constant,
-    so this holds in the single-host trainer; traced contexts must pass a
-    prebuilt plan in).  ``docs_per_chunk`` pads the doc tables to a common
-    width (WorkSchedule2 stacks plans of several micro-chunks).
-    """
-    try:
-        td = np.asarray(token_doc)
-    except jax.errors.TracerArrayConversionError as e:  # pragma: no cover
-        raise ValueError(
-            "build_chunk_plan needs a concrete token_doc (the chunk plan is "
-            "static per corpus tiling); pass plan= explicitly in traced "
-            "contexts such as shard_map") from e
-    n, t = td.shape
-    C = tiles_per_step
-    n_pad = -n % C
-    if n_pad:
-        td = np.concatenate([td, np.zeros((n_pad, t), td.dtype)])
-    n_chunks = td.shape[0] // C
-    per_chunk = [np.unique(td[c * C:(c + 1) * C]) for c in range(n_chunks)]
-    dpc = max(len(d) for d in per_chunk)
-    if docs_per_chunk is not None:
-        assert docs_per_chunk >= dpc, (docs_per_chunk, dpc)
-        dpc = docs_per_chunk
-    chunk_docs = np.zeros((n_chunks, dpc), np.int32)
-    token_slot = np.zeros((n + n_pad, t), np.int32)
-    for c, docs in enumerate(per_chunk):
-        chunk_docs[c, :len(docs)] = docs
-        chunk_docs[c, len(docs):] = docs[-1]
-        slot_of = np.zeros(int(docs[-1]) + 1, np.int32)
-        slot_of[docs] = np.arange(len(docs), dtype=np.int32)
-        blk = td[c * C:(c + 1) * C]
-        token_slot[c * C:(c + 1) * C] = slot_of[blk]
-    return ChunkPlan(chunk_docs=chunk_docs, token_slot=token_slot)
-
-
-def build_sweep_plans(token_doc, micro_chunks: int, tiles_per_step: int,
-                      docs_per_chunk: int | None = None) -> tuple[ChunkPlan, ...]:
-    """Host-side chunk plans for a whole sweep — one plan per micro-chunk.
-
-    Mirrors the trainer's WorkSchedule padding exactly (pad the tile count
-    to a multiple of M with empty tiles, then chunk width C = min(tiles_per_
-    step, tiles-per-micro-chunk)) so the plans line up tile-for-tile with
-    the sliced arrays ``lda_iteration`` hands the kernel.  All plans share
-    one ``docs_per_chunk`` width; pass a larger ``docs_per_chunk`` to pad
-    further (the mesh-sharded sweep stacks plans of SPMD shards, which must
-    agree on one static dpc — see ``DistributedLDA``).
-
-    ``micro_chunks=1`` (WorkSchedule1) returns the single whole-shard plan.
-    """
-    try:
-        td = np.asarray(token_doc)
-    except jax.errors.TracerArrayConversionError as e:  # pragma: no cover
-        raise ValueError(
-            "build_sweep_plans needs a concrete token_doc (plans are static "
-            "per corpus tiling); pass plans= explicitly in traced contexts "
-            "such as shard_map") from e
-    n, t = td.shape
-    M = micro_chunks
-    n_pad = -n % M
-    if n_pad:
-        td = np.concatenate([td, np.zeros((n_pad, t), td.dtype)])
-    nc = (n + n_pad) // M
-    C = min(tiles_per_step, nc)
-    plans = [build_chunk_plan(td[m * nc:(m + 1) * nc], C) for m in range(M)]
-    dpc = max(p.chunk_docs.shape[1] for p in plans)
-    if docs_per_chunk is not None:
-        assert docs_per_chunk >= dpc, (docs_per_chunk, dpc)
-        dpc = docs_per_chunk
-    if any(p.chunk_docs.shape[1] != dpc for p in plans):
-        plans = [build_chunk_plan(td[m * nc:(m + 1) * nc], C,
-                                  docs_per_chunk=dpc) for m in range(M)]
-    return tuple(plans)
-
-
+@functools.partial(jax.jit, static_argnames=(
+    "alpha", "beta", "num_words_total", "impl", "interpret"))
 def lda_sample(
     tile_word, token_doc, token_mask, z, phi_vk, phi_sum,
     ell_counts, ell_topics, key, *,
     alpha: float, beta: float, num_words_total: int,
-    impl: str = "pallas", interpret: bool = True,
-    tiles_per_step: int | None = None, plan: ChunkPlan | None = None,
+    impl: str, interpret: bool,
 ):
     """Sample one sweep of word tiles.
 
@@ -142,59 +38,22 @@ def lda_sample(
     bit-identical to ``sampler.sample_sweep`` under the same key.
     """
     n, t = z.shape
-    C = min(tiles_per_step or DEFAULT_TILES_PER_STEP, n)
-    if plan is None and impl == "pallas":
-        plan = build_chunk_plan(token_doc, C)
-    cd = ts = jnp.zeros((0,), jnp.int32)  # ref path: plan unused
-    if plan is not None:
-        C = plan.tiles_per_step
-        cd = jnp.asarray(plan.chunk_docs)
-        ts = jnp.asarray(plan.token_slot)
-    return _lda_sample(
-        tile_word, token_doc, token_mask, z, phi_vk, phi_sum,
-        ell_counts, ell_topics, key, cd, ts,
-        alpha=alpha, beta=beta, num_words_total=num_words_total,
-        impl=impl, interpret=interpret, tiles_per_step=C)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "alpha", "beta", "num_words_total", "impl", "interpret",
-    "tiles_per_step"))
-def _lda_sample(
-    tile_word, token_doc, token_mask, z, phi_vk, phi_sum,
-    ell_counts, ell_topics, key, chunk_docs, token_slot, *,
-    alpha: float, beta: float, num_words_total: int,
-    impl: str, interpret: bool, tiles_per_step: int,
-):
-    n, t = z.shape
-    C = tiles_per_step
-    # same uniforms as the XLA sweep: split over the *unpadded* tile count
+    # same uniforms as the XLA sweep: one key per tile
     uniforms = draw_sweep_uniforms(key, n, t)
-
-    n_pad = -n % C
-    tw = tile_word.astype(jnp.int32)
-    td = token_doc.astype(jnp.int32)
-    tm = token_mask.astype(jnp.int32)
-    zo = z.astype(jnp.int32)
-    if n_pad:  # masked-out padding tiles (static at trace time)
-        tw = jnp.concatenate([tw, jnp.zeros(n_pad, jnp.int32)])
-        td = jnp.concatenate([td, jnp.zeros((n_pad, t), jnp.int32)])
-        tm = jnp.concatenate([tm, jnp.zeros((n_pad, t), jnp.int32)])
-        zo = jnp.concatenate([zo, jnp.zeros((n_pad, t), jnp.int32)])
-        uniforms = jnp.concatenate(
-            [uniforms, jnp.zeros((n_pad, t, 2), jnp.float32)])
-
-    args = (phi_vk.astype(jnp.int32), phi_sum.astype(jnp.int32),
-            ell_counts.astype(jnp.int32), ell_topics.astype(jnp.int32),
-            uniforms, tm, zo)
-    kw = dict(alpha=alpha, beta=beta, num_words_total=num_words_total)
+    tw, td = tile_word.astype(jnp.int32), token_doc.astype(jnp.int32)
+    rest = (ell_counts.astype(jnp.int32), ell_topics.astype(jnp.int32),
+            uniforms[..., 0], uniforms[..., 1],
+            token_mask.astype(jnp.int32), z.astype(jnp.int32))
     if impl == "pallas":
+        # p* of every word, by the same XLA division the sq sweep makes
+        pstar_vk = pstar(phi_vk, phi_sum, beta, num_words_total)
         z_new, sparse, ssq = kernel.lda_sample_tiles(
-            tw, chunk_docs, token_slot, *args,
-            tiles_per_step=C, interpret=interpret, **kw)
+            tw, td, pstar_vk, *rest, alpha=alpha, interpret=interpret)
     else:
-        z_new, sparse, ssq = ref.lda_sample_tiles_ref(tw, td, *args, **kw)
+        z_new, sparse, ssq = ref.lda_sample_tiles_ref(
+            tw, td, phi_vk.astype(jnp.int32), phi_sum.astype(jnp.int32),
+            *rest, alpha=alpha, beta=beta, num_words_total=num_words_total)
     total = jnp.maximum(token_mask.sum(), 1)
     stats = SamplerStats(sparse_frac=sparse.sum() / total,
                          mean_s_over_sq=ssq.sum() / total)
-    return z_new[:n].astype(z.dtype), stats
+    return z_new.astype(z.dtype), stats

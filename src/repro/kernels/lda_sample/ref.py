@@ -1,19 +1,18 @@
 """Pure-jnp oracle for the lda_sample kernel.
 
-Mirrors the kernel's math exactly (same blocked search, same branch rule)
-using only jnp ops; kernel draws must match bit-for-bit given the same
-uniforms.  Also cross-checked against ``repro.core.sampler`` in tests.
-
-The oracle deliberately keeps the *naive* data movement the kernel
-eliminates: it gathers the per-token ELL rows ``ell_*[token_doc]`` in HBM —
-that is the baseline the on-chip doc-slot streaming is measured against,
-and it makes the oracle independent of the kernel's chunk plan.
+The kernel's contract, written the plain way: every tile through
+``repro.core.sampler.sample_one_tile`` (the ``"sq"`` sweep's tile step),
+with the per-token ELL rows gathered as ordinary XLA gathers.  The kernel
+must match it draw for draw given the same uniforms.
 """
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 
-from repro.core.sampler import SEARCH_BLOCK, _pick_block
+from repro.core.sampler import sample_one_tile
 
 
 def lda_sample_tiles_ref(
@@ -23,57 +22,18 @@ def lda_sample_tiles_ref(
     phi_sum,       # (K,) int32
     ell_counts,    # (D, P) int32
     ell_topics,    # (D, P) int32
-    uniforms,      # (n, t, 2) float32
+    u1,            # (n, t) float32
+    u2,            # (n, t) float32
     token_mask,    # (n, t) int32
     z_old,         # (n, t) int32
     *,
     alpha, beta, num_words_total,
 ):
     """Returns (z_new, sparse, ssq), all (n, t) — the kernel's contract."""
-    n, t = z_old.shape
-    V, K = phi_vk.shape
-    B = SEARCH_BLOCK if K % SEARCH_BLOCK == 0 else _pick_block(K)
-    nb = K // B
-
-    phi_rows = phi_vk[tile_word]                              # (n, K)
-    pstar = (phi_rows.astype(jnp.float32) + beta) / (
-        phi_sum.astype(jnp.float32)[None, :] + beta * num_words_total)
-    Q = alpha * pstar.sum(-1)                                 # (n,)
-
-    blocks = pstar.reshape(n, nb, B)
-    bsum = blocks.sum(-1)
-    bcum = jnp.cumsum(bsum, axis=-1)
-    total = bcum[:, -1]
-
-    tpc = ell_topics[token_doc].astype(jnp.int32)             # (n, t, P)
-    cnt = ell_counts[token_doc].astype(jnp.float32)
-    p1 = cnt * jnp.take_along_axis(pstar[:, None, :], tpc, axis=2)
-    p1_cum = jnp.cumsum(p1, axis=-1)
-    S = p1_cum[..., -1]                                       # (n, t)
-
-    u1 = uniforms[..., 0]
-    u2 = uniforms[..., 1]
-    use_sparse = u1 * (S + Q[:, None]) < S
-
-    t_sp = (u2 * S)[..., None]
-    j = jnp.minimum((p1_cum <= t_sp).sum(-1), tpc.shape[-1] - 1)
-    k_sparse = jnp.take_along_axis(tpc, j[..., None], axis=-1)[..., 0]
-
-    target = u2 * total[:, None]
-    b_idx = jnp.minimum((bcum[:, None, :] <= target[..., None]).sum(-1), nb - 1)
-    prev = jnp.where(
-        b_idx > 0,
-        jnp.take_along_axis(bcum, jnp.maximum(b_idx - 1, 0), axis=-1),
-        0.0)
-    seg = jnp.take_along_axis(blocks, b_idx[..., None], axis=1)  # (n, t, B)
-    seg_cum = jnp.cumsum(seg, axis=-1) + prev[..., None]
-    in_b = jnp.minimum((seg_cum <= target[..., None]).sum(-1), B - 1)
-    k_dense = b_idx * B + in_b
-
-    mask = token_mask != 0
-    z = jnp.where(use_sparse, k_sparse.astype(jnp.int32),
-                  k_dense.astype(jnp.int32))
-    z_new = jnp.where(mask, z, z_old)
-    sparse = (use_sparse & mask).astype(jnp.int32)
-    ssq = jnp.where(mask, S / jnp.maximum(S + Q[:, None], 1e-30), 0.0)
-    return z_new, sparse, ssq
+    step = functools.partial(sample_one_tile, alpha=alpha, beta=beta,
+                             num_words_total=num_words_total)
+    z_new, sparse, ssq = jax.vmap(
+        step, in_axes=(0, None, 0, 0, 0, None, None, 0))(
+        phi_vk[tile_word], phi_sum, token_doc, token_mask != 0, z_old,
+        ell_counts, ell_topics, jnp.stack([u1, u2], axis=-1))
+    return z_new, sparse.astype(jnp.int32), ssq

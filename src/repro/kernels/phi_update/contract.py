@@ -1,10 +1,11 @@
 """kernel-contract metadata for the phi count-update kernel.
 
-The output spec REVISITS blocks (grid walks word-sorted tiles, each landing
-in its word's (1, K) row), so coverage here asserts the word-boundary
-discipline: every phi row is visited, and the ``tile_first`` invariant
-(exactly one first-visit per contiguous word run) holds — that invariant is
-what makes the ``@pl.when(first == 1)`` zero-init produce exact counts.
+The grid walks word-sorted tiles accumulating into one VMEM row, which is
+written to its word's HBM row after the word's last tile.  The checks here
+assert the word-boundary discipline on the kernel's own ``tile_meta``:
+tiles are word-sorted, exactly one first flag opens and exactly one last
+flag closes each contiguous word run (so the zero-init and the single
+write produce exact counts), and every word's row is written.
 """
 from __future__ import annotations
 
@@ -14,42 +15,52 @@ import jax.numpy as jnp
 from repro.analysis.contracts import ContractCase, KernelContract, Operand
 from repro.kernels.phi_update import kernel
 
-VMEM_BUDGET_BYTES = 64 * 1024
+VMEM_BUDGET_BYTES = 128 * 1024
 
 
-def _word_sorted_meta(n: int, V: int) -> np.ndarray:
-    """(n, 2) [tile_word, tile_first] with word-sorted tiles covering every
-    word (the trainer's host-side layout)."""
+def _word_sorted_tiles(n: int, V: int) -> tuple[np.ndarray, np.ndarray]:
+    """(tile_word, tile_first) with word-sorted tiles covering every word
+    (the trainer's host-side layout)."""
     tile_word = np.sort((np.arange(n, dtype=np.int32) * V) // n)
     tile_first = np.r_[1, (np.diff(tile_word) != 0).astype(np.int32)]
-    return np.stack([tile_word, tile_first], axis=1).astype(np.int32)
+    return tile_word, tile_first.astype(np.int32)
 
 
 def _case(name: str, *, n: int, t: int, V: int, K: int, delta: bool
           ) -> ContractCase:
-    meta = _word_sorted_meta(n, V)
-    grid, in_specs, out_spec = kernel.grid_layout(n, t, K, delta=delta)
+    tile_word, tile_first = _word_sorted_tiles(n, V)
+    meta = np.asarray(kernel.tile_meta(tile_word, tile_first))[:, 0]
+    grid, in_specs, out_spec, scratch = kernel.grid_layout(n, t, K,
+                                                           delta=delta)
     names = ("z_new", "z_old", "mask") if delta else ("z", "mask")
-    inputs = tuple(Operand(nm, (n, t), jnp.int32, spec)
-                   for nm, spec in zip(names, in_specs))
-    outputs = (Operand("phi_delta", (V, K), jnp.int32, out_spec),)
+    inputs = (Operand("meta", (n, 1, kernel.META), jnp.int32, in_specs[0]),)
+    inputs += tuple(Operand(nm, (n, 1, t), jnp.int32, spec)
+                    for nm, spec in zip(names, in_specs[1:]))
+    outputs = (Operand("phi_delta", (V, 1, K), jnp.int32, out_spec),)
 
-    def first_visit_invariant():
+    def word_run_invariant():
         msgs = []
-        w, f = meta[:, 0], meta[:, 1]
+        w, first, last = meta[:, 0], meta[:, 1], meta[:, 2]
         if not np.array_equal(w, np.sort(w)):
-            msgs.append("tile_word not word-sorted — block revisiting "
-                        "would interleave rows mid-accumulation")
+            msgs.append("tile_word not word-sorted — a word's row would be "
+                        "written before all its tiles accumulated")
         expect_first = np.r_[1, (np.diff(w) != 0).astype(np.int32)]
-        if not np.array_equal(f, expect_first):
+        if not np.array_equal(first, expect_first):
             msgs.append("tile_first != first-tile-of-each-word-run — the "
-                        "first-visit zero-init would drop or double counts")
+                        "zero-init would drop or double counts")
+        expect_last = np.r_[(np.diff(w) != 0).astype(np.int32), 1]
+        if not np.array_equal(last, expect_last):
+            msgs.append("last-tile flags != end of each word run — a row "
+                        "would be written early, twice or never")
+        missing = np.setdiff1d(np.arange(V), w[last == 1])
+        if missing.size:
+            msgs.append(f"{missing.size} phi rows never written "
+                        f"(e.g. word {int(missing[0])})")
         return msgs
 
     return ContractCase(
         name=name, grid=grid, inputs=inputs, outputs=outputs,
-        scalar_args=(meta,), coverage=("phi_delta",),
-        extra_checks=(first_visit_invariant,))
+        scratch=tuple(scratch), extra_checks=(word_run_invariant,))
 
 
 def contract() -> KernelContract:

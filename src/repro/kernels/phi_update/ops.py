@@ -13,7 +13,7 @@ from . import kernel, ref
                                              "impl", "interpret"))
 def phi_update(tile_word, tile_first, z, token_mask, *,
                num_words: int, num_topics: int,
-               impl: str = "pallas", interpret: bool = True):
+               impl: str, interpret: bool):
     args = (tile_word.astype(jnp.int32), tile_first.astype(jnp.int32),
             z.astype(jnp.int32), token_mask.astype(jnp.int32))
     if impl == "pallas":
@@ -30,7 +30,7 @@ def phi_update(tile_word, tile_first, z, token_mask, *,
                                              "impl", "interpret"))
 def phi_delta(tile_word, tile_first, z_old, z_new, token_mask, *,
               num_words: int, num_topics: int,
-              impl: str = "pallas", interpret: bool = True):
+              impl: str, interpret: bool):
     """Per-iteration phi DELTA (V, K) int32: counts(z_new) - counts(z_old).
 
     The trainer adds this to the previous phi instead of rebuilding counts

@@ -167,7 +167,8 @@ def run_cell(arch: str, shape: str, multi_pod: bool, probe: bool = True) -> dict
         memory=mem,
         scan_cost=dict(flops=float(ca.get("flops", 0) or 0),
                        bytes=float(ca.get("bytes accessed", 0) or 0)),
-        fits_hbm=bool(mem["peak_device_bytes"] <= mesh_lib.HBM_BYTES),
+        fits_hbm=bool(mem["peak_device_bytes"] <= mesh_lib.device_peaks(
+            mesh_lib.PRODUCTION_KIND)["hbm_bytes"]),
     )
     if probe and not multi_pod:
         out["costs"] = probe_costs(arch, shape, mesh)

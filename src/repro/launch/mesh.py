@@ -9,23 +9,43 @@ from __future__ import annotations
 
 import jax
 
-# TPU v5e hardware constants (roofline denominators)
-PEAK_FLOPS_BF16 = 197e12       # per chip
-HBM_BW = 819e9                 # bytes/s per chip
-ICI_BW = 50e9                  # bytes/s per link
-HBM_BYTES = 16 * 1024 ** 3     # 16 GiB per chip
+# Published per-chip peaks (roofline denominators), keyed by the
+# ``device_kind`` JAX reports.  Source: Google Cloud documentation, "TPU
+# v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of ICI over
+# four links.
+PEAKS = {
+    "TPU v5 lite": dict(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9,
+                        hbm_bytes=16 * 1024 ** 3),
+}
+PRODUCTION_KIND = "TPU v5 lite"   # the dry-run target: v5e pods
+
+
+def device_peaks(kind: str | None = None) -> dict:
+    """Peaks of ``kind`` (default: the first local device's kind).  A kind
+    missing from ``PEAKS`` is an error, never a default."""
+    kind = jax.devices()[0].device_kind if kind is None else kind
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind {kind!r}; "
+                         f"known: {sorted(PEAKS)}") from None
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axis types (sharding constraints and
+    ``shard_map`` both accept them; jax's default Explicit axes do not)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes,
-                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n: int | None = None, name: str = "data"):
     """All (or n) local devices on one axis — CPU tests and examples."""
     devs = jax.devices()
     n = len(devs) if n is None else n
-    return jax.make_mesh((n,), (name,),
-                         axis_types=(jax.sharding.AxisType.Auto,))
+    return make_mesh((n,), (name,))

@@ -23,7 +23,7 @@ from __future__ import annotations
 import json
 
 from repro.configs.archs import ARCHS, SHAPES
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import PRODUCTION_KIND, device_peaks
 from repro.models.common import ModelConfig, padded_vocab
 
 CHIPS = 256  # single-pod roofline mesh
@@ -122,9 +122,10 @@ def analyze_cell(cell: dict) -> dict:
     flops = costs["hlo_flops"]          # per device
     bytes_ = costs["hlo_bytes"]         # per device
     coll = sum(costs["coll_bytes"].values())  # per device
-    t_compute = flops / PEAK_FLOPS_BF16
-    t_memory = bytes_ / HBM_BW
-    t_collective = coll / ICI_BW
+    peaks = device_peaks(PRODUCTION_KIND)
+    t_compute = flops / peaks["flops_bf16"]
+    t_memory = bytes_ / peaks["hbm_bw"]
+    t_collective = coll / peaks["ici_bw"]
     terms = dict(compute=t_compute, memory=t_memory, collective=t_collective)
     bound = max(terms, key=terms.get)
     mf = model_flops(cell["arch"], cell["shape"])
@@ -133,7 +134,7 @@ def analyze_cell(cell: dict) -> dict:
         bound=bound, model_flops=mf,
         useful_ratio=mf / max(flops * CHIPS, 1.0),
         step_time=max(terms.values()),
-        mfu=mf / CHIPS / PEAK_FLOPS_BF16 / max(terms.values()),
+        mfu=mf / CHIPS / peaks["flops_bf16"] / max(terms.values()),
     )
 
 

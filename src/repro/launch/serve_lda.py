@@ -75,11 +75,10 @@ def build_argparser() -> argparse.ArgumentParser:
     ap.add_argument("--burn-in", type=int, default=8)
     ap.add_argument("--samples", type=int, default=4)
     ap.add_argument("--top-k", type=int, default=8)
-    ap.add_argument("--impl", choices=("xla", "pallas", "ref"), default="xla",
-                    help="fold-in implementation: pure-XLA scan, the Pallas "
-                         "kernel (repro.kernels.fold_in; interpret mode on "
-                         "CPU), or the kernel's jnp oracle — all "
-                         "draw-identical")
+    ap.add_argument("--impl", choices=("xla", "pallas"), default="xla",
+                    help="fold-in implementation: the pure-XLA sweeps or "
+                         "the Pallas kernel (repro.kernels.fold_in; "
+                         "interpret mode on CPU) — draw-identical")
     ap.add_argument("--shards", type=int, default=0,
                     help="serve phi word-sharded over this many mesh "
                          "devices; a dense snapshot is re-split at load, a "
@@ -406,6 +405,8 @@ def run_http(args) -> int:
 
 def main(argv=None) -> int:
     args = build_argparser().parse_args(argv)
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     return run_bench(args) if args.bench else run_http(args)
 
 

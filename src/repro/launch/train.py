@@ -61,6 +61,8 @@ def main():
         os.execv(sys.executable, [sys.executable] + sys.argv)
 
     import jax
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     if args.sanitize:
         from repro.analysis.runtime import enable_debug_nans
         enable_debug_nans()
@@ -80,6 +82,7 @@ def run_lda(args):
     from repro.core import trainer
     from repro.core.corpus import read_uci_bow
     from repro.data.synthetic import nytimes_like
+    from repro.launch.mesh import make_mesh
     from repro.obs import Observability
     from repro.train import fit
 
@@ -87,16 +90,14 @@ def run_lda(args):
     n_dev = len(jax.devices())
     cfg = trainer.LDAConfig(num_topics=args.topics, sampler=args.sampler)
 
-    # every sampler — the fused Pallas sweep included — runs on the mesh:
-    # per-shard chunk plans travel through shard_map as data, so there is no
-    # single-host fallback anymore (see DistributedLDA)
+    # every sampler — the fused Pallas sweep included — runs on the mesh
     mesh = None
     if n_dev > 1:
         if args.mode == "1d":
-            mesh = jax.make_mesh((n_dev,), ("data",))
+            mesh = make_mesh((n_dev,), ("data",))
         else:
             md = max(1, n_dev // 2)
-            mesh = jax.make_mesh((md, n_dev // md), ("data", "model"))
+            mesh = make_mesh((md, n_dev // md), ("data", "model"))
 
     # eval cadence must hit every --ckpt-every multiple AND keep the
     # every-10-iterations progress line
@@ -122,6 +123,7 @@ def run_lm(args):
     import jax
     import jax.numpy as jnp
     from repro.configs.archs import ARCHS, smoke
+    from repro.launch.mesh import make_mesh
     from repro.launch.specs import make_policy
     from repro.models import transformer as tf, zoo
     from repro.optim import adamw
@@ -129,8 +131,7 @@ def run_lm(args):
     assert args.arch, "--arch required for lm workload"
     n_dev = len(jax.devices())
     cfg = smoke(args.arch) if n_dev < 16 else ARCHS[args.arch]
-    mesh = jax.make_mesh((max(1, n_dev // 2), min(n_dev, 2)),
-                         ("data", "model"))
+    mesh = make_mesh((max(1, n_dev // 2), min(n_dev, 2)), ("data", "model"))
     policy = make_policy(mesh, batch=8)
     key = jax.random.key(0)
     params = tf.init_params(key, cfg)

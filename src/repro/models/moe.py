@@ -101,8 +101,6 @@ def moe_ffn_ep(p: MoEParams, cfg: ModelConfig, x: Array,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.partition import shard_map_compat
-
     dp = policy.batch()
     tp = policy.tp
     fs = policy._fs()
@@ -146,7 +144,7 @@ def moe_ffn_ep(p: MoEParams, cfg: ModelConfig, x: Array,
         yk = yk * (keep[:, None] * gate_vals.reshape(T * K)[:, None]).astype(xl.dtype)
         return yk.reshape(T, K, D).sum(1).reshape(Bl, Sl, D)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(P(dp, seq, None), P(None, None),
                   P(tp, fs, None), P(tp, fs, None), P(tp, None, fs)),

@@ -22,15 +22,15 @@ shape buckets; phi enters as an argument, so hot-swapping a same-shape
 snapshot never recompiles.  Working set is O(B*L*K) floats — the engine's
 buckets bound it.
 
-Three interchangeable implementations behind ``impl`` (all draw-identical
-given the same key — same split tree, same uniforms):
+Interchangeable implementations behind ``impl`` (all draw-identical given
+the same key — same split tree, same uniforms):
 
-* ``"xla"``    — the original pure-XLA scan below (re-materializes the
-  per-sweep intermediates each sweep);
+* ``"xla"`` — the pure-jnp sweeps of
+  ``repro.kernels.fold_in.ref`` (re-materializes the per-sweep
+  intermediates each sweep);
 * ``"pallas"`` — ``repro.kernels.fold_in``: one grid step per doc, theta
-  counts + gathered p* rows + the S/Q block sums stay on-chip across all
-  sweeps (interpret mode on CPU);
-* ``"ref"``    — the kernel's pure-jnp oracle, for parity testing.
+  counts + gathered p* rows + the S/Q search tables stay on-chip across all
+  sweeps (compiled on TPU, interpret mode elsewhere).
 
 Everything downstream of the per-token gather consumes only the gathered
 ``(B, L, K)`` phi rows (``_fold_in_rows``), never the full ``(V, K)`` phi.
@@ -55,7 +55,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import sampler, updates
+from repro.kernels import resolve_interpret
 from repro.kernels.fold_in import ops as foldin_ops
 
 Array = jnp.ndarray
@@ -70,7 +70,7 @@ class InferConfig:
     samples: int = 4
     top_k: int = 8
     ell_capacity: int | None = None  # P; None -> min(L, K)
-    impl: str = "xla"                # "xla" | "pallas" | "ref"
+    impl: str = "xla"                # "xla" | "pallas"
     # How a V-sharded snapshot assembles the per-token phi rows:
     #   "psum"    — every shard gathers its owned rows at full (B, L, K) and
     #               a psum assembles them (comm volume B*L*K per device);
@@ -89,15 +89,6 @@ class FoldInResult(NamedTuple):
     top_weights: Array  # (B, top_k) float32 — their theta mass
     sparse_frac: Array  # () — fraction of draws taken on the sparse S side
     mean_s_over_sq: Array  # () — mean S/(S+Q) over real tokens
-
-
-def _theta_counts(z: Array, mask: Array, num_topics: int) -> Array:
-    """(B, L) assignments -> (B, K) per-doc topic counts.
-
-    The training count-rebuild primitive with one "doc" per batch row."""
-    B = z.shape[0]
-    rows = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None], z.shape)
-    return updates.theta_from_z(z, rows, mask, B, num_topics)
 
 
 def _fold_in_rows(
@@ -129,61 +120,19 @@ def _fold_in_rows(
     n_real = jnp.maximum(mask.sum(), 1).astype(jnp.float32)
     denom = n_real * samples
 
-    if impl != "xla":
-        # kernel path (repro.kernels.fold_in): all sweeps fused on-chip,
-        # per-doc partials back; draw-identical to the scan below.
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        tsum, sps, ssqs = foldin_ops.fold_in_sweeps(
-            phi_tok, phi_sum, mask, key, alpha, beta,
+    with jax.named_scope("serve.sweeps"):
+        z0, uniforms = foldin_ops.draw_fold_in_randoms(
+            key, B, L, K, burn_in + samples)
+        # "xla": the pure-jnp sweeps; "pallas": all sweeps fused on-chip
+        # (repro.kernels.fold_in) — draw-identical, per-doc partials back
+        tsum, sps, ssqs = foldin_ops.fold_in_sweeps_drawn(
+            phi_tok, phi_sum, mask, z0, uniforms, alpha, beta,
             num_words_total=num_words_total, burn_in=burn_in,
-            samples=samples, ell_capacity=P, impl=impl, interpret=interpret)
+            samples=samples, ell_capacity=P, impl=impl,
+            interpret=resolve_interpret(interpret))
+    with jax.named_scope("serve.assemble"):
         return _assemble(tsum, sps.sum(), ssqs.sum(), alpha, samples, kk,
                          denom)
-
-    # C7: the Eq. 1 word factor, gathered once per request token and shared
-    # by every sweep (the training sampler's per-tile p*, per-token here).
-    pstar_tok = sampler.pstar(phi_tok, phi_sum, beta,
-                              num_words_total)            # (B, L, K)
-    Q = alpha * pstar_tok.sum(-1)                         # (B, L)
-    flat_pstar = pstar_tok.reshape(B * L, K)
-
-    def sweep(carry, key_i):
-        z, theta = carry  # delayed counts: whole sweep vs sweep-start theta
-        counts, topics = jax.lax.top_k(theta, P)          # (B, P) ELL slice
-        gat = jnp.broadcast_to(topics[:, None, :], (B, L, P))
-        p1 = counts[:, None, :].astype(jnp.float32) * jnp.take_along_axis(
-            pstar_tok, gat, axis=-1)                      # (B, L, P)
-        p1_cum = jnp.cumsum(p1, axis=-1)
-        S = p1_cum[..., -1]                               # (B, L)
-
-        u = foldin_ops.sweep_uniforms(key_i, B, L)
-        use_sparse = u[..., 0] * (S + Q) < S
-        # sparse draw over the P-entry ELL cumsum
-        t_sparse = (u[..., 1] * S)[..., None]
-        j = jnp.minimum((p1_cum <= t_sparse).sum(-1), P - 1)
-        k_sparse = jnp.take_along_axis(topics, j.reshape(B, L), axis=1)
-        # dense draw: the training sampler's two-level blocked search (C5)
-        k_dense = jax.vmap(sampler.blocked_search)(
-            flat_pstar, u[..., 1].reshape(B * L, 1))[:, 0].reshape(B, L)
-
-        z_new = jnp.where(use_sparse, k_sparse, k_dense).astype(jnp.int32)
-        z_new = jnp.where(mask, z_new, z)
-        theta_new = _theta_counts(z_new, mask, K)
-        sp = (use_sparse & mask).sum()
-        ssq = jnp.where(mask, S / jnp.maximum(S + Q, 1e-30), 0.0).sum()
-        return (z_new, theta_new), (theta_new, sp, ssq)
-
-    k_init, k_sweeps = jax.random.split(key)
-    z0 = foldin_ops.init_assignments(k_init, B, L, K)
-    carry = (z0, _theta_counts(z0, mask, K))
-    keys = jax.random.split(k_sweeps, burn_in + samples)
-    with jax.named_scope("serve.sweeps"):
-        carry, _ = jax.lax.scan(sweep, carry, keys[:burn_in])
-        _, (thetas, sps, ssqs) = jax.lax.scan(sweep, carry, keys[burn_in:])
-    with jax.named_scope("serve.assemble"):
-        return _assemble(thetas.sum(0), sps.sum(), ssqs.sum(), alpha,
-                         samples, kk, denom)
 
 
 _STATICS = ("num_words_total", "burn_in", "samples", "top_k", "ell_capacity",
@@ -331,51 +280,6 @@ def fold_in_buffer(
 _SHARDED_JITS: list = []   # every built sharded jit, for cache-size probes
 
 
-def _sweeps_xla_drawn(phi_tok, phi_sum, mask, z0, uniforms, alpha, beta, *,
-                      num_words_total: int, burn_in: int, samples: int,
-                      ell_capacity: int):
-    """Per-doc-partials variant of the XLA scan in ``_fold_in_rows``,
-    consuming pre-drawn randomness.
-
-    The all2all path sweeps only a doc slice, so z0/uniforms are drawn at
-    full batch shape outside and sliced — every op here is per-doc or
-    per-token, so the sliced rows evolve bit-identically to the same rows of
-    the dense scan.  Returns (theta_sum (b, K) int32, sparse (b,) int32,
-    ssq (b,) float32)."""
-    b, L = mask.shape
-    K = phi_sum.shape[0]
-    P = ell_capacity
-    pstar_tok = sampler.pstar(phi_tok, phi_sum, beta, num_words_total)
-    Q = alpha * pstar_tok.sum(-1)
-    flat_pstar = pstar_tok.reshape(b * L, K)
-
-    def sweep(carry, u):
-        z, theta = carry
-        counts, topics = jax.lax.top_k(theta, P)
-        gat = jnp.broadcast_to(topics[:, None, :], (b, L, P))
-        p1 = counts[:, None, :].astype(jnp.float32) * jnp.take_along_axis(
-            pstar_tok, gat, axis=-1)
-        p1_cum = jnp.cumsum(p1, axis=-1)
-        S = p1_cum[..., -1]
-        use_sparse = u[..., 0] * (S + Q) < S
-        t_sparse = (u[..., 1] * S)[..., None]
-        j = jnp.minimum((p1_cum <= t_sparse).sum(-1), P - 1)
-        k_sparse = jnp.take_along_axis(topics, j.reshape(b, L), axis=1)
-        k_dense = jax.vmap(sampler.blocked_search)(
-            flat_pstar, u[..., 1].reshape(b * L, 1))[:, 0].reshape(b, L)
-        z_new = jnp.where(use_sparse, k_sparse, k_dense).astype(jnp.int32)
-        z_new = jnp.where(mask, z_new, z)
-        theta_new = _theta_counts(z_new, mask, K)
-        sp = (use_sparse & mask).astype(jnp.int32).sum(-1)         # (b,)
-        ssq = jnp.where(mask, S / jnp.maximum(S + Q, 1e-30), 0.0).sum(-1)
-        return (z_new, theta_new), (theta_new, sp, ssq)
-
-    carry = (z0, _theta_counts(z0, mask, K))
-    carry, _ = jax.lax.scan(sweep, carry, uniforms[:burn_in])
-    _, (thetas, sps, ssqs) = jax.lax.scan(sweep, carry, uniforms[burn_in:])
-    return thetas.sum(0), sps.sum(0), ssqs.sum(0)
-
-
 @functools.lru_cache(maxsize=None)
 def _sharded_fold_in_fns(mesh, axis: str, num_words_total: int, burn_in: int,
                          samples: int, top_k: int, ell_capacity: int | None,
@@ -399,8 +303,7 @@ def _sharded_fold_in_fns(mesh, axis: str, num_words_total: int, burn_in: int,
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.partition import (doc_slice_bounds,
-                                             doc_slice_owner, route_buckets,
-                                             shard_map_compat)
+                                             doc_slice_owner, route_buckets)
 
     kw = dict(num_words_total=num_words_total, burn_in=burn_in,
               samples=samples, top_k=top_k, ell_capacity=ell_capacity,
@@ -453,20 +356,11 @@ def _sharded_fold_in_fns(mesh, axis: str, num_words_total: int, burn_in: int,
         z0_s = jax.lax.dynamic_slice_in_dim(z0, start, Bs, 0)
         uni_s = jax.lax.dynamic_slice_in_dim(uniforms, start, Bs, 1)
         P_ell = min(ell_capacity or L, L, K)
-        if impl == "xla":
-            tsum, sp, ssq = _sweeps_xla_drawn(
-                phi_tok_s, phi_sum, msk_s, z0_s, uni_s, hyper[0], hyper[1],
-                num_words_total=num_words_total, burn_in=burn_in,
-                samples=samples, ell_capacity=P_ell)
-        else:
-            itp = interpret
-            if itp is None:
-                itp = jax.default_backend() != "tpu"
-            tsum, sp, ssq = foldin_ops.fold_in_sweeps_drawn(
-                phi_tok_s, phi_sum, msk_s, z0_s, uni_s, hyper[0], hyper[1],
-                num_words_total=num_words_total, burn_in=burn_in,
-                samples=samples, ell_capacity=P_ell, impl=impl,
-                interpret=itp)
+        tsum, sp, ssq = foldin_ops.fold_in_sweeps_drawn(
+            phi_tok_s, phi_sum, msk_s, z0_s, uni_s, hyper[0], hyper[1],
+            num_words_total=num_words_total, burn_in=burn_in,
+            samples=samples, ell_capacity=P_ell, impl=impl,
+            interpret=resolve_interpret(interpret))
 
         # --- assemble: per-doc partials home, overlap deduplicated -------
         g_t = jax.lax.all_gather(tsum, axis)               # (S, Bs, K)
@@ -479,10 +373,11 @@ def _sharded_fold_in_fns(mesh, axis: str, num_words_total: int, burn_in: int,
                          min(top_k, K), n_real * samples)
 
     inner = inner_a2a if comm == "all2all" else inner_psum
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(axis), repl, repl, repl, repl, repl, repl, repl),
-        out_specs=FoldInResult(repl, repl, repl, repl, repl))
+        out_specs=FoldInResult(repl, repl, repl, repl, repl),
+        check_vma=False)
 
     def run_tokens(phi_blocks, phi_sum, shard_of, local_id, tokens, mask,
                    key, hyper):

@@ -19,9 +19,9 @@ cfg = trainer.LDAConfig(num_topics=8, tile_tokens=32, tiles_per_step=8, seed=0)
 def test_shard_map_shim_one_step_in_process():
     """Regression for the jax.shard_map import failure: importing
     repro.distributed.partition and running a 1-step 1D iteration through
-    the version-tolerant shim must work on the pinned jax (which only has
-    jax.experimental.shard_map).  Runs in-process on a 1-device mesh — no
-    subprocess, not slow — so CI catches a broken shim immediately."""
+    ``jax.shard_map`` must work on the installed jax.  Runs in-process on a
+    1-device mesh — no subprocess, not slow — so CI catches a broken
+    shard_map call immediately."""
     import jax
     import numpy as np
 
@@ -142,7 +142,8 @@ from repro.models import moe as moe_lib
 from repro.models.common import ShardingPolicy, NO_SHARDING
 cfg = smoke("qwen3-moe-30b-a3b")
 cfg = dataclasses.replace(cfg, capacity_factor=8.0)  # no drops -> exact match
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 policy = ShardingPolicy(dp=("data",), tp="model", enabled=True, mesh=mesh)
 key = jax.random.key(0)
 p = moe_lib.init_moe(key, cfg)
@@ -246,15 +247,14 @@ def test_compressed_sync_heavy_rows_exact_one_device():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.core import sync
-    from repro.distributed.partition import shard_map_compat
-
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     delta = (jnp.zeros((4, 3), jnp.int32)
              .at[1, 2].set(40000).at[2, 0].set(-30000).at[0, 1].set(123))
     heavy = jnp.asarray([1, 2], jnp.int32)
 
     def run(fn):
-        mapped = shard_map_compat(fn, mesh=mesh, in_specs=P(), out_specs=P())
+        mapped = jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                               check_vma=False)
         return np.asarray(jax.jit(mapped)(delta))
 
     wrapped = run(lambda d: sync.compressed_sync_phi(d, ("data",)))
