@@ -1,0 +1,6 @@
+"""Device milliseconds per training iteration under ``lda.sample``."""
+from bench.metrics._common import per_unit_ms
+
+
+def read(reading):
+    return per_unit_ms(reading, "lda.sample", reading.window["iterations"])
