@@ -145,11 +145,15 @@ def init_state(
 
 def _build_theta_ell(cfg: LDAConfig, shard: TiledCorpusShard, z, model_axes):
     K = cfg.num_topics
-    theta = updates.theta_from_z(z, shard.token_doc, shard.token_mask,
-                                 shard.num_docs_local, K)
-    theta = sync.sync_theta(theta, model_axes)
+    # nested under ``lda.plan``: the theta rebuild (scatter and sync) and
+    # the ELL top-k are timed apart in a device profile
+    with jax.named_scope("theta"):
+        theta = updates.theta_from_z(z, shard.token_doc, shard.token_mask,
+                                     shard.num_docs_local, K)
+        theta = sync.sync_theta(theta, model_axes)
     P = cfg.ell_capacity or min(K, int(shard.doc_length.max()) if shard.doc_length.size else K)
-    counts, topics, overflow = updates.theta_to_ell(theta, min(P, K))
+    with jax.named_scope("ell"):
+        counts, topics, overflow = updates.theta_to_ell(theta, min(P, K))
     return theta, counts, topics, overflow
 
 

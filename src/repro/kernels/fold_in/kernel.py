@@ -255,6 +255,7 @@ def fold_in_docs(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="fold_in",
     )(pstar_tok, jnp.reshape(alpha, (1, 1)),
       u1.reshape(nB, n_sweeps, 1, L), u2.reshape(nB, n_sweeps, 1, L),
       mask.reshape(nB, 1, L), z0.reshape(nB, 1, L))

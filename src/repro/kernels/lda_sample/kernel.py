@@ -75,9 +75,10 @@ def _kernel(
     R = row_block(t)
 
     # ---- stage the tile: its p* row and every real token's ELL row ----
-    pstar_copy = pltpu.make_async_copy(pstar_hbm.at[head_ref[0, 0]],
-                                       pstar_scr, sem.at[0])
-    pstar_copy.start()
+    # Three phases carry a ``jax.named_scope``, which Mosaic lowers to a
+    # device trace region (one per grid step): ``lda_sample.issue`` (the
+    # DMA starts), ``lda_sample.wait`` (the drain) and ``lda_sample.rows``
+    # (the row loop).  Regions touch no values, so draws are unchanged.
     n_real = head_ref[0, 1]
 
     def copies(j, doc):
@@ -96,9 +97,14 @@ def _kernel(
             c.wait()
         return carry
 
-    jax.lax.fori_loop(0, n_real, issue, 0)
-    jax.lax.fori_loop(0, n_real, drain, 0)
-    pstar_copy.wait()
+    with jax.named_scope("lda_sample.issue"):
+        pstar_copy = pltpu.make_async_copy(pstar_hbm.at[head_ref[0, 0]],
+                                           pstar_scr, sem.at[0])
+        pstar_copy.start()
+        jax.lax.fori_loop(0, n_real, issue, 0)
+    with jax.named_scope("lda_sample.wait"):
+        jax.lax.fori_loop(0, n_real, drain, 0)
+        pstar_copy.wait()
 
     # C7: p* is computed by XLA for every word (``sampler.pstar``, the same
     # division the XLA sweep makes); C5 tables once per tile
@@ -135,7 +141,8 @@ def _kernel(
 
     # only the row blocks holding real tokens (most tiles of the Zipf tail
     # hold a few); the columns of the others are stale and masked out below
-    jax.lax.fori_loop(0, (n_real + R - 1) // R, rows, 0)
+    with jax.named_scope("lda_sample.rows"):
+        jax.lax.fori_loop(0, (n_real + R - 1) // R, rows, 0)
     mask = mask_ref[...] != 0
     z_new_ref[...] = jnp.where(mask, jnp.transpose(z_col[...]),
                                z_old_ref[...])
@@ -224,6 +231,7 @@ def lda_sample_tiles(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="lda_sample",
     )(tile_head(tile_word, token_doc, token_mask),
       pstar_vk.reshape(V, 1, K), ell_counts.reshape(D, 1, P), ell_topics.reshape(D, 1, P),
       rows(u1), rows(u2), rows(token_mask), rows(z_old))

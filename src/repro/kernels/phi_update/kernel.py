@@ -117,6 +117,7 @@ def _launch(kern, tile_word, tile_first, rows, num_words, num_topics,
         scratch_shapes=scratch,
         out_shape=jax.ShapeDtypeStruct((num_words, 1, num_topics), jnp.int32),
         interpret=interpret,
+        name="phi_delta" if delta else "phi_update",
     )(tile_meta(tile_word.astype(jnp.int32), tile_first),
       *(r.reshape(n, 1, t) for r in rows))
     return out.reshape(num_words, num_topics)

@@ -6,13 +6,14 @@ Three pieces, one bundle:
     fixed-bucket histograms behind a :class:`MetricsRegistry`, rendered as
     Prometheus text exposition (``GET /metrics`` in ``launch/serve_lda``);
   * :mod:`repro.obs.trace` — host phase-span tracing exported as Chrome
-    trace-event JSON (Perfetto-loadable), optionally mirrored into
-    ``jax.profiler.TraceAnnotation`` names;
+    trace-event JSON (Perfetto-loadable), mirrored into
+    ``jax.profiler.TraceAnnotation`` names so profiles hold the spans;
   * :mod:`repro.obs.sink` — per-iteration JSONL rows for training.
 
 :class:`Observability` carries a registry + tracer pair through the engine
-and trainer.  ``Observability.noop()`` is the measured-overhead baseline:
-same call sites, every operation free.
+and trainer (training reads only the tracer; the registry's families are
+the serving engine's, for ``/metrics``).  ``Observability.noop()`` is the
+measured-overhead baseline: same call sites, every operation free.
 """
 from __future__ import annotations
 
@@ -40,7 +41,7 @@ class Observability:
     tracer: SpanTracer
 
     @classmethod
-    def default(cls, trace: bool = True, annotate: bool = False,
+    def default(cls, trace: bool = True, annotate: bool = True,
                 max_events: int = 65536) -> "Observability":
         return cls(registry=MetricsRegistry(),
                    tracer=SpanTracer(enabled=trace, annotate=annotate,

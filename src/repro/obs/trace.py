@@ -8,10 +8,12 @@ are microseconds from the tracer's epoch, and every event carries the real
 pid/tid so multi-threaded phases (the engine worker vs submitters) land on
 separate tracks.
 
-With ``annotate=True`` each span additionally enters a
-``jax.profiler.TraceAnnotation`` of the same name, so when a device profile
-is captured (``jax.profiler.trace``) the host spans line up with the XLA
-rows under identical names.  Device-side phase names inside jitted code come
+By default (``annotate=True``) each span also enters a
+``jax.profiler.TraceAnnotation`` of the same name, so any profile the
+process records (``jax.profiler.trace``) holds the span on the device
+trace's own clock; the profiler's timestamps count from its session, not
+from ``perf_counter``, so the Chrome export alone cannot be lined up with
+a device trace.  Device-side phase names inside jitted code come
 from ``jax.named_scope`` at the call sites (see ``core/trainer`` and
 ``serve/infer``) — pure metadata, so instrumented draws stay bit-identical.
 
@@ -31,7 +33,7 @@ _NULL_CM = contextlib.nullcontext()
 
 
 class SpanTracer:
-    def __init__(self, enabled: bool = True, annotate: bool = False,
+    def __init__(self, enabled: bool = True, annotate: bool = True,
                  max_events: int = 65536, process_name: str = "repro"):
         self.enabled = enabled
         self.annotate = annotate

@@ -8,9 +8,9 @@ with the LL trajectory, tokens/sec and AOT compile time; ``obs=`` /
 result object).  ``fit`` dispatches on ``mesh=`` and gives both paths the
 whole surface:
 
-  * the same per-iteration telemetry (``repro.obs`` counters + histograms,
-    ``sample``/``eval`` host spans, one JSONL row per iteration) — all
-    host-side, so draws are bit-identical to an uninstrumented run;
+  * the same per-iteration telemetry (``sample``/``eval`` host spans, one
+    JSONL row per iteration) — all host-side, so draws are bit-identical
+    to an uninstrumented run;
   * the same AOT-compile accounting (``TrainResult.compile_sec`` excluded
     from ``tokens_per_sec``, mesh path included via
     ``DistributedLDA.compile_step``);
@@ -190,25 +190,16 @@ def _run_loop(cfg, it0, num_iterations, state, compile_step, *, ll_fn,
               ) -> TrainResult:
     """The one training loop both paths share.
 
-    Telemetry is host-side only (``repro.obs``): per-iteration counters and
-    latency histograms in ``obs.registry``, ``sample``/``eval`` phase spans
-    in ``obs.tracer`` (device-side phase names come from the
-    ``jax.named_scope`` annotations inside ``lda_iteration``), and — when
+    Telemetry is host-side only (``repro.obs``): ``sample``/``eval`` phase
+    spans in ``obs.tracer`` (device-side phase names come from the
+    ``jax.named_scope`` annotations inside ``lda_iteration``) and — when
     ``metrics_out`` is given — one JSONL row per iteration.  None of it
     touches keys or traced values, so draws are bit-identical to an
     uninstrumented run (pinned in tests/test_obs.py).
     """
-    from repro.obs import JsonlSink, NULL_SINK, Observability
+    from repro.obs import JsonlSink, NULL_SINK, NULL_TRACER
 
-    obs = obs if obs is not None else Observability.default(trace=False)
-    reg, tracer = obs.registry, obs.tracer
-    m_iters = reg.counter("repro_train_iterations_total", "sweeps completed")
-    m_tokens = reg.counter("repro_train_tokens_sampled_total",
-                           "tokens resampled (iterations * corpus tokens)")
-    m_iter_ms = reg.histogram("repro_train_iteration_ms",
-                              "wall time per training iteration")
-    g_tps = reg.gauge("repro_train_tokens_per_sec", "last iteration's rate")
-    g_ll = reg.gauge("repro_train_ll_per_token", "last evaluated joint LL")
+    tracer = obs.tracer if obs is not None else NULL_TRACER
     sink = JsonlSink(metrics_out) if metrics_out else NULL_SINK
 
     step, compile_sec = compile_step(tracer)
@@ -230,16 +221,11 @@ def _run_loop(cfg, it0, num_iterations, state, compile_step, *, ll_fn,
             tps.append(num_tokens / dt)
             st.append((float(stats.sparse_frac), float(stats.ell_overflow),
                        float(stats.mean_s_over_sq)))
-            m_iters.inc()
-            m_tokens.inc(num_tokens)
-            m_iter_ms.observe(dt * 1e3)
-            g_tps.set(tps[-1])
             ll = None
             if (it + 1) % eval_every == 0 or it == num_iterations - 1:
                 with tracer.span("eval", iteration=it):
                     ll = float(ll_fn(state))
                 lls.append(ll)
-                g_ll.set(ll)
                 if verbose:
                     print(f"iter {it + 1:5d}  {tps[-1] / 1e6:7.2f}M tok/s  "
                           f"LL/token {ll:.4f}  "

@@ -204,6 +204,32 @@ class TestSpanTracer:
         assert [e for e in tr.to_chrome()["traceEvents"]
                 if e["ph"] != "M"] == []
 
+    def test_spans_land_in_a_profile(self, tmp_path):
+        """Every enabled span also enters a TraceAnnotation, so a profile
+        recorded meanwhile holds it on the host plane, on the device
+        trace's clock, where the benchmark's reduction finds it."""
+        import importlib
+        import pathlib
+        import sys
+
+        import jax
+
+        root = str(pathlib.Path(__file__).resolve().parents[1])
+        if root not in sys.path:
+            sys.path.insert(0, root)
+        bench_trace = importlib.import_module("bench.trace")
+        tr = SpanTracer()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span("obs.probe.span"):
+                jax.numpy.ones(4).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        data = next(tmp_path.rglob("*.xplane.pb")).read_bytes()
+        summary = bench_trace.from_xspace(data, [])
+        found = [s for s in summary.spans if s.name == "obs.probe.span"]
+        assert len(found) == 1 and found[0].end > found[0].start
+
     def test_ring_buffer_bounds_memory(self):
         tr = SpanTracer(enabled=True, max_events=16)
         for i in range(100):

@@ -66,6 +66,49 @@ def test_lda_sample_compiles_for_v5e(one_chip):
              ((N_TILES, T), i32), ((N_TILES, T), i32))
 
 
+def _mosaic_trace_regions(lowered_text: str) -> list[str]:
+    """The ``trace_start`` messages of every Mosaic kernel body in a lowered
+    program, in order (each body rides base64-encoded in its custom call)."""
+    import base64
+    import re
+
+    from jaxlib.mlir import ir
+
+    out = []
+    # backend_config = "{\22custom_call_config\22: {\22body\22: \22<base64>..."
+    for m in re.finditer(r"body\\22: \\22([A-Za-z0-9+/=]+)", lowered_text):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(m.group(1)))
+
+            def walk(op):
+                if op.name.endswith("trace_start"):
+                    out.append(ir.StringAttr(op.attributes["message"]).value)
+                return ir.WalkResult.ADVANCE
+
+            module.operation.walk(walk)
+    return out
+
+
+def test_lda_sample_lowers_with_three_trace_regions(one_chip):
+    """The NYTimes-width sampler carries its three device trace regions:
+    the DMA starts, the drain and the row loop, one per grid step."""
+    def sweep(tw, td, pstar, cnt, tpc, u1, u2, mask, z):
+        return sample_kernel.lda_sample_tiles(
+            tw, td, pstar, cnt, tpc, u1, u2, mask, z, alpha=50.0 / K,
+            interpret=False)
+
+    i32, f32 = jnp.int32, jnp.float32
+    shapes = [((N_TILES,), i32), ((N_TILES, T), i32), ((V, K), f32),
+              ((D, P), i32), ((D, P), i32),
+              ((N_TILES, T), f32), ((N_TILES, T), f32),
+              ((N_TILES, T), i32), ((N_TILES, T), i32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(sweep).lower(*args).as_text()
+    assert _mosaic_trace_regions(text) == [
+        "lda_sample.issue", "lda_sample.wait", "lda_sample.rows"]
+
+
 def test_fold_in_compiles_for_v5e(one_chip):
     B, L, sweeps = 8, 512, 12           # a NYTimes-length bucket, 8+4 sweeps
 
