@@ -87,6 +87,8 @@ DECLARED: dict[tuple[str, str, str], str] = {
         "index-topic-bound",
     ("src/repro/kernels/lanes.py", "gather_lanes", "DT003"):
         "index-topic-bound",
+    ("src/repro/kernels/lda_sample/kernel.py", "table_rows", "DT003"):
+        "index-topic-bound",
 }
 
 

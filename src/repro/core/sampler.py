@@ -40,6 +40,8 @@ class SamplerStats(NamedTuple):
 
     sparse_frac: Array  # fraction of tokens drawn from p1 (sparsity hit rate)
     mean_s_over_sq: Array  # mean over tokens of S/(S+Q) — sparse mass share
+    row_width_share: Array  # mean over row blocks of the ELL lanes sampled
+    #                         over the padded width; 1 where all are
 
 
 def pstar(phi_col: Array, phi_sum: Array, beta: float, num_words_total: int) -> Array:
@@ -63,7 +65,8 @@ def pick_search_block(K: int) -> int:
     return SEARCH_BLOCK if K % SEARCH_BLOCK == 0 else _pick_block(K)
 
 
-def prefix_sum(x: Array, block: int | None = None, roll=jnp.roll) -> Array:
+def prefix_sum(x: Array, block: int | None = None, roll=jnp.roll, *,
+               stop: int | None = None) -> Array:
     """Inclusive prefix sum along the last axis, as log2(n) shifted adds.
 
     The Hillis-Steele form: step ``d`` adds the element ``d`` lanes back.
@@ -75,8 +78,14 @@ def prefix_sum(x: Array, block: int | None = None, roll=jnp.roll) -> Array:
 
     ``block`` restarts the sum every ``block`` lanes (a segmented scan over
     contiguous blocks of the last axis).
+
+    ``stop`` (a power of two) runs only the steps ``d < stop``: each lane
+    then holds the sum of its ``stop``-lane window (``lda_sample`` runs the
+    later steps over whole 128-lane chunks).
     """
     width = x.shape[-1] if block is None else block
+    if stop is not None:
+        width = min(width, stop)
     lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
     if block is not None:
         lane = lane % block
@@ -270,5 +279,6 @@ def sample_sweep(
     stats = SamplerStats(
         sparse_frac=sp_counts.sum() / total,
         mean_s_over_sq=ssq_sums.sum() / total,
+        row_width_share=jnp.float32(1),
     )
     return z_new, stats

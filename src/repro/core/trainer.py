@@ -111,6 +111,7 @@ class IterStats(NamedTuple):
     sparse_frac: Array
     ell_overflow: Array  # docs exceeding ELL capacity (0 in exact mode)
     mean_s_over_sq: Array  # mean S/(S+Q) sparse mass share (sq sampler only)
+    row_width_share: Array  # mean sampled ELL lanes / padded width (pallas)
 
 
 def state_from_z(
@@ -205,6 +206,7 @@ def lda_iteration(
                     **sweep_kwargs)
             sparse_frac = stats.sparse_frac
             mean_ssq = stats.mean_s_over_sq
+            width_share = stats.row_width_share
         elif cfg.sampler == "pallas":
             from ..kernels.lda_sample import ops as lda_kernel
             with jax.named_scope("lda.sample"):
@@ -214,6 +216,7 @@ def lda_iteration(
                     impl="pallas", interpret=interpret, **sweep_kwargs)
             sparse_frac = stats.sparse_frac
             mean_ssq = stats.mean_s_over_sq
+            width_share = stats.row_width_share
         else:
             with jax.named_scope("lda.sample"):
                 z_new = dense_sampler.sample_sweep_dense(
@@ -222,6 +225,7 @@ def lda_iteration(
                     tiles_per_step=min(cfg.tiles_per_step, n), **sweep_kwargs)
             sparse_frac = jnp.float32(0)
             mean_ssq = jnp.float32(0)
+            width_share = jnp.float32(1)
     else:  # WorkSchedule2: M micro-chunks, theta refreshed between chunks
         n_pad = -n % M
         tw_a, td_a, tm_a, z_a = shard.tile_word, shard.token_doc, shard.token_mask, state.z
@@ -247,7 +251,7 @@ def lda_iteration(
             keys_m = jax.random.split(key, M)
             theta_c = theta
             phi_acc = jnp.zeros_like(state.phi_vk) if overlap else None
-            z_parts, sfs_l, ssqs_l = [], [], []
+            z_parts, sfs_l, ssqs_l, ws_l = [], [], [], []
             for m in range(M):
                 sl = slice(m * nc, (m + 1) * nc)
                 cnts, tpcs = jax.lax.top_k(theta_c, P)
@@ -269,9 +273,11 @@ def lda_iteration(
                 z_parts.append(z_c)
                 sfs_l.append(st.sparse_frac)
                 ssqs_l.append(st.mean_s_over_sq)
+                ws_l.append(st.row_width_share)
             z_new = jnp.concatenate(z_parts)[:n]
             sparse_frac = jnp.stack(sfs_l).mean()
             mean_ssq = jnp.stack(ssqs_l).mean()
+            width_share = jnp.stack(ws_l).mean()
         else:
             def chunk_step(carry, inp):
                 theta_c, phi_acc = carry if overlap else (carry, None)
@@ -314,6 +320,7 @@ def lda_iteration(
             z_new = z_chunks.reshape(n + n_pad, t)[:n]
             sparse_frac = sfs.mean()
             mean_ssq = ssqs.mean()
+            width_share = jnp.float32(1)
 
     if M > 1 and cfg.sync_overlap:
         # the per-chunk syncs above already hold the whole iteration's
@@ -327,7 +334,8 @@ def lda_iteration(
                              iteration=state.iteration + 1)
         return new_state, IterStats(sparse_frac=sparse_frac,
                                     ell_overflow=overflow.sum(),
-                                    mean_s_over_sq=mean_ssq)
+                                    mean_s_over_sq=mean_ssq,
+                                    row_width_share=width_share)
 
     # incremental phi advance + reduce/broadcast (C3): one scatter/MXU pass
     # over the sweep's moves instead of a full count rebuild (and instead of
@@ -357,7 +365,8 @@ def lda_iteration(
                          iteration=state.iteration + 1)
     return new_state, IterStats(sparse_frac=sparse_frac,
                                 ell_overflow=overflow.sum(),
-                                mean_s_over_sq=mean_ssq)
+                                mean_s_over_sq=mean_ssq,
+                                row_width_share=width_share)
 
 
 def log_likelihood(
@@ -391,7 +400,8 @@ class TrainResult:
     state: LDAState
     ll_per_token: list[float]
     tokens_per_sec: list[float]
-    stats: list[tuple[float, float, float]]  # (sparse_frac, ell_overflow, S/(S+Q))
+    # (sparse_frac, ell_overflow, S/(S+Q), row_width_share)
+    stats: list[tuple[float, float, float, float]]
     compile_sec: float = 0.0  # jit compile time, excluded from tokens_per_sec
     cfg: LDAConfig | None = None  # the resolved config actually trained with
 

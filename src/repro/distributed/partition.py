@@ -369,7 +369,8 @@ class DistributedLDA:
             iteration=repl,
         )
         stats_specs = core_trainer.IterStats(sparse_frac=repl, ell_overflow=repl,
-                                             mean_s_over_sq=repl)
+                                             mean_s_over_sq=repl,
+                                             row_width_share=repl)
 
         d_ax = doc_axes if mode == "2d" else lead
         m_ax = word_axes if mode == "2d" else None
@@ -410,6 +411,7 @@ class DistributedLDA:
                 ell_overflow=jax.lax.psum(stats.ell_overflow, all_ax)
                 // (n_word if mode == "2d" else 1),
                 mean_s_over_sq=jax.lax.pmean(stats.mean_s_over_sq, all_ax),
+                row_width_share=jax.lax.pmean(stats.row_width_share, all_ax),
             )
             return st, stats
 

@@ -3,7 +3,10 @@
 The cases re-derive the launch geometry from ``kernel.grid_layout`` (the
 same call ``lda_sample_tiles`` launches from) and run the kernel's own
 ``tile_head`` over real tilings, so the checker sees the SMEM header the
-kernel's DMA loop reads: word id, real-token count and doc ids.
+kernel's DMA loop reads: word id, real-token count and doc ids.  Each case
+also runs its first tiles through the kernel (interpret mode) over an ELL
+of heavy-tailed live-topic counts and checks the width each row block
+sampled.
 """
 from __future__ import annotations
 
@@ -11,12 +14,14 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.analysis.contracts import ContractCase, KernelContract, Operand
+from repro.kernels.lanes import row_block
 from repro.kernels.lda_sample import kernel
 
 # Declared VMEM blocks + scratch (the two (t, 1, P) per-token ELL tables
 # dominate: 8 MiB at t=256, P=512); the kernel raises Mosaic's scoped limit
 # to ``kernel.VMEM_LIMIT_BYTES`` for its body's temporaries.
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+WIDTH_TILES = 2  # tiles of a case run through the kernel for its widths
 
 
 def _case(name: str, *, n: int, t: int, V: int, K: int, D: int, P: int,
@@ -80,6 +85,38 @@ def _build(name: str, token_doc: np.ndarray, tile_word: np.ndarray,
             msgs.append("a fetched doc or word row lies outside its table")
         return msgs
 
+    def block_widths():
+        # each row block holding a real token samples the smallest whole-
+        # vreg width that covers its real tokens' documents' live topics
+        # (and no more than the padded P); the others sample nothing
+        m = min(n, WIDTH_TILES)
+        Pp = -(-P // kernel.LANES) * kernel.LANES
+        live = np.minimum(P, 1 + (np.arange(D) * 97) % (2 * P))
+        counts = (np.arange(P) < live[:, None]).astype(np.int32)
+        topics = np.broadcast_to(np.arange(P, dtype=np.int32) % K, (D, P))
+        rng = np.random.default_rng(0)
+        u = rng.random((2, m, t), dtype=np.float32)
+        *_, widths = kernel.lda_sample_tiles(
+            jnp.asarray(tile_word[:m]), jnp.asarray(token_doc[:m]),
+            jnp.asarray(rng.random((V, K), dtype=np.float32)),
+            jnp.asarray(counts), jnp.asarray(topics), jnp.asarray(u[0]),
+            jnp.asarray(u[1]), jnp.asarray(token_mask[:m], np.int32),
+            jnp.zeros((m, t), jnp.int32), alpha=0.1, interpret=True)
+        R = row_block(t)
+        real = token_mask[:m].astype(bool)
+        need = np.where(real, live[token_doc[:m]], 0).reshape(m, -1, R)
+        want = np.where(real.reshape(m, -1, R).any(2),
+                        np.maximum(-(-need.max(2) // kernel.LANES), 1)
+                        * kernel.LANES, 0)
+        widths = np.asarray(widths)
+        msgs = []
+        if (widths % kernel.LANES).any() or (widths > Pp).any():
+            msgs.append("a row block's width is not whole vregs within P")
+        if not np.array_equal(widths, want):
+            msgs.append("a row block's width is not the smallest one "
+                        "covering its real tokens' live topics")
+        return msgs
+
     row = (n, 1, t)
     in_shapes = [
         Operand("head", (n, 1, kernel.HEAD + t), jnp.int32, in_specs[0]),
@@ -95,13 +132,14 @@ def _build(name: str, token_doc: np.ndarray, tile_word: np.ndarray,
         Operand("z_new", row, jnp.int32, out_specs[0]),
         Operand("sparse", row, jnp.int32, out_specs[1]),
         Operand("ssq", row, jnp.float32, out_specs[2]),
+        Operand("width", (n, 1, kernel.LANES), jnp.int32, out_specs[3]),
     ]
     return ContractCase(
         name=name, grid=grid,
         inputs=tuple(in_shapes), outputs=tuple(out_shapes),
         scratch=tuple(scratch),
-        coverage=("z_new", "sparse", "ssq"),
-        extra_checks=(header_round_trip,))
+        coverage=("z_new", "sparse", "ssq", "width"),
+        extra_checks=(header_round_trip, block_widths))
 
 
 def contract() -> KernelContract:
@@ -110,8 +148,12 @@ def contract() -> KernelContract:
         vmem_budget_bytes=VMEM_BUDGET_BYTES,
         cases=(
             _case("tiny", n=8, t=16, V=12, K=32, D=6, P=4),
-            # the NYTimes width: K=1024, 256-token tiles, ELL width 512
+            # NYTimes-like: K=1024, 256-token tiles, ELL width 512
             _case("paper", n=128, t=256, V=512, K=1024, D=2048, P=512),
+            # the PubMed cell's ELL width, 809 padded to 896 (7 row bodies),
+            # in 128-token tiles: at 256 the two ELL tables alone count
+            # 14 MiB here, over the budget
+            _case("pubmed", n=128, t=128, V=512, K=1024, D=2048, P=896),
             # one real 2d-partition shard: local vocab rows, irregular doc
             # subset, padding tiles
             _shard_case("shard2d", K=48, P=6),

@@ -23,7 +23,23 @@ shared ``sampler.prefix_sum`` (shifted adds via ``pltpu.roll``) and gathers
 by exact selects: p*[topic] is a 128-lane ``take_along_axis`` per 128-topic
 block, a per-token pick is a masked lane sum.  Every float op matches
 ``repro.core.sampler.sample_one_tile`` one for one, so draws are
-bit-identical to the ``"sq"`` sweep wherever both run on the same backend.
+bit-identical to the ``"sq"`` sweep wherever both run on the same backend
+(but for the one draw below).
+
+Row work follows each row block's documents, not the ELL width P.  The
+ELL left-packs a document's live topics (``top_k``), so every lane past the
+last non-zero count is an exact zero.  Each block of R tokens first takes
+the live extent of its real rows (one past their last non-zero count)
+rounded up to whole 128-lane vregs, W; one static body per width then runs
+the gather, prefix sum and sparse search over W lanes, reading its rows by
+strided loads (``table_rows``) so that each vreg holds 8 rows of one
+128-lane chunk and the work is W/128 vregs per 8 rows.  Hillis-Steele is
+causal, so lanes below W of the W-lane prefix sum equal the P-lane one bit
+for bit, and the total S = p1_cum[P-1] is rebuilt under the full sum's
+association (``chunk_prefix``).  A draw can differ in one case only: where
+u2*S lies within an ulp of S, the P-lane search may count a lane past W (a
+zero-count slot past the document's topics), which the W-lane search
+cannot.  ``row_width_share`` reports the mean W / P.
 
 Array layout: per-tile rows are ``(n, 1, t)`` and tables ``(rows, 1, width)``
 so that every block or DMA takes whole trailing dims (the TPU's (8, 128)
@@ -43,6 +59,40 @@ from repro.kernels.lanes import (VMEM_LIMIT_BYTES, dense_draw, gather_lanes,
                                   lane_pick, row_block, search_rows)
 
 HEAD = 128  # SMEM header lanes before a tile's doc ids: [word, n_real, 0...]
+LANES = 128
+
+
+def chunk_prefix(win, P: int):
+    """The prefix sum over P lanes of rows that are zero past lane W, from
+    ``win`` (R, W): their first W lanes after the prefix-sum steps below
+    128 (``prefix_sum(x, stop=128)``), each lane the sum of its 128-lane
+    window.  Returns ``(p1_cum, last)``: the sum's first W lanes, and an
+    (R, 128) chunk whose last lane is the sum's last lane, the total S, bit
+    for bit.
+
+    The later steps (shifts of 128 lanes and more) add whole chunks, so
+    they run here as adds of (R, 128) chunks.  The last lane of a chunk
+    past W is an exact zero: an add of one is skipped, which leaves the
+    same bits, and only the chunks the outputs need are computed.  The
+    W-lane sum's own last lane can differ from the P-lane total in the last
+    bit whenever W does not divide P; ``last`` cannot."""
+    n = P // LANES
+    chunks = [win[:, a:a + LANES] for a in range(0, win.shape[1], LANES)]
+
+    @functools.cache
+    def chunk(c, d):   # chunk c after the steps of shift below d chunks
+        if d == 1:
+            return chunks[c] if c < len(chunks) else None
+        own, back = chunk(c, d // 2), (
+            chunk(c - d // 2, d // 2) if c >= d // 2 else None)
+        if own is None or back is None:
+            return back if own is None else own
+        return own + back
+
+    top = 1 << (n - 1).bit_length()   # the steps run while the shift < n
+    cum = [chunk(c, top) for c in range(len(chunks))]
+    p1_cum = cum[0] if len(cum) == 1 else jnp.concatenate(cum, axis=1)
+    return p1_cum, chunk(n - 1, top)
 
 
 def _kernel(
@@ -57,6 +107,7 @@ def _kernel(
     z_new_ref,      # out (1, t) int32
     sparse_ref,     # out (1, t) int32 — drew from p1?
     ssq_ref,        # out (1, t) float32 — per-token S/(S+Q), 0 on pads
+    width_ref,      # out (1, LANES) int32 — each row block's W, 0 if unsampled
     tok_cnt,        # VMEM (t, 1, P) int32 — each token's ELL counts
     tok_tpc,        # VMEM (t, 1, P) int32 — ... and topics
     pstar_scr,      # VMEM (1, K) float32 — the tile's p* row
@@ -111,32 +162,43 @@ def _kernel(
     bcum, total, nb = search_rows(pstar_scr[...], local_scr)
     Q = alpha * total                                             # (1, 1)
 
-    # C4 sparse side over each token's ELL row, R tokens at a time (one
-    # fixed-size body keeps the kernel small).  Rows past n_real hold stale
-    # data; everything is row-local and masked out at the end.
+    # C4 sparse side over each token's ELL row, R tokens at a time, over
+    # the block's width (one static body per width).  Rows past n_real hold
+    # stale data; everything is row-local and masked out at the end.
     u1_col[...] = jnp.transpose(u1_ref[...])                      # (t, 1)
     u2_col[...] = jnp.transpose(u2_ref[...])
+    width_ref[...] = jnp.zeros_like(width_ref)
+    block_lane = jax.lax.broadcasted_iota(jnp.int32, width_ref.shape, 1)
 
-    def rows(r, carry):
-        r0 = pl.multiple_of(r * R, R)
+    def block(r, r0, W):
         sl = pl.ds(r0, R)
-        cnt = tok_cnt[sl].reshape(R, P).astype(jnp.float32)
-        tpc = tok_tpc[sl].reshape(R, P)
-        p1 = cnt * gather_lanes(pstar_scr, tpc)                   # (R, P)
-        p1_cum = prefix_sum(p1, roll=pltpu.roll)
-        S = lane_pick(p1_cum, jnp.full((R, 1), P - 1, jnp.int32))  # (R, 1)
+        cnt = table_rows(tok_cnt, r0, R, W).astype(jnp.float32)
+        tpc = table_rows(tok_tpc, r0, R, W)
+        p1 = cnt * gather_lanes(pstar_scr, tpc)                   # (R, W)
+        p1_cum, last = chunk_prefix(
+            prefix_sum(p1, roll=pltpu.roll, stop=LANES), P)
+        S = lane_pick(last, jnp.full((R, 1), LANES - 1, jnp.int32))  # (R, 1)
         u1, u2 = u1_col[sl], u2_col[sl]
         use_sparse = u1 * (S + Q) < S
-        # sparse draw: search the P-entry prefix sums
+        # sparse draw: search the W-entry prefix sums
         jj = jnp.minimum(
             jnp.sum((p1_cum <= u2 * S).astype(jnp.int32), -1, keepdims=True),
-            P - 1)
+            W - 1)
         k_sparse = lane_pick(tpc, jj)
         # dense draw: two-level blocked search (C5)
         k_dense = dense_draw(local_scr, bcum, nb, u2 * total)
         z_col[sl] = jnp.where(use_sparse, k_sparse, k_dense)
         sp_col[sl] = use_sparse.astype(jnp.int32)
         ssq_col[sl] = S / jnp.maximum(S + Q, 1e-30)
+        width_ref[...] = jnp.where(block_lane == r, W, width_ref[...])
+
+    def rows(r, carry):
+        r0 = pl.multiple_of(r * R, R)
+        extent = live_extent(table_rows(tok_cnt, r0, R, P), n_real - r0)
+        for W in range(LANES, P + 1, LANES):
+            fits = extent <= W if W == LANES else (
+                (extent > W - LANES) & (extent <= W))
+            pl.when(fits)(functools.partial(block, r, r0, W))
         return carry
 
     # only the row blocks holding real tokens (most tiles of the Zipf tail
@@ -164,7 +226,8 @@ def grid_layout(n: int, t: int, K: int, P: int):
         hbm, hbm,                                         # ELL counts/topics
         row, row, row, row,                               # u1, u2, mask, z
     ]
-    out_specs = [row, row, row]
+    out_specs = [row, row, row,
+                 pl.BlockSpec((None, 1, LANES), lambda i: (i, 0, 0))]
     scratch_shapes = [
         pltpu.VMEM((t, 1, P), jnp.int32),
         pltpu.VMEM((t, 1, P), jnp.int32),
@@ -178,6 +241,39 @@ def grid_layout(n: int, t: int, K: int, P: int):
         pltpu.SemaphoreType.DMA((3,)),
     ]
     return (n,), in_specs, out_specs, scratch_shapes
+
+
+def table_rows(ref, r0, R: int, W: int):
+    """Rows ``r0 .. r0+R`` of a (t, 1, P) VMEM table, their first W lanes,
+    as an (R, W) array.  Each 128-lane chunk is one strided load from the
+    table's (t * P/128, 128) view, so a vreg holds 8 rows of one chunk; an
+    (R, 1, W) load holds one row a vreg, whatever W, and the row work
+    would then cost R vregs an op at every W up to 1024."""
+    t, _, P = ref.shape
+    n = P // LANES
+    flat = ref.reshape(t * n, LANES)
+    parts = [flat[pl.ds(r0 * n + c, R, stride=n), :]
+             for c in range(W // LANES)]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def live_extent(cnt, n_rows):
+    """One past the last non-zero count of the first ``n_rows`` rows of the
+    (R, P) ELL counts ``cnt``: the lanes those rows' documents use (their
+    live-topic count, for a ``top_k`` ELL).  Later rows are ignored."""
+    row = jax.lax.broadcasted_iota(jnp.int32, cnt.shape, 0)
+    # counts are >= 0: a lane is live in some row iff its row max is > 0
+    most = jnp.max(jnp.where(row < n_rows, cnt, 0), axis=0, keepdims=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, most.shape, 1)
+    return jnp.max(jnp.where(most > 0, lane + 1, 0))
+
+
+def row_width_share(widths, ell_width: int):
+    """Mean over the sampled row blocks (``widths`` > 0, as
+    ``lda_sample_tiles`` returns them) of W over the padded ELL width."""
+    P = -(-ell_width // LANES) * LANES
+    sampled = widths > 0
+    return jnp.sum(widths) / P / jnp.maximum(sampled.sum(), 1)
 
 
 def tile_head(tile_word, token_doc, token_mask):
@@ -204,16 +300,20 @@ def lda_sample_tiles(
     interpret: bool,
 ):
     """pallas_call wrapper: grid over word tiles.  Returns (z_new, sparse,
-    ssq), all (n, t).
+    ssq), all (n, t), and each row block's width ``(n, t // R)``: the ELL
+    lanes it sampled, 0 for a block past the tile's real tokens.
 
     The ELL width is padded to whole 128-lane vregs: the extra entries have
     count 0, add exact zeros to every prefix sum and are never drawn."""
     n, t = z_old.shape
     V, K = pstar_vk.shape
-    pad = -ell_counts.shape[1] % 128
+    pad = -ell_counts.shape[1] % LANES
     ell_counts = jnp.pad(ell_counts, ((0, 0), (0, pad)))
     ell_topics = jnp.pad(ell_topics, ((0, 0), (0, pad)))
     D, P = ell_counts.shape
+    nb = t // row_block(t)
+    if nb > LANES:
+        raise ValueError(f"{nb} row blocks a tile; at most {LANES}")
     grid, in_specs, out_specs, scratch_shapes = grid_layout(n, t, K, P)
     kern = functools.partial(_kernel, alpha=alpha)
     rows = lambda a: a.reshape(n, 1, t)  # noqa: E731
@@ -227,6 +327,7 @@ def lda_sample_tiles(
             jax.ShapeDtypeStruct((n, 1, t), jnp.int32),
             jax.ShapeDtypeStruct((n, 1, t), jnp.int32),
             jax.ShapeDtypeStruct((n, 1, t), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1, LANES), jnp.int32),
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
@@ -235,4 +336,6 @@ def lda_sample_tiles(
     )(tile_head(tile_word, token_doc, token_mask),
       pstar_vk.reshape(V, 1, K), ell_counts.reshape(D, 1, P), ell_topics.reshape(D, 1, P),
       rows(u1), rows(u2), rows(token_mask), rows(z_old))
-    return tuple(o.reshape(n, t) for o in out)
+    z_new, sparse, ssq, widths = out
+    return (z_new.reshape(n, t), sparse.reshape(n, t), ssq.reshape(n, t),
+            widths.reshape(n, LANES)[:, :nb])

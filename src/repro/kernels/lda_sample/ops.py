@@ -47,13 +47,16 @@ def lda_sample(
     if impl == "pallas":
         # p* of every word, by the same XLA division the sq sweep makes
         pstar_vk = pstar(phi_vk, phi_sum, beta, num_words_total)
-        z_new, sparse, ssq = kernel.lda_sample_tiles(
+        z_new, sparse, ssq, widths = kernel.lda_sample_tiles(
             tw, td, pstar_vk, *rest, alpha=alpha, interpret=interpret)
+        width_share = kernel.row_width_share(widths, ell_counts.shape[1])
     else:
         z_new, sparse, ssq = ref.lda_sample_tiles_ref(
             tw, td, phi_vk.astype(jnp.int32), phi_sum.astype(jnp.int32),
             *rest, alpha=alpha, beta=beta, num_words_total=num_words_total)
+        width_share = jnp.float32(1)
     total = jnp.maximum(token_mask.sum(), 1)
     stats = SamplerStats(sparse_frac=sparse.sum() / total,
-                         mean_s_over_sq=ssq.sum() / total)
+                         mean_s_over_sq=ssq.sum() / total,
+                         row_width_share=width_share)
     return z_new.astype(z.dtype), stats

@@ -206,7 +206,7 @@ def _run_loop(cfg, it0, num_iterations, state, compile_step, *, ll_fn,
 
     lls: list[float] = []
     tps: list[float] = []
-    st: list[tuple[float, float, float]] = []
+    st: list[tuple[float, float, float, float]] = []
     try:
         for it in range(it0, num_iterations):
             t0 = time.perf_counter()
@@ -220,7 +220,8 @@ def _run_loop(cfg, it0, num_iterations, state, compile_step, *, ll_fn,
             dt = time.perf_counter() - t0
             tps.append(num_tokens / dt)
             st.append((float(stats.sparse_frac), float(stats.ell_overflow),
-                       float(stats.mean_s_over_sq)))
+                       float(stats.mean_s_over_sq),
+                       float(stats.row_width_share)))
             ll = None
             if (it + 1) % eval_every == 0 or it == num_iterations - 1:
                 with tracer.span("eval", iteration=it):
@@ -230,12 +231,14 @@ def _run_loop(cfg, it0, num_iterations, state, compile_step, *, ll_fn,
                     print(f"iter {it + 1:5d}  {tps[-1] / 1e6:7.2f}M tok/s  "
                           f"LL/token {ll:.4f}  "
                           f"sparse {st[-1][0]:.2f}  "
+                          f"W/P {st[-1][3]:.2f}  "
                           f"S/(S+Q) {st[-1][2]:.2f}")
                 if callback:
                     callback(it, state, ll)
             sink.write(dict(iteration=it, seconds=dt,
                             tokens=num_tokens, tokens_per_sec=tps[-1],
-                            sparse_frac=st[-1][0], ell_overflow=st[-1][1],
+                            sparse_frac=st[-1][0],
+                            row_width_share=st[-1][3], ell_overflow=st[-1][1],
                             mean_s_over_sq=st[-1][2], ll_per_token=ll))
             if (save_fn is not None and checkpoint_every
                     and (it + 1) % checkpoint_every == 0):

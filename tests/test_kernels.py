@@ -110,9 +110,156 @@ def test_lda_sample_matches_core_sampler():
     np.testing.assert_array_equal(np.asarray(zc), np.asarray(zk))
 
 
+def heavy_tail_case(K, classes, tile_tokens=64, seed=0):
+    """Documents in classes of ``(docs, length)``, each class over words of
+    its own, so the word tiles of short documents sample narrow row blocks
+    and those of long ones wide blocks.  The ELL width is
+    ``ell_capacity``: min(K, the longest document) in whole vregs."""
+    from repro.core.corpus import Corpus
+    rng = np.random.default_rng(seed)
+    docs, words, d0 = [], [], 0
+    for c, (n_docs, length) in enumerate(classes):
+        docs.append(np.repeat(np.arange(d0, d0 + n_docs), length))
+        words.append(rng.integers(8 * c, 8 * c + 8, n_docs * length))
+        d0 += n_docs
+    corpus = Corpus(np.concatenate(docs).astype(np.int32),
+                    np.concatenate(words).astype(np.int32), d0,
+                    8 * len(classes))
+    shard = tile_corpus(corpus, 1, tile_tokens)[0]
+    n, t = shard.token_doc.shape
+    key = jax.random.key(seed)
+    z = jax.random.randint(key, (n, t), 0, K, jnp.int32).astype(jnp.int16)
+    phi = updates.phi_from_z(z, shard.tile_word, shard.token_mask,
+                             corpus.num_words, K)
+    theta = updates.theta_from_z(z, shard.token_doc, shard.token_mask,
+                                 shard.num_docs_local, K)
+    cnts, tpcs, _ = updates.theta_to_ell(theta, ell_capacity(corpus, K))
+    return corpus, shard, z, phi, phi.sum(0), cnts, tpcs, key
+
+
+def expected_widths(token_doc, token_mask, ell_counts, R=32):
+    """Each row block's width by hand: the smallest multiple of 128 lanes
+    holding the live topics (one past the last non-zero count) of its real
+    tokens' documents; 0 for a block with no real token."""
+    cnt = np.asarray(ell_counts)
+    extent = np.max(np.where(cnt > 0, np.arange(cnt.shape[1]) + 1, 0), 1)
+    mask = np.asarray(token_mask) != 0
+    live = np.where(mask, extent[np.asarray(token_doc)], 0)
+    n, t = live.shape
+    most = live.reshape(n, t // R, R).max(axis=2)
+    real = mask.reshape(n, t // R, R).any(axis=2)
+    return np.where(real, np.maximum(-(-most // 128), 1) * 128, 0)
+
+
+@pytest.mark.parametrize("K,classes,n_widths", [
+    # live extents about 20, 170 and 430 of P=640: widths 128, 256, 512
+    (640, [(8, 20), (4, 200), (2, 700)], 3),
+    # about 30, 290 and 570 of P=896: widths 128, 384, 640
+    (896, [(6, 30), (3, 350), (2, 900)], 3),
+    # every document reaches P=384: every block full width
+    (384, [(3, 1500)], 1),
+])
+def test_lda_sample_heavy_tail_bit_exact(K, classes, n_widths):
+    """Row blocks of several widths, over an ELL width that no width
+    divides: draws and stats equal ``impl="ref"`` and ``sample_sweep``."""
+    from repro.core import sampler as core
+    corpus, shard, z, phi, phi_sum, cnts, tpcs, key = heavy_tail_case(
+        K, classes)
+    P = cnts.shape[1]
+    assert P % 128 == 0 and P & (P - 1) != 0
+    widths = expected_widths(shard.token_doc, shard.token_mask, cnts)
+    assert len(np.unique(widths[widths > 0])) == n_widths
+    n, t = z.shape
+    kw = dict(alpha=50.0 / K, beta=0.01, num_words_total=corpus.num_words)
+    args = (shard.tile_word, shard.token_doc, shard.token_mask, z, phi,
+            phi_sum, cnts, tpcs, key)
+    zk, sk = sample_ops.lda_sample(*args, impl="pallas", interpret=True, **kw)
+    zr, sr = sample_ops.lda_sample(*args, impl="ref", interpret=True, **kw)
+    # one chunk of every tile: the sweep's stats sum in the wrapper's order
+    zs, ss = core.sample_sweep(phi, phi_sum, shard.tile_word,
+                               shard.token_doc, shard.token_mask, z, cnts,
+                               tpcs, key, tiles_per_step=n, **kw)
+    np.testing.assert_array_equal(np.asarray(zk), np.asarray(zr))
+    np.testing.assert_array_equal(np.asarray(zk), np.asarray(zs))
+    assert 0 < float(sk.sparse_frac) < 1
+    assert float(sk.sparse_frac) == float(sr.sparse_frac) == float(
+        ss.sparse_frac)
+    assert float(sk.mean_s_over_sq) == float(sr.mean_s_over_sq) == float(
+        ss.mean_s_over_sq)
+    assert float(sr.row_width_share) == float(ss.row_width_share) == 1.0
+    share = widths[widths > 0].mean() / P
+    assert float(sk.row_width_share) == pytest.approx(share, rel=1e-6)
+    assert (share == 1.0) == (n_widths == 1)
+
+
+@pytest.mark.parametrize("P", range(128, 2049, 128))
+def test_chunk_prefix_is_the_full_prefix_sum(P):
+    """The chunk tree over a W-lane row gives the P-lane prefix sum's first
+    W lanes and its total bit for bit, for every W <= P in whole vregs;
+    the W-lane sum's own last lane does not always."""
+    from repro.core.sampler import prefix_sum
+    from repro.kernels.lda_sample import kernel
+    rng = np.random.default_rng(P)
+    naive_differs = 0
+    for W in range(128, P + 1, 128):
+        nnz = rng.integers(1, W + 1, (64, 1))
+        x = (rng.integers(1, 60, (64, W))
+             * rng.random((64, W)) ** 4).astype(np.float32)
+        x = np.where(np.arange(W) < nnz, x, np.float32(0))
+        full = np.asarray(
+            prefix_sum(jnp.asarray(np.pad(x, ((0, 0), (0, P - W))))))
+        p1_cum, last = kernel.chunk_prefix(
+            prefix_sum(jnp.asarray(x), stop=128), P)
+        np.testing.assert_array_equal(np.asarray(p1_cum), full[:, :W])
+        np.testing.assert_array_equal(np.asarray(last)[:, -1], full[:, -1])
+        naive_differs += int((full[:, W - 1] != full[:, -1]).sum())
+    # past 512 some W adds the chunk totals in another order than P does
+    # (at P=384 both sum chunk 0 and chunk 1 in one add)
+    if P > 512 and P & (P - 1):
+        assert naive_differs > 0
+
+
+def test_row_block_widths():
+    """Each sampled block's width is the smallest whole-vreg width holding
+    the live topics of its real tokens' documents.  Rows past a tile's
+    real tokens still hold the previous tile's ELL rows (here, a document
+    reaching P): they are ignored, as are the doc ids of padding slots."""
+    from repro.kernels.lda_sample import kernel
+    P, K, t = 640, 640, 64
+    extent = np.array([0, 5, 128, 129, 400, 640])
+    counts = (np.arange(P) < extent[:, None]).astype(np.int32)
+    topics = np.tile(np.arange(P, dtype=np.int32), (len(extent), 1))
+    # tile 0: blocks [docs 1, 5], [5]; tile 1: [2, 3, pads]; tile 2: [4], []
+    token_doc = np.array([[1] * 16 + [5] * 48,
+                          [2] * 8 + [3] * 8 + [5] * 48,
+                          [4] * 20 + [5] * 44], np.int32)
+    mask = np.zeros((3, t), np.int32)
+    mask[0, :] = 1
+    mask[1, :16] = 1
+    mask[2, :20] = 1
+    rng = np.random.default_rng(0)
+    pstar = jnp.asarray(rng.random((3, K)), jnp.float32)
+    u = jnp.asarray(rng.random((2, 3, t)), jnp.float32)
+    *_, widths = kernel.lda_sample_tiles(
+        jnp.arange(3), jnp.asarray(token_doc), pstar, jnp.asarray(counts),
+        jnp.asarray(topics), u[0], u[1], jnp.asarray(mask),
+        jnp.zeros((3, t), jnp.int32), alpha=0.1, interpret=True)
+    want = [[640, 640], [256, 0], [512, 0]]
+    np.testing.assert_array_equal(np.asarray(widths), want)
+    np.testing.assert_array_equal(
+        want, expected_widths(token_doc, mask, counts))
+    share = float(kernel.row_width_share(widths, P))
+    assert share == pytest.approx((640 + 640 + 256 + 512) / 4 / P)
+
+
 def _collect_shapes(jaxpr, acc):
     """Every intermediate's shape, recursing into nested jaxprs (pjit,
-    scan, cond, pallas_call kernels, ...)."""
+    scan, cond, pallas_call kernels, ...), and the shapes of the nested
+    jaxprs' inputs (a kernel's refs, its VMEM scratch among them)."""
+    for v in jaxpr.invars:
+        aval = getattr(v, "aval", None)
+        if aval is not None and hasattr(aval, "shape"):
+            acc.append(tuple(aval.shape))
     for eqn in jaxpr.eqns:
         for v in eqn.outvars:
             aval = getattr(v, "aval", None)

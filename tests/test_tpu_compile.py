@@ -2,9 +2,11 @@
 
 Nothing runs: each test lowers and compiles one kernel for a *described*
 v5e chip (no chip attached), at the paper's NYTimes shape — K=1024 topics,
-V=101,636 words, 256-token tiles, ELL width 512 — so Mosaic's block-shape,
-lowering and VMEM rules are checked on every change without a chip.  Each
-compile takes about two seconds.
+V=101,636 words, 256-token tiles — so Mosaic's block-shape, lowering and
+VMEM rules are checked on every change without a chip.  The sampler is
+compiled at the benchmark cells' ELL widths, 1024 (NYTimes) and 896
+(PubMed), with one row body per 128-lane width.  Each compile takes a few
+seconds.
 
 The topology is described inside a module fixture, never at import time:
 only one process at a time may load the TPU compiler library, and the test
@@ -23,7 +25,8 @@ from repro.kernels.fold_in import kernel as fold_in_kernel
 from repro.kernels.lda_sample import kernel as sample_kernel
 from repro.kernels.phi_update import kernel as phi_kernel
 
-K, V, T, P = 1024, 101_636, 256, 512     # NYTimes: topics, vocab, tile, ELL
+K, V, T = 1024, 101_636, 256           # NYTimes: topics, vocab, tile
+ELL_WIDTHS = (1024, 896)                 # the NYTimes and PubMed cells' P
 N_TILES, D = 4096, 14_987                # a 0.05-scale NYTimes shard
 
 
@@ -52,18 +55,23 @@ def _compile(fn, one_chip, *shapes):
     return compiled
 
 
-def test_lda_sample_compiles_for_v5e(one_chip):
-    def sweep(tw, td, pstar, cnt, tpc, u1, u2, mask, z):
-        return sample_kernel.lda_sample_tiles(
-            tw, td, pstar, cnt, tpc, u1, u2, mask, z, alpha=50.0 / K,
-            interpret=False)
+def _sweep(tw, td, pstar, cnt, tpc, u1, u2, mask, z):
+    return sample_kernel.lda_sample_tiles(
+        tw, td, pstar, cnt, tpc, u1, u2, mask, z, alpha=50.0 / K,
+        interpret=False)
 
+
+def _sweep_shapes(P):
     i32, f32 = jnp.int32, jnp.float32
-    _compile(sweep, one_chip,
-             ((N_TILES,), i32), ((N_TILES, T), i32), ((V, K), f32),
-             ((D, P), i32), ((D, P), i32),
-             ((N_TILES, T), f32), ((N_TILES, T), f32),
-             ((N_TILES, T), i32), ((N_TILES, T), i32))
+    return [((N_TILES,), i32), ((N_TILES, T), i32), ((V, K), f32),
+            ((D, P), i32), ((D, P), i32),
+            ((N_TILES, T), f32), ((N_TILES, T), f32),
+            ((N_TILES, T), i32), ((N_TILES, T), i32)]
+
+
+@pytest.mark.parametrize("P", ELL_WIDTHS)
+def test_lda_sample_compiles_for_v5e(one_chip, P):
+    _compile(_sweep, one_chip, *_sweep_shapes(P))
 
 
 def _mosaic_trace_regions(lowered_text: str) -> list[str]:
@@ -90,21 +98,14 @@ def _mosaic_trace_regions(lowered_text: str) -> list[str]:
     return out
 
 
-def test_lda_sample_lowers_with_three_trace_regions(one_chip):
-    """The NYTimes-width sampler carries its three device trace regions:
-    the DMA starts, the drain and the row loop, one per grid step."""
-    def sweep(tw, td, pstar, cnt, tpc, u1, u2, mask, z):
-        return sample_kernel.lda_sample_tiles(
-            tw, td, pstar, cnt, tpc, u1, u2, mask, z, alpha=50.0 / K,
-            interpret=False)
-
-    i32, f32 = jnp.int32, jnp.float32
-    shapes = [((N_TILES,), i32), ((N_TILES, T), i32), ((V, K), f32),
-              ((D, P), i32), ((D, P), i32),
-              ((N_TILES, T), f32), ((N_TILES, T), f32),
-              ((N_TILES, T), i32), ((N_TILES, T), i32)]
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    text = jax.jit(sweep).lower(*args).as_text()
+@pytest.mark.parametrize("P", ELL_WIDTHS)
+def test_lda_sample_lowers_with_three_trace_regions(one_chip, P):
+    """The sampler carries its three device trace regions at each cell's
+    width: the DMA starts, the drain and the row loop (whatever row body
+    each block takes), one per grid step."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in _sweep_shapes(P)]
+    text = jax.jit(_sweep).lower(*args).as_text()
     assert _mosaic_trace_regions(text) == [
         "lda_sample.issue", "lda_sample.wait", "lda_sample.rows"]
 

@@ -120,6 +120,28 @@ class TestPallasSamplerParity:
         res = trainer.train(corpus, cfg, 10, eval_every=2)
         assert res.ll_per_token[-1] > res.ll_per_token[0] + 0.2, res.ll_per_token
 
+    def test_fit_reports_row_width_share(self):
+        """Short documents beside one of 300 tokens: P=256, and the row
+        blocks of the short documents' words sample 128 lanes, so the
+        mean width share lies strictly between 1/2 and 1; `sq` reads 1."""
+        from repro.core.corpus import Corpus
+        from repro.train import fit
+        rng = np.random.default_rng(0)
+        lens = np.array([20] * 24 + [300])
+        doc = np.repeat(np.arange(len(lens)), lens).astype(np.int32)
+        # the long document's words are its own, so its tiles are apart
+        word = np.where(doc < 24, rng.integers(0, 16, len(doc)),
+                        rng.integers(16, 24, len(doc))).astype(np.int32)
+        corpus = Corpus(doc, word, len(lens), 24)
+        shares = {}
+        for name in ("sq", "pallas"):
+            cfg = trainer.LDAConfig(num_topics=256, tile_tokens=32,
+                                    tiles_per_step=8, sampler=name)
+            res = fit(corpus, cfg, 2, eval_every=2)
+            shares[name] = [s[3] for s in res.stats]
+        assert shares["sq"] == [1.0, 1.0]
+        assert all(0.5 < s < 1.0 for s in shares["pallas"]), shares
+
 
 class TestTopicDtypeGuard:
     """Regression (dtype-flow DT001): K beyond topic_dtype's range used to
