@@ -89,6 +89,9 @@ DECLARED: dict[tuple[str, str, str], str] = {
         "index-topic-bound",
     ("src/repro/kernels/lda_sample/kernel.py", "table_rows", "DT003"):
         "index-topic-bound",
+    # the tiler's radix sort narrows each 16-bit half of a uint32 rank
+    ("src/repro/core/corpus.py", "_stable_argsort_ranks", "DT001"):
+        "radix-halves-fit-uint16",
 }
 
 
@@ -383,6 +386,25 @@ def _w_index_topic() -> list[str]:
     return probs
 
 
+def _w_radix_halves() -> list[str]:
+    """The tiler's two 16-bit radix passes (each half masked or shifted
+    into uint16 before its narrowing) order ranks up to 2**32 - 1 exactly
+    as one stable argsort does."""
+    import numpy as np
+
+    from repro.core.corpus import _stable_argsort_ranks
+
+    rng = np.random.default_rng(0)
+    for hi in (1 << 17, 1 << 32):
+        rank = rng.integers(0, hi, 20_000, dtype=np.int64)
+        rank[:8] = [0, hi - 1, 0xFFFF, 0x10000, hi - 1, 0, 0xFFFF, 0x10000]
+        if not np.array_equal(_stable_argsort_ranks(rank),
+                              np.argsort(rank, kind="stable")):
+            return [f"radix order differs from a stable argsort for ranks "
+                    f"below {hi}"]
+    return []
+
+
 def _w_count_scatter() -> list[str]:
     """Count accumulators are integer-typed (float32 is exact only to 2^24
     < both corpora's T) and int32 still covers the Table-3 token counts."""
@@ -428,6 +450,8 @@ WITNESSES = (
      "index-topic-bound", _w_index_topic),
     ("DT004", "src/repro/core/updates.py", "phi_from_z",
      "count-scatter-int32", _w_count_scatter),
+    ("DT001", "src/repro/core/corpus.py", "_stable_argsort_ranks",
+     "radix-halves-fit-uint16", _w_radix_halves),
 )
 
 

@@ -23,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 TOPIC_DTYPE = np.int16  # C7: K < 2**16
@@ -156,6 +157,96 @@ _jtu.register_pytree_node(
 )
 
 
+def num_tiles(word_ids: np.ndarray, num_words: int, tile_tokens: int) -> int:
+    """Tiles the word-major tiling of these tokens takes: each word's
+    tokens fill ``ceil(count / tile_tokens)`` tiles of their own."""
+    counts = np.bincount(word_ids, minlength=num_words)
+    return int((-(-counts // tile_tokens)).sum())
+
+
+def _stable_argsort_ranks(rank: np.ndarray) -> np.ndarray:
+    """``np.argsort(rank, kind="stable")`` for non-negative ranks below
+    2**32, as two stable 16-bit radix passes (low half, then high half)."""
+    rank = rank.astype(np.uint32)
+    low = np.argsort((rank & 0xFFFF).astype(np.uint16), kind="stable")
+    high = np.argsort((rank[low] >> 16).astype(np.uint16), kind="stable")
+    return low[high]
+
+
+def tile_shard_host(
+    corpus: Corpus,
+    doc_ids_of_shard: np.ndarray,
+    tile_tokens: int = 256,
+    pad_tiles_to: int | None = None,
+    token_uid: np.ndarray | None = None,
+    num_words_total: int | None = None,
+) -> TiledCorpusShard:
+    """``tile_shard`` with numpy fields, left on the host (for a mesh,
+    whose devices each receive only their own shard)."""
+    sel = np.isin(corpus.doc_ids, doc_ids_of_shard)
+    docs = corpus.doc_ids[sel]
+    words = corpus.word_ids[sel]
+    uid = (np.nonzero(sel)[0].astype(np.int32) if token_uid is None
+           else np.asarray(token_uid, dtype=np.int32)[sel])
+    # local doc ids
+    doc_global = np.asarray(doc_ids_of_shard, dtype=np.int32)
+    remap = np.full(corpus.num_docs, -1, dtype=np.int32)
+    remap[doc_global] = np.arange(len(doc_global), dtype=np.int32)
+    docs_local = remap[docs]
+
+    # word-first sort; heavy words first, stable within word
+    counts = np.bincount(words, minlength=corpus.num_words)
+    heavy_rank = np.argsort(np.argsort(-counts, kind="stable"), kind="stable")
+    order = _stable_argsort_ranks(heavy_rank[words])
+    docs_local = docs_local[order]
+    words_sorted = words[order]
+    uid_sorted = uid[order]
+
+    # cut into tiles: a tile never mixes words.  Each run of one word
+    # fills ceil(run / t) tiles from its first, so token i of a run sits
+    # in slot first_tile * t + i, and slots rise with the sorted order.
+    T = len(words_sorted)
+    run_start = np.flatnonzero(np.diff(words_sorted, prepend=-1) != 0)
+    run_len = np.diff(np.append(run_start, T))
+    run_tiles = -(-run_len // tile_tokens)
+    first_tile = np.cumsum(run_tiles) - run_tiles
+    n = int(run_tiles.sum())
+    n_pad = pad_tiles_to if pad_tiles_to is not None else n
+    assert n_pad >= n, f"pad_tiles_to={n_pad} < required {n}"
+
+    tile_word = np.zeros(n_pad, dtype=np.int32)
+    token_doc = np.zeros((n_pad, tile_tokens), dtype=np.int32)
+    token_mask = np.zeros((n_pad, tile_tokens), dtype=bool)
+    tile_first = np.zeros(n_pad, dtype=bool)
+    tok_uid = np.full((n_pad, tile_tokens), -1, dtype=np.int32)
+    tile_word[:n] = np.repeat(words_sorted[run_start], run_tiles)
+    tile_first[first_tile] = True
+    slot = np.repeat(first_tile * tile_tokens - run_start, run_len) + np.arange(T)
+    token_mask.reshape(-1)[slot] = True
+    token_doc[token_mask] = docs_local
+    tok_uid[token_mask] = uid_sorted
+    # padding tiles alias the LAST real word with tile_first=False so that
+    # accumulation kernels (phi_update) neither re-zero a row nor add to it
+    if n and n_pad > n:
+        tile_word[n:] = tile_word[n - 1]
+
+    doc_length = np.bincount(docs_local, minlength=len(doc_global)).astype(np.int32)
+    return TiledCorpusShard(
+        tile_word=tile_word,
+        token_doc=token_doc,
+        token_mask=token_mask,
+        tile_first=tile_first,
+        doc_length=doc_length,
+        doc_global=doc_global,
+        token_uid=tok_uid,
+        num_tokens=int(len(docs_local)),
+        num_words=corpus.num_words,
+        num_docs_local=int(len(doc_global)),
+        num_words_total=(corpus.num_words if num_words_total is None
+                         else num_words_total),
+    )
+
+
 def tile_shard(
     corpus: Corpus,
     doc_ids_of_shard: np.ndarray,
@@ -174,73 +265,9 @@ def tile_shard(
     (for elastic checkpoints); defaults to the corpus positions of the
     selected tokens.
     """
-    sel = np.isin(corpus.doc_ids, doc_ids_of_shard)
-    docs = corpus.doc_ids[sel]
-    words = corpus.word_ids[sel]
-    uid = (np.nonzero(sel)[0].astype(np.int32) if token_uid is None
-           else np.asarray(token_uid, dtype=np.int32)[sel])
-    # local doc ids
-    doc_global = np.asarray(doc_ids_of_shard, dtype=np.int32)
-    remap = np.full(corpus.num_docs, -1, dtype=np.int32)
-    remap[doc_global] = np.arange(len(doc_global), dtype=np.int32)
-    docs_local = remap[docs]
-
-    # word-first sort; heavy words first, stable within word
-    counts = np.bincount(words, minlength=corpus.num_words)
-    heavy_rank = np.argsort(np.argsort(-counts, kind="stable"), kind="stable")
-    sort_key = heavy_rank[words].astype(np.int64) * (len(docs) + 1)
-    order = np.argsort(sort_key, kind="stable")
-    docs_local = docs_local[order]
-    words_sorted = words[order]
-    uid_sorted = uid[order]
-
-    # cut into tiles: a tile never mixes words
-    word_starts = np.flatnonzero(np.diff(words_sorted)) + 1
-    starts = np.concatenate([[0], word_starts, [len(words_sorted)]])
-    tiles: list[tuple[int, int, int]] = []  # (word, start, stop)
-    for a, b in zip(starts[:-1], starts[1:]):
-        w = int(words_sorted[a]) if b > a else 0
-        for s in range(a, b, tile_tokens):
-            tiles.append((w, s, min(s + tile_tokens, b)))
-    n = len(tiles)
-    n_pad = pad_tiles_to if pad_tiles_to is not None else n
-    assert n_pad >= n, f"pad_tiles_to={n_pad} < required {n}"
-
-    tile_word = np.zeros(n_pad, dtype=np.int32)
-    token_doc = np.zeros((n_pad, tile_tokens), dtype=np.int32)
-    token_mask = np.zeros((n_pad, tile_tokens), dtype=bool)
-    tile_first = np.zeros(n_pad, dtype=bool)
-    tok_uid = np.full((n_pad, tile_tokens), -1, dtype=np.int32)
-    prev_word = -1
-    for i, (w, s, e) in enumerate(tiles):
-        m = e - s
-        tile_word[i] = w
-        token_doc[i, :m] = docs_local[s:e]
-        token_mask[i, :m] = True
-        tok_uid[i, :m] = uid_sorted[s:e]
-        tile_first[i] = w != prev_word
-        prev_word = w
-    # padding tiles alias the LAST real word with tile_first=False so that
-    # accumulation kernels (phi_update) neither re-zero a row nor add to it
-    if n and n_pad > n:
-        tile_word[n:] = tile_word[n - 1]
-        tile_first[n:] = False
-
-    doc_length = np.bincount(docs_local, minlength=len(doc_global)).astype(np.int32)
-    return TiledCorpusShard(
-        tile_word=jnp.asarray(tile_word),
-        token_doc=jnp.asarray(token_doc),
-        token_mask=jnp.asarray(token_mask),
-        tile_first=jnp.asarray(tile_first),
-        doc_length=jnp.asarray(doc_length),
-        doc_global=jnp.asarray(doc_global),
-        token_uid=jnp.asarray(tok_uid),
-        num_tokens=int(len(docs_local)),
-        num_words=corpus.num_words,
-        num_docs_local=int(len(doc_global)),
-        num_words_total=(corpus.num_words if num_words_total is None
-                         else num_words_total),
-    )
+    return jax.tree.map(jnp.asarray, tile_shard_host(
+        corpus, doc_ids_of_shard, tile_tokens, pad_tiles_to, token_uid,
+        num_words_total))
 
 
 def tile_corpus(
@@ -249,9 +276,8 @@ def tile_corpus(
     """Partition + tile: shards padded to a common tile count so they can be
     stacked on a mesh axis (SPMD requires identical per-device shapes)."""
     parts = partition_by_document(corpus, num_shards)
-    raw = [tile_shard(corpus, p, tile_tokens, None) for p in parts]
-    n_max = max(s.tile_word.shape[0] for s in raw)
-    # re-tile with padding to the common size
+    n_max = max(num_tiles(corpus.word_ids[np.isin(corpus.doc_ids, p)],
+                          corpus.num_words, tile_tokens) for p in parts)
     return [tile_shard(corpus, p, tile_tokens, n_max) for p in parts]
 
 

@@ -15,8 +15,10 @@ Two partition modes:
   of the 1D collective volume.  The sampler itself is partition-agnostic
   (tiles carry local word ids).
 
-Host-side construction is numpy; device arrays are stacked with a leading
-shard axis and handed to ``jax.shard_map``.
+Host-side construction is numpy: every shard is tiled once on the host,
+stacked there with a leading shard axis, and placed on the mesh so that
+each device receives only its own shard; ``jax.shard_map`` then finds the
+corpus where its in_specs put it.
 """
 from __future__ import annotations
 
@@ -28,11 +30,12 @@ from typing import Sequence
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import trainer as core_trainer
 from repro.core.corpus import (
-    Corpus, TiledCorpusShard, partition_by_document, tile_shard,
+    Corpus, TiledCorpusShard, num_tiles, partition_by_document,
+    tile_shard_host,
 )
 
 Array = jnp.ndarray
@@ -259,7 +262,10 @@ def build_shards(
     mode: str,
     tile_tokens: int,
 ) -> tuple[list[TiledCorpusShard], PartitionPlan, list[np.ndarray]]:
-    """Host-side shard construction (doc-major, then word order)."""
+    """Host-side shard construction (doc-major, then word order).
+
+    Each shard is tiled once, with numpy fields, padded to the largest
+    shard's tile count, which its word counts give (``num_tiles``)."""
     doc_parts = partition_by_document(corpus, num_doc_shards)
     lengths = corpus.doc_lengths()
 
@@ -279,12 +285,10 @@ def build_shards(
         word_meta = (shard_of, local_id, v_local)
 
     v_total = corpus.num_words
-    pre = [tile_shard(sub, pd, tile_tokens, token_uid=uid,
-                      num_words_total=v_total)
-           for sub, uid, pd in subs]
-    n_max = max(s.tile_word.shape[0] for s in pre)
-    shards = [tile_shard(sub, pd, tile_tokens, n_max, token_uid=uid,
-                         num_words_total=v_total)
+    n_max = max(num_tiles(sub.word_ids, sub.num_words, tile_tokens)
+                for sub, _, _ in subs)
+    shards = [tile_shard_host(sub, pd, tile_tokens, n_max, token_uid=uid,
+                              num_words_total=v_total)
               for sub, uid, pd in subs]
     full_doc_lengths = [lengths[pd] for sub, uid, pd in subs]
     plan = PartitionPlan(mode, (), (), num_doc_shards, num_word_shards,
@@ -294,7 +298,8 @@ def build_shards(
 
 def stack_shards(shards: list[TiledCorpusShard],
                  full_doc_lengths: list[np.ndarray]) -> dict:
-    """Stack per-device shards on a leading shard axis -> dict of (G, ...) arrays.
+    """Stack per-device shards on a leading shard axis -> dict of (G, ...)
+    numpy arrays, on the host.
 
     ``doc_length`` is the *global* per-doc length (in 2D the local bincount
     only sees one word shard's tokens)."""
@@ -307,14 +312,57 @@ def stack_shards(shards: list[TiledCorpusShard],
         return out
 
     return dict(
-        tile_word=jnp.stack([s.tile_word for s in shards]),
-        token_doc=jnp.stack([s.token_doc for s in shards]),
-        token_mask=jnp.stack([s.token_mask for s in shards]),
-        tile_first=jnp.stack([s.tile_first for s in shards]),
-        doc_length=jnp.stack([jnp.asarray(pad_docs(x)) for x in full_doc_lengths]),
-        doc_global=jnp.stack([jnp.asarray(pad_docs(s.doc_global, -1)) for s in shards]),
-        token_uid=jnp.stack([s.token_uid for s in shards]),
+        tile_word=np.stack([s.tile_word for s in shards]),
+        token_doc=np.stack([s.token_doc for s in shards]),
+        token_mask=np.stack([s.token_mask for s in shards]),
+        tile_first=np.stack([s.tile_first for s in shards]),
+        doc_length=np.stack([pad_docs(np.asarray(x, np.int32))
+                             for x in full_doc_lengths]),
+        doc_global=np.stack([pad_docs(s.doc_global, -1) for s in shards]),
+        token_uid=np.stack([s.token_uid for s in shards]),
     )
+
+
+def place_on_mesh(tree, mesh, spec: P):
+    """Host arrays committed to ``mesh`` as ``NamedSharding(mesh, spec)``:
+    each device receives only its own slice, straight from the host.  On
+    a device-free mesh (``AbstractMesh``, for tracing) the host arrays are
+    returned as they are."""
+    if not isinstance(mesh, Mesh):
+        return tree
+    return jax.device_put(tree, NamedSharding(mesh, spec))
+
+
+def partition_counters(shards: list[TiledCorpusShard],
+                       sync_bytes: int) -> dict:
+    """What a partition costs, counted once from its host shards.
+
+    ``shard_tokens`` and ``shard_tiles`` are each shard's real tokens and
+    real tiles, ``tiles_max`` the tile count every shard is padded to,
+    ``padding_share`` the padded token slots over all slots, and
+    ``sync_bytes`` the bytes each device all-reduces per iteration."""
+    tokens = [s.num_tokens for s in shards]
+    # real tiles are left-packed: their first slot always holds a token
+    tiles = [int(np.asarray(s.token_mask)[:, 0].sum()) for s in shards]
+    n_max, t = np.asarray(shards[0].token_mask).shape
+    slots = len(shards) * n_max * t
+    return dict(shard_tokens=tokens, shard_tiles=tiles, tiles_max=int(n_max),
+                padding_share=1.0 - sum(tokens) / max(slots, 1),
+                sync_bytes=int(sync_bytes))
+
+
+def sync_bytes_per_iteration(cfg: core_trainer.LDAConfig, num_words_local: int,
+                             heavy_rows: int) -> int:
+    """Bytes each device all-reduces per iteration for phi, from shapes and
+    dtypes: the int32 (V_local, K) delta, or under ``compressed_sync`` its
+    int16 copy plus the int32 heavy rows; once per micro-chunk under
+    ``sync_overlap``."""
+    K = cfg.num_topics
+    if cfg.compressed_sync:
+        per = num_words_local * K * 2 + heavy_rows * K * 4
+    else:
+        per = num_words_local * K * 4
+    return per * (cfg.micro_chunks if cfg.sync_overlap else 1)
 
 
 class DistributedLDA:
@@ -349,17 +397,24 @@ class DistributedLDA:
         shards, plan, full_dl = build_shards(corpus, n_doc, n_word, mode,
                                              cfg.tile_tokens)
         self.plan = dataclasses.replace(plan, doc_axes=doc_axes, word_axes=word_axes)
-        self.stacked = stack_shards(shards, full_dl)
-        # int32-correction rows for the int16 compressed delta sync (empty
-        # (G, 0) when off or when no word reaches the flux bound)
-        self._heavy = jnp.asarray(
-            heavy_word_rows(corpus, self.plan) if cfg.compressed_sync
-            else np.zeros((n_doc * n_word, 0), np.int32))
-        self.num_tokens = corpus.num_tokens
-        self._template = shards[0]  # static aux: num_words, num_docs_local
-
         lead = doc_axes + word_axes     # shard-axis order is doc-major
         dev = P(lead)
+        # committed where the step's in_specs put them, so no call moves
+        # corpus data and no device holds another's shard
+        self.stacked = place_on_mesh(stack_shards(shards, full_dl), mesh, dev)
+        # int32-correction rows for the int16 compressed delta sync (empty
+        # (G, 0) when off or when no word reaches the flux bound)
+        heavy = (heavy_word_rows(corpus, self.plan) if cfg.compressed_sync
+                 else np.zeros((n_doc * n_word, 0), np.int32))
+        self._heavy = place_on_mesh(heavy, mesh, dev)
+        self.num_tokens = corpus.num_tokens
+        # static aux only (num_words, num_docs_local): the arrays are on the mesh
+        self._template = dataclasses.replace(
+            shards[0], **{k: None for k in _CORPUS_FIELDS})
+        self.counters = partition_counters(
+            shards, sync_bytes_per_iteration(cfg, shards[0].num_words,
+                                             heavy.shape[1]))
+
         repl = P()
         corpus_specs = {k: dev for k in _CORPUS_FIELDS}
         state_specs = core_trainer.LDAState(
@@ -406,13 +461,16 @@ class DistributedLDA:
             st, stats = core_trainer.lda_iteration(
                 cfg_, unpack(c), state, key, data_axes=d_ax, model_axes=m_ax,
                 heavy_rows=heavy[0])
-            stats = core_trainer.IterStats(
-                sparse_frac=jax.lax.pmean(stats.sparse_frac, all_ax),
-                ell_overflow=jax.lax.psum(stats.ell_overflow, all_ax)
-                // (n_word if mode == "2d" else 1),
-                mean_s_over_sq=jax.lax.pmean(stats.mean_s_over_sq, all_ax),
-                row_width_share=jax.lax.pmean(stats.row_width_share, all_ax),
-            )
+            # the stats' collectives, timed apart from lda.sync
+            with jax.named_scope("lda.stats"):
+                stats = core_trainer.IterStats(
+                    sparse_frac=jax.lax.pmean(stats.sparse_frac, all_ax),
+                    ell_overflow=jax.lax.psum(stats.ell_overflow, all_ax)
+                    // (n_word if mode == "2d" else 1),
+                    mean_s_over_sq=jax.lax.pmean(stats.mean_s_over_sq, all_ax),
+                    row_width_share=jax.lax.pmean(stats.row_width_share,
+                                                  all_ax),
+                )
             return st, stats
 
         def _ll(c, state):
@@ -456,8 +514,10 @@ class DistributedLDA:
         are rebuilt from the re-tiled assignments."""
         from repro.distributed import checkpoint as ckpt
         z_tiled = ckpt.scatter_canonical_z(z_canon, self.stacked["token_uid"])
-        z_dev = jnp.asarray(z_tiled.reshape(-1, z_tiled.shape[-1])
-                            ).astype(self.cfg.topic_dtype)
+        z_dev = place_on_mesh(
+            z_tiled.reshape(-1, z_tiled.shape[-1]).astype(
+                np.dtype(self.cfg.topic_dtype)),
+            self.mesh, self.state_specs.z)
         with self.mesh:
             return self._rebuild_fn(self.stacked, z_dev,
                                     jnp.int32(iteration))

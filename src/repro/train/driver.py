@@ -181,13 +181,13 @@ def _fit_mesh(corpus, cfg, num_iterations, mesh, *, mode, doc_axes,
         num_tokens=corpus.num_tokens, mgr=mgr, eval_every=eval_every,
         callback=callback, obs=obs, metrics_out=metrics_out,
         sanitize=sanitize, checkpoint_every=checkpoint_every,
-        verbose=verbose)
+        verbose=verbose, first_row={"partition": dl.counters})
 
 
 def _run_loop(cfg, it0, num_iterations, state, compile_step, *, ll_fn,
               save_fn, num_tokens, mgr, eval_every, callback, obs,
-              metrics_out, sanitize, checkpoint_every, verbose
-              ) -> TrainResult:
+              metrics_out, sanitize, checkpoint_every, verbose,
+              first_row=None) -> TrainResult:
     """The one training loop both paths share.
 
     Telemetry is host-side only (``repro.obs``): ``sample``/``eval`` phase
@@ -195,7 +195,8 @@ def _run_loop(cfg, it0, num_iterations, state, compile_step, *, ll_fn,
     ``jax.named_scope`` annotations inside ``lda_iteration``) and — when
     ``metrics_out`` is given — one JSONL row per iteration.  None of it
     touches keys or traced values, so draws are bit-identical to an
-    uninstrumented run (pinned in tests/test_obs.py).
+    uninstrumented run (pinned in tests/test_obs.py).  ``first_row``
+    (the mesh partition's counters) is written into the first row only.
     """
     from repro.obs import JsonlSink, NULL_SINK, NULL_TRACER
 
@@ -239,7 +240,8 @@ def _run_loop(cfg, it0, num_iterations, state, compile_step, *, ll_fn,
                             tokens=num_tokens, tokens_per_sec=tps[-1],
                             sparse_frac=st[-1][0],
                             row_width_share=st[-1][3], ell_overflow=st[-1][1],
-                            mean_s_over_sq=st[-1][2], ll_per_token=ll))
+                            mean_s_over_sq=st[-1][2], ll_per_token=ll,
+                            **(first_row if it == it0 and first_row else {})))
             if (save_fn is not None and checkpoint_every
                     and (it + 1) % checkpoint_every == 0):
                 save_fn(it, state)

@@ -109,3 +109,72 @@ def test_ell_capacity_bounds(tiny_corpus):
     P = ell_capacity(tiny_corpus, 8)
     assert P >= min(8, int(tiny_corpus.doc_lengths().max()))
     assert ell_capacity(tiny_corpus, 10_000) >= 8
+
+
+def tile_by_loop(corpus, docs, t, n_pad):
+    """The tiling written tile by tile: the word-major layout the
+    vectorised ``tile_shard_host`` must reproduce field for field."""
+    sel = np.isin(corpus.doc_ids, docs)
+    d, w = corpus.doc_ids[sel], corpus.word_ids[sel]
+    uid = np.nonzero(sel)[0]
+    remap = np.full(corpus.num_docs, -1)
+    remap[docs] = np.arange(len(docs))
+    counts = np.bincount(w, minlength=corpus.num_words)
+    rank = np.argsort(np.argsort(-counts, kind="stable"), kind="stable")
+    order = np.argsort(rank[w], kind="stable")
+    d, w, uid = remap[d][order], w[order], uid[order]
+    out = dict(tile_word=np.zeros(n_pad, np.int32),
+               token_doc=np.zeros((n_pad, t), np.int32),
+               token_mask=np.zeros((n_pad, t), bool),
+               tile_first=np.zeros(n_pad, bool),
+               token_uid=np.full((n_pad, t), -1, np.int32))
+    i, a = 0, 0
+    while a < len(w):
+        b = a
+        while b < len(w) and w[b] == w[a]:
+            b += 1
+        for s in range(a, b, t):
+            e = min(s + t, b)
+            out["tile_word"][i] = w[a]
+            out["tile_first"][i] = s == a
+            out["token_doc"][i, :e - s] = d[s:e]
+            out["token_mask"][i, :e - s] = True
+            out["token_uid"][i, :e - s] = uid[s:e]
+            i += 1
+        a = b
+    if i:
+        out["tile_word"][i:] = out["tile_word"][i - 1]
+    return out, i
+
+
+@pytest.mark.parametrize("t", [1, 3, 16, 64])
+def test_vectorised_tiling_matches_the_loop(t):
+    from repro.core.corpus import num_tiles, tile_shard, tile_shard_host
+
+    rng = np.random.default_rng(t)
+    D, V, T = 30, 150, 2500
+    # Zipf-like words so that heavy words span several tiles
+    corpus = make_corpus(np.sort(rng.integers(0, D, T)),
+                         np.minimum(rng.zipf(1.3, T) - 1, V - 1), D, V)
+    docs = np.sort(rng.choice(D, 20, replace=False)).astype(np.int32)
+    _, n = tile_by_loop(corpus, docs, t, T)
+    want, _ = tile_by_loop(corpus, docs, t, n + 3)
+    assert num_tiles(corpus.word_ids[np.isin(corpus.doc_ids, docs)], V,
+                     t) == n
+    host = tile_shard_host(corpus, docs, t, n + 3)
+    dev = tile_shard(corpus, docs, t, n + 3)
+    for k, v in want.items():
+        np.testing.assert_array_equal(getattr(host, k), v, err_msg=k)
+        np.testing.assert_array_equal(np.asarray(getattr(dev, k)), v,
+                                      err_msg=k)
+    assert isinstance(host.token_doc, np.ndarray)
+    assert not isinstance(dev.token_doc, np.ndarray)
+
+
+def test_empty_shard_tiles_to_padding_only():
+    from repro.core.corpus import tile_shard_host
+
+    corpus = make_corpus([0, 0, 1], [2, 2, 1], 3, 4)
+    sh = tile_shard_host(corpus, np.array([2], np.int32), 4, 2)
+    assert sh.num_tokens == 0 and not sh.token_mask.any()
+    assert sh.tile_word.shape == (2,) and not sh.tile_first.any()

@@ -429,3 +429,110 @@ def test_compressed_sync_matches_exact():
         print("OK", lls[False][0])
     """))
     assert "OK" in out
+
+
+@pytest.mark.slow
+def test_mesh_corpus_is_committed_where_the_step_reads_it():
+    """The stacked corpus and the heavy rows are committed
+    ``NamedSharding(mesh, P(lead))`` arrays, each device holding exactly
+    its own shard's rows, and two iterations draw exactly what the same
+    step draws from the corpus stacked on the default device (the
+    construction before the corpus was placed on the mesh)."""
+    out = run_subprocess(COMMON + textwrap.dedent("""
+        import dataclasses, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.distributed.partition import build_shards, stack_shards
+        cases = (("1d", (4,), ("data",), {}, "pallas"),
+                 ("2d", (2, 2), ("data", "model"),
+                  dict(doc_axes=("data",), word_axes=("model",)), "sq"))
+        for mode, shape, axes, kw, sampler in cases:
+            mesh = jax.make_mesh(shape, axes)
+            c = dataclasses.replace(cfg, sampler=sampler)
+            dl = DistributedLDA(c, mesh, corpus, mode=mode, **kw)
+            lead = dl.plan.doc_axes + dl.plan.word_axes
+            want = NamedSharding(mesh, P(lead))
+            shards, _, full = build_shards(corpus, dl.plan.num_doc_shards,
+                                           dl.plan.num_word_shards, mode,
+                                           c.tile_tokens)
+            host = stack_shards(shards, full)
+            G = len(shards)
+            for k, v in dict(dl.stacked, heavy=dl._heavy).items():
+                assert v.committed, (mode, k)
+                assert v.sharding.is_equivalent_to(want, v.ndim), (mode, k)
+                held = []
+                for s in v.addressable_shards:
+                    g = s.index[0].start or 0
+                    held.append(g)
+                    assert s.data.shape[0] == 1, (mode, k)
+                    if k != "heavy":
+                        np.testing.assert_array_equal(
+                            np.asarray(s.data), host[k][g:g + 1])
+                assert sorted(held) == list(range(G)), (mode, k, held)
+            old = {k: jnp.stack([jnp.asarray(x) for x in v])
+                   for k, v in host.items()}
+            assert not old["token_uid"].committed
+            heavy_old = jnp.asarray(np.asarray(dl._heavy))
+            key = jax.random.key(7)
+            a = b = dl.init(3)
+            for _ in range(2):
+                a, sa = dl.step(a, key)
+                with mesh:
+                    b, sb = dl._step_fn(old, heavy_old, b, key)
+            for f in ("z", "phi_vk", "phi_sum"):
+                assert (np.asarray(getattr(a, f))
+                        == np.asarray(getattr(b, f))).all(), (mode, f)
+            assert float(sa.sparse_frac) == float(sb.sparse_frac), mode
+        print("OK")
+    """), devices=4)
+    assert "OK" in out
+
+
+@pytest.mark.parametrize("mode", ["1d", "2d"])
+def test_one_pass_build_matches_the_two_pass_build(mode):
+    """``build_shards`` tiles each shard once, its padding taken from the
+    shards' word counts; its shards equal, field for field, those of
+    tiling every shard twice (a pass to find the largest tile count, then
+    a padded pass)."""
+    import numpy as np
+
+    from repro.core.corpus import partition_by_document, tile_shard
+    from repro.data.synthetic import lda_corpus
+    from repro.distributed import partition
+
+    corpus = lda_corpus(num_docs=48, num_words=96, num_topics=8,
+                        avg_doc_len=40, seed=1)
+    t, (n_doc, n_word) = 16, ((4, 1) if mode == "1d" else (2, 2))
+    shards, plan, full = partition.build_shards(corpus, n_doc, n_word,
+                                                mode, t)
+    subs = []
+    for pd in partition_by_document(corpus, n_doc):
+        sel = np.isin(corpus.doc_ids, pd)
+        if mode == "1d":
+            subs.append((*partition._subset(corpus, sel, None,
+                                            corpus.num_words), pd))
+            continue
+        shard_of, local_id, v_local = partition.partition_vocabulary(
+            corpus, n_word)
+        for m in range(n_word):
+            subs.append((*partition._subset(
+                corpus, sel & (shard_of[corpus.word_ids] == m), local_id,
+                v_local), pd))
+    V = corpus.num_words
+    pre = [tile_shard(sub, pd, t, token_uid=uid, num_words_total=V)
+           for sub, uid, pd in subs]
+    n_max = max(s.tile_word.shape[0] for s in pre)
+    two = [tile_shard(sub, pd, t, n_max, token_uid=uid, num_words_total=V)
+           for sub, uid, pd in subs]
+    assert len(shards) == len(two) == n_doc * n_word
+    for a, b in zip(shards, two):
+        for f in ("tile_word", "token_doc", "token_mask", "tile_first",
+                  "doc_length", "doc_global", "token_uid"):
+            x, y = getattr(a, f), np.asarray(getattr(b, f))
+            assert x.dtype == y.dtype and x.shape == y.shape, f
+            np.testing.assert_array_equal(x, y, err_msg=f)
+        assert (a.num_tokens, a.num_words, a.num_docs_local,
+                a.num_words_total) == (b.num_tokens, b.num_words,
+                                       b.num_docs_local, b.num_words_total)
+    for x, (_, _, pd) in zip(full, subs):
+        np.testing.assert_array_equal(x, corpus.doc_lengths()[pd])
+    assert plan.mode == mode and plan.num_doc_shards == n_doc

@@ -410,3 +410,69 @@ def test_instrumentation_does_not_change_draws(tiny_corpus):
                                   np.asarray(r_full.state.z))
     np.testing.assert_array_equal(np.asarray(r_noop.state.phi_vk),
                                   np.asarray(r_full.state.phi_vk))
+
+
+def _mesh_case(tiny_corpus):
+    import jax
+
+    from repro.core import trainer
+
+    cfg = trainer.LDAConfig(num_topics=8, tile_tokens=32, tiles_per_step=8)
+    return cfg, jax.make_mesh((1,), ("data",))
+
+
+def test_stats_scope_does_not_change_mesh_draws(tiny_corpus, monkeypatch):
+    """The ``lda.stats`` scope around the mesh step's stats collectives
+    reaches the compiled program and leaves its draws and stats
+    bit-identical to the same step built with every scope off."""
+    import contextlib
+
+    import jax
+
+    from repro.distributed.partition import DistributedLDA
+
+    cfg, mesh = _mesh_case(tiny_corpus)
+
+    def two_steps():
+        dl = DistributedLDA(cfg, mesh, tiny_corpus, mode="1d")
+        state = dl.init()
+        for _ in range(2):
+            state, stats = dl.step(state)
+        return dl, state, stats
+
+    dl, on, stats_on = two_steps()
+    assert "lda.stats/" in dl.lower_step().compile().as_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    dl, off, stats_off = two_steps()
+    assert "lda.stats/" not in dl.lower_step().compile().as_text()
+    for f in ("z", "phi_vk", "phi_sum"):
+        np.testing.assert_array_equal(np.asarray(getattr(on, f)),
+                                      np.asarray(getattr(off, f)))
+    for a, b in zip(stats_on, stats_off):
+        assert float(a) == float(b)
+
+
+def test_partition_counters_in_first_row_do_not_change_draws(tiny_corpus,
+                                                             tmp_path):
+    """``fit`` on a mesh writes the partition's counters into its first
+    JSONL row only, and training draws the same with the sink as without."""
+    from repro.train import fit
+
+    cfg, mesh = _mesh_case(tiny_corpus)
+    path = tmp_path / "m.jsonl"
+    plain = fit(tiny_corpus, cfg, 3, mesh=mesh, eval_every=3)
+    logged = fit(tiny_corpus, cfg, 3, mesh=mesh, eval_every=3,
+                 metrics_out=str(path))
+    np.testing.assert_array_equal(np.asarray(plain.state.z),
+                                  np.asarray(logged.state.z))
+    rows = [json.loads(ln) for ln in path.read_text().splitlines()]
+    assert len(rows) == 3
+    assert all("partition" not in r for r in rows[1:])
+    c = rows[0]["partition"]
+    assert c["shard_tokens"] == [tiny_corpus.num_tokens]
+    assert c["tiles_max"] == c["shard_tiles"][0] > 0
+    slots = c["tiles_max"] * cfg.tile_tokens
+    assert c["padding_share"] == pytest.approx(
+        1 - tiny_corpus.num_tokens / slots)
+    assert c["sync_bytes"] == tiny_corpus.num_words * cfg.num_topics * 4

@@ -31,7 +31,8 @@ N_TILES, D = 4096, 14_987                # a 0.05-scale NYTimes shard
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
+    """A described v5e:2x2 host: four chips to compile for, none to run."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -44,8 +45,13 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
 
 
 def _compile(fn, one_chip, *shapes):
@@ -136,3 +142,50 @@ def test_phi_update_compiles_for_v5e(one_chip, delta):
     i32 = jnp.int32
     _compile(update, one_chip, ((n,), i32), ((n,), i32),
              *[((n, T), i32)] * (3 if delta else 2))
+
+
+
+def test_mesh_step_compiles_for_a_v5e_host(topology, monkeypatch):
+    """The 1d mesh step (``DistributedLDA``, the paper's four-GPU
+    partition: documents over the chips, phi replicated and all-reduced)
+    compiles for a described v5e:2x2 host at NYTimes width on a small
+    corpus: the sampler kernel, the phi all-reduce, and a per-chip
+    footprint within one chip's 16 GB."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import trainer
+    from repro.core.corpus import Corpus
+    from repro.core.trainer import LDAConfig
+    from repro.distributed import partition
+
+    # described chips hold no data: keep the corpus on the host, and give
+    # the compiler its shapes with the shardings the step reads them in;
+    # the kernels go to Mosaic as on the chip
+    monkeypatch.setattr(partition, "place_on_mesh", lambda tree, *_: tree)
+    monkeypatch.setattr(trainer, "resolve_interpret", lambda *_: False)
+    rng = np.random.default_rng(0)
+    D, n_tok = 64, 20_000
+    corpus = Corpus(np.sort(rng.integers(0, D, n_tok)).astype(np.int32),
+                    rng.integers(0, V, n_tok).astype(np.int32), D, V)
+    mesh = Mesh(np.array(topology.devices), ("data",))
+    dl = partition.DistributedLDA(LDAConfig(num_topics=K, sampler="pallas"),
+                                  mesh, corpus, mode="1d")
+    lead = NamedSharding(mesh, P(("data",)))
+
+    def shaped(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=lead)
+
+    stacked = jax.tree.map(shaped, dl.stacked)
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=NamedSharding(mesh, P()))
+    state = jax.eval_shape(dl._init_fn, stacked, key)
+    compiled = dl._step_fn.lower(stacked, shaped(dl._heavy), state,
+                                 key).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text
+    mem = compiled.memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert V * K * 4 < per_chip < 16e9
