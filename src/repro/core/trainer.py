@@ -94,10 +94,20 @@ def resolve_config(cfg: LDAConfig, corpus: Corpus) -> LDAConfig:
     its config exactly once through here and threads the SAME object
     everywhere afterwards — the resolved config is what ``TrainResult.cfg``
     surfaces for reproducibility.  Idempotent."""
+    _check_count_field(cfg, int(corpus.doc_lengths().max(initial=0)))
     if cfg.ell_capacity is None:
         cfg = dataclasses.replace(
             cfg, ell_capacity=ell_capacity(corpus, cfg.num_topics))
     return cfg
+
+
+def _check_count_field(cfg: LDAConfig, longest_doc: int) -> None:
+    """Under ``sampler="pallas"``, refuse (``ValueError``) a corpus whose
+    longest document does not fit the count field of the kernel's packed
+    ELL words; the other samplers keep counts and topics apart."""
+    if cfg.sampler == "pallas":
+        from ..kernels.lda_sample import kernel as lda_kernel
+        lda_kernel.check_count_field(longest_doc, cfg.num_topics)
 
 
 class LDAState(NamedTuple):
@@ -152,7 +162,11 @@ def _build_theta_ell(cfg: LDAConfig, shard: TiledCorpusShard, z, model_axes):
         theta = updates.theta_from_z(z, shard.token_doc, shard.token_mask,
                                      shard.num_docs_local, K)
         theta = sync.sync_theta(theta, model_axes)
-    P = cfg.ell_capacity or min(K, int(shard.doc_length.max()) if shard.doc_length.size else K)
+    P = cfg.ell_capacity
+    if P is None:
+        longest = int(shard.doc_length.max(initial=0))
+        _check_count_field(cfg, longest)
+        P = min(K, longest) if shard.doc_length.size else K
     with jax.named_scope("ell"):
         counts, topics, overflow = updates.theta_to_ell(theta, min(P, K))
     return theta, counts, topics, overflow

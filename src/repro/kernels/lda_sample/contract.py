@@ -17,9 +17,9 @@ from repro.analysis.contracts import ContractCase, KernelContract, Operand
 from repro.kernels.lanes import row_block
 from repro.kernels.lda_sample import kernel
 
-# Declared VMEM blocks + scratch (the two (t, 1, P) per-token ELL tables
-# dominate: 8 MiB at t=256, P=512); the kernel raises Mosaic's scoped limit
-# to ``kernel.VMEM_LIMIT_BYTES`` for its body's temporaries.
+# Declared VMEM blocks + scratch (the one (t, 1, P) table of per-token packed
+# ELL rows dominates: 4 MiB at t=256, P=512); the kernel raises Mosaic's
+# scoped limit to ``kernel.VMEM_LIMIT_BYTES`` for its body's temporaries.
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 WIDTH_TILES = 2  # tiles of a case run through the kernel for its widths
 
@@ -121,12 +121,11 @@ def _build(name: str, token_doc: np.ndarray, tile_word: np.ndarray,
     in_shapes = [
         Operand("head", (n, 1, kernel.HEAD + t), jnp.int32, in_specs[0]),
         Operand("pstar", (V, 1, K), jnp.float32, in_specs[1]),
-        Operand("ell_counts", (D, 1, P), jnp.int32, in_specs[2]),
-        Operand("ell_topics", (D, 1, P), jnp.int32, in_specs[3]),
-        Operand("u1", row, jnp.float32, in_specs[4]),
-        Operand("u2", row, jnp.float32, in_specs[5]),
-        Operand("mask", row, jnp.int32, in_specs[6]),
-        Operand("z_old", row, jnp.int32, in_specs[7]),
+        Operand("ell", (D, 1, P), jnp.int32, in_specs[2]),
+        Operand("u1", row, jnp.float32, in_specs[3]),
+        Operand("u2", row, jnp.float32, in_specs[4]),
+        Operand("mask", row, jnp.int32, in_specs[5]),
+        Operand("z_old", row, jnp.int32, in_specs[6]),
     ]
     out_shapes = [
         Operand("z_new", row, jnp.int32, out_specs[0]),
@@ -151,9 +150,8 @@ def contract() -> KernelContract:
             # NYTimes-like: K=1024, 256-token tiles, ELL width 512
             _case("paper", n=128, t=256, V=512, K=1024, D=2048, P=512),
             # the PubMed cell's ELL width, 809 padded to 896 (7 row bodies),
-            # in 128-token tiles: at 256 the two ELL tables alone count
-            # 14 MiB here, over the budget
-            _case("pubmed", n=128, t=128, V=512, K=1024, D=2048, P=896),
+            # in its 256-token tiles: the packed ELL table counts 7 MiB
+            _case("pubmed", n=128, t=256, V=512, K=1024, D=2048, P=896),
             # one real 2d-partition shard: local vocab rows, irregular doc
             # subset, padding tiles
             _shard_case("shard2d", K=48, P=6),

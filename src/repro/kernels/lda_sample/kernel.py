@@ -10,7 +10,9 @@ GPU -> TPU mapping:
         -> each real token's (1, P) ELL row DMA'd straight from HBM into a
            per-tile VMEM table (doc ids ride in SMEM), so no per-token
            ``(n, t, P)`` ELL tensor ever exists in HBM; padding slots of a
-           tile issue no DMA at all;
+           tile issue no DMA at all.  An ELL entry's count and topic ride
+           packed in one int32 word (``pack_ell``), so a token's row is one
+           copy, and the row body splits each word in-register;
   * 32 warp-samplers per block
         -> the tile's t tokens sampled in lock-step on the VPU;
   * 32-ary shared-memory index tree (C5)
@@ -41,6 +43,11 @@ u2*S lies within an ulp of S, the P-lane search may count a lane past W (a
 zero-count slot past the document's topics), which the W-lane search
 cannot.  ``row_width_share`` reports the mean W / P.
 
+Packed ELL words: ``count << b | topic`` with ``b = topic_bits(K)``.  The
+count field holds counts up to ``count_limit(K)`` (2,097,151 at K=1024),
+which keeps the word non-negative; ``check_count_field`` refuses, on the
+host, a corpus whose longest document exceeds it.
+
 Array layout: per-tile rows are ``(n, 1, t)`` and tables ``(rows, 1, width)``
 so that every block or DMA takes whole trailing dims (the TPU's (8, 128)
 tiling forbids one-row slices of a 2-D array).
@@ -60,6 +67,39 @@ from repro.kernels.lanes import (VMEM_LIMIT_BYTES, dense_draw, gather_lanes,
 
 HEAD = 128  # SMEM header lanes before a tile's doc ids: [word, n_real, 0...]
 LANES = 128
+
+
+def topic_bits(K: int) -> int:
+    """Low bits of a packed ELL word that hold the topic: enough for K - 1."""
+    return max(1, (K - 1).bit_length())
+
+
+def count_limit(K: int) -> int:
+    """The largest count a packed ELL word holds at K topics: the count
+    field's ``31 - topic_bits(K)`` bits, so that the word stays >= 0."""
+    return (1 << (31 - topic_bits(K))) - 1
+
+
+def check_count_field(longest_doc: int, K: int) -> None:
+    """Refuse a corpus whose longest document (the largest count its ELL can
+    hold) does not fit the packed word's count field at K topics."""
+    if longest_doc > count_limit(K):
+        raise ValueError(
+            f"the longest document has {longest_doc} tokens; the sampler "
+            f"kernel packs ELL counts into {31 - topic_bits(K)} bits at "
+            f"K={K}, which hold at most {count_limit(K)}")
+
+
+def pack_ell(counts, topics, K: int):
+    """One int32 word per ELL entry: ``count << topic_bits(K) | topic``."""
+    return (counts << topic_bits(K)) | topics
+
+
+def unpack_ell(words, K: int):
+    """``(counts, topics)`` of packed ELL words, the inverse of ``pack_ell``
+    for counts up to ``count_limit(K)`` and topics below K."""
+    b = topic_bits(K)
+    return words >> b, words & ((1 << b) - 1)
 
 
 def chunk_prefix(win, P: int):
@@ -98,8 +138,7 @@ def chunk_prefix(win, P: int):
 def _kernel(
     head_ref,       # SMEM (1, HEAD + t) int32: [word, n_real, 0.., doc ids]
     pstar_hbm,      # ANY (V, 1, K) float32 — p*(k) of every word
-    ell_cnt_hbm,    # ANY (D, 1, P) int32
-    ell_tpc_hbm,    # ANY (D, 1, P) int32
+    ell_hbm,        # ANY (D, 1, P) int32 — packed (count, topic) words
     u1_ref,         # (1, t) float32 — branch uniform
     u2_ref,         # (1, t) float32 — search uniform
     mask_ref,       # (1, t) int32
@@ -108,8 +147,7 @@ def _kernel(
     sparse_ref,     # out (1, t) int32 — drew from p1?
     ssq_ref,        # out (1, t) float32 — per-token S/(S+Q), 0 on pads
     width_ref,      # out (1, LANES) int32 — each row block's W, 0 if unsampled
-    tok_cnt,        # VMEM (t, 1, P) int32 — each token's ELL counts
-    tok_tpc,        # VMEM (t, 1, P) int32 — ... and topics
+    tok_ell,        # VMEM (t, 1, P) int32 — each token's packed ELL row
     pstar_scr,      # VMEM (1, K) float32 — the tile's p* row
     local_scr,      # VMEM (1, K) float32
     u1_col,         # VMEM (t, 1) float32 — u1 as a column
@@ -117,12 +155,13 @@ def _kernel(
     z_col,          # VMEM (t, 1) int32 — results as columns
     sp_col,         # VMEM (t, 1) int32
     ssq_col,        # VMEM (t, 1) float32
-    sem,            # DMA semaphores (3,)
+    sem,            # DMA semaphores (2,): p*, ELL rows
     *,
     alpha: float,
 ):
     t = z_old_ref.shape[1]
-    P = tok_cnt.shape[2]
+    P = tok_ell.shape[2]
+    K = pstar_scr.shape[1]
     R = row_block(t)
 
     # ---- stage the tile: its p* row and every real token's ELL row ----
@@ -132,20 +171,16 @@ def _kernel(
     # (the row loop).  Regions touch no values, so draws are unchanged.
     n_real = head_ref[0, 1]
 
-    def copies(j, doc):
-        return (pltpu.make_async_copy(ell_cnt_hbm.at[doc], tok_cnt.at[j],
-                                      sem.at[1]),
-                pltpu.make_async_copy(ell_tpc_hbm.at[doc], tok_tpc.at[j],
-                                      sem.at[2]))
+    def copy(j, doc):
+        return pltpu.make_async_copy(ell_hbm.at[doc], tok_ell.at[j],
+                                     sem.at[1])
 
     def issue(j, carry):
-        for c in copies(j, head_ref[0, HEAD + j]):
-            c.start()
+        copy(j, head_ref[0, HEAD + j]).start()
         return carry
 
     def drain(j, carry):
-        for c in copies(0, 0):   # waits count bytes: any same-shape copy
-            c.wait()
+        copy(0, 0).wait()   # waits count bytes: any same-shape copy
         return carry
 
     with jax.named_scope("lda_sample.issue"):
@@ -172,8 +207,8 @@ def _kernel(
 
     def block(r, r0, W):
         sl = pl.ds(r0, R)
-        cnt = table_rows(tok_cnt, r0, R, W).astype(jnp.float32)
-        tpc = table_rows(tok_tpc, r0, R, W)
+        cnt, tpc = unpack_ell(table_rows(tok_ell, r0, R, W), K)
+        cnt = cnt.astype(jnp.float32)
         p1 = cnt * gather_lanes(pstar_scr, tpc)                   # (R, W)
         p1_cum, last = chunk_prefix(
             prefix_sum(p1, roll=pltpu.roll, stop=LANES), P)
@@ -194,7 +229,7 @@ def _kernel(
 
     def rows(r, carry):
         r0 = pl.multiple_of(r * R, R)
-        extent = live_extent(table_rows(tok_cnt, r0, R, P), n_real - r0)
+        extent = live_extent(table_rows(tok_ell, r0, R, P), n_real - r0, K)
         for W in range(LANES, P + 1, LANES):
             fits = extent <= W if W == LANES else (
                 (extent > W - LANES) & (extent <= W))
@@ -223,14 +258,13 @@ def grid_layout(n: int, t: int, K: int, P: int):
         pl.BlockSpec((None, 1, HEAD + t), lambda i: (i, 0, 0),
                      memory_space=pltpu.SMEM),
         hbm,                                              # p* rows
-        hbm, hbm,                                         # ELL counts/topics
+        hbm,                                              # packed ELL rows
         row, row, row, row,                               # u1, u2, mask, z
     ]
     out_specs = [row, row, row,
                  pl.BlockSpec((None, 1, LANES), lambda i: (i, 0, 0))]
     scratch_shapes = [
         pltpu.VMEM((t, 1, P), jnp.int32),
-        pltpu.VMEM((t, 1, P), jnp.int32),
         pltpu.VMEM((1, K), jnp.float32),
         pltpu.VMEM((1, K), jnp.float32),
         pltpu.VMEM((t, 1), jnp.float32),
@@ -238,7 +272,7 @@ def grid_layout(n: int, t: int, K: int, P: int):
         pltpu.VMEM((t, 1), jnp.int32),
         pltpu.VMEM((t, 1), jnp.int32),
         pltpu.VMEM((t, 1), jnp.float32),
-        pltpu.SemaphoreType.DMA((3,)),
+        pltpu.SemaphoreType.DMA((2,)),
     ]
     return (n,), in_specs, out_specs, scratch_shapes
 
@@ -257,15 +291,18 @@ def table_rows(ref, r0, R: int, W: int):
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
-def live_extent(cnt, n_rows):
+def live_extent(words, n_rows, K: int):
     """One past the last non-zero count of the first ``n_rows`` rows of the
-    (R, P) ELL counts ``cnt``: the lanes those rows' documents use (their
-    live-topic count, for a ``top_k`` ELL).  Later rows are ignored."""
-    row = jax.lax.broadcasted_iota(jnp.int32, cnt.shape, 0)
-    # counts are >= 0: a lane is live in some row iff its row max is > 0
-    most = jnp.max(jnp.where(row < n_rows, cnt, 0), axis=0, keepdims=True)
+    (R, P) packed ELL words ``words``: the lanes those rows' documents use
+    (their live-topic count, for a ``top_k`` ELL).  Later rows are
+    ignored."""
+    row = jax.lax.broadcasted_iota(jnp.int32, words.shape, 0)
+    # a padding lane's word may hold a topic but has count 0; words are
+    # >= 0 and order by count, so a lane is live in some row iff the count
+    # of its row max is > 0
+    most = jnp.max(jnp.where(row < n_rows, words, 0), axis=0, keepdims=True)
     lane = jax.lax.broadcasted_iota(jnp.int32, most.shape, 1)
-    return jnp.max(jnp.where(most > 0, lane + 1, 0))
+    return jnp.max(jnp.where(most >> topic_bits(K) > 0, lane + 1, 0))
 
 
 def row_width_share(widths, ell_width: int):
@@ -303,14 +340,16 @@ def lda_sample_tiles(
     ssq), all (n, t), and each row block's width ``(n, t // R)``: the ELL
     lanes it sampled, 0 for a block past the tile's real tokens.
 
-    The ELL width is padded to whole 128-lane vregs: the extra entries have
-    count 0, add exact zeros to every prefix sum and are never drawn."""
+    The counts and topics are packed into one word an entry
+    (``pack_ell``), in the fusion that pads the ELL width to whole 128-lane
+    vregs: the extra entries have count 0, add exact zeros to every prefix
+    sum and are never drawn.  Counts must not exceed ``count_limit(K)``
+    (``check_count_field`` checks the corpus on the host)."""
     n, t = z_old.shape
     V, K = pstar_vk.shape
-    pad = -ell_counts.shape[1] % LANES
-    ell_counts = jnp.pad(ell_counts, ((0, 0), (0, pad)))
-    ell_topics = jnp.pad(ell_topics, ((0, 0), (0, pad)))
-    D, P = ell_counts.shape
+    ell = jnp.pad(pack_ell(ell_counts, ell_topics, K),
+                  ((0, 0), (0, -ell_counts.shape[1] % LANES)))
+    D, P = ell.shape
     nb = t // row_block(t)
     if nb > LANES:
         raise ValueError(f"{nb} row blocks a tile; at most {LANES}")
@@ -334,7 +373,7 @@ def lda_sample_tiles(
         interpret=interpret,
         name="lda_sample",
     )(tile_head(tile_word, token_doc, token_mask),
-      pstar_vk.reshape(V, 1, K), ell_counts.reshape(D, 1, P), ell_topics.reshape(D, 1, P),
+      pstar_vk.reshape(V, 1, K), ell.reshape(D, 1, P),
       rows(u1), rows(u2), rows(token_mask), rows(z_old))
     z_new, sparse, ssq, widths = out
     return (z_new.reshape(n, t), sparse.reshape(n, t), ssq.reshape(n, t),

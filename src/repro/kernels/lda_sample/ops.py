@@ -3,8 +3,9 @@
 Adapts the trainer's data model (per-doc ELL, int16 z, bool masks) to the
 kernel's layout and exposes an ``impl={"pallas","ref"}`` switch.
 
-The wrapper performs **no per-token HBM gather**: the kernel DMAs each
-real token's ELL row from the per-doc tables straight into VMEM, so the
+The wrapper performs **no per-token HBM gather**: the kernel wrapper packs
+the per-doc ELL counts and topics into one (D, P) table of int32 words, and
+the kernel DMAs each real token's packed row straight into VMEM, so the
 ``(n, t, P)`` tensor ``ell_counts[token_doc]`` exists nowhere
 (``tests/test_kernels.py`` pins this by jaxpr shape accounting).
 

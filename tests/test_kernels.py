@@ -252,6 +252,57 @@ def test_row_block_widths():
     assert share == pytest.approx((640 + 640 + 256 + 512) / 4 / P)
 
 
+@pytest.mark.parametrize("K,bits", [(1024, 10), (1000, 10), (2, 1)])
+def test_packed_ell_round_trip(K, bits):
+    """Packed ELL words give back every count and topic at the edges of
+    their fields (count 0 to ``count_limit(K)``, topic 0 to K - 1), and
+    the largest word stays a non-negative int32."""
+    from repro.kernels.lda_sample import kernel
+    top = kernel.count_limit(K)
+    assert kernel.topic_bits(K) == bits and top == (1 << (31 - bits)) - 1
+    counts = np.array([[0, 0, 1, 1, top, top, top - 1, 7]], np.int32)
+    topics = np.array([[0, K - 1, 0, K - 1, 0, K - 1, K // 2, 1]], np.int32)
+    for xp in (np, jnp):
+        words = kernel.pack_ell(xp.asarray(counts), xp.asarray(topics), K)
+        assert words.dtype == np.int32 and int(words.min()) >= 0
+        c, k = kernel.unpack_ell(words, K)
+        np.testing.assert_array_equal(np.asarray(c), counts)
+        np.testing.assert_array_equal(np.asarray(k), topics)
+
+
+@pytest.mark.parametrize("over", [0, 1])
+@pytest.mark.parametrize("where", ["resolve_config", "shard"])
+def test_count_field_guard(where, over):
+    """The host refuses, under the Pallas sampler, a corpus whose longest
+    document exceeds the packed count field, and accepts one exactly at
+    it; the ``sq`` sampler, which packs nothing, accepts both."""
+    import dataclasses
+
+    from repro.core import trainer
+    from repro.core.corpus import Corpus
+    from repro.kernels.lda_sample import kernel
+    K = 1024
+    longest = kernel.count_limit(K) + over
+    cfg = trainer.LDAConfig(num_topics=K, sampler="pallas", tile_tokens=32)
+    if where == "resolve_config":
+        corpus = Corpus(np.r_[np.zeros(longest, np.int32), 1, 1],
+                        np.zeros(longest + 2, np.int32), 2, 1)
+        check = lambda c: trainer.resolve_config(c, corpus)  # noqa: E731
+    else:
+        corpus, shard, z, *_ = setup_case(K, 32)
+        lengths = np.asarray(shard.doc_length).copy()
+        lengths[1] = longest
+        shard = dataclasses.replace(shard, doc_length=lengths)
+        check = lambda c: trainer._build_theta_ell(  # noqa: E731
+            c, shard, z, None)
+    if over:
+        with pytest.raises(ValueError, match=str(kernel.count_limit(K))):
+            check(cfg)
+    else:
+        check(cfg)
+    check(dataclasses.replace(cfg, sampler="sq"))
+
+
 def _collect_shapes(jaxpr, acc):
     """Every intermediate's shape, recursing into nested jaxprs (pjit,
     scan, cond, pallas_call kernels, ...), and the shapes of the nested
