@@ -2,9 +2,8 @@
 
 The device never waits on the host: batches are produced by a worker thread
 into a small queue and transferred while the previous step computes (the
-WorkSchedule2 overlap idea — C2 — applied to input data).  Used by the LM
-training path; the LDA corpus is static (resident, WorkSchedule1) so it
-needs no loader.
+WorkSchedule2 overlap idea — C2 — applied to input data).  The LDA corpus
+is static today (resident, WorkSchedule1), so no training path uses it yet.
 """
 from __future__ import annotations
 
@@ -13,7 +12,6 @@ import threading
 from typing import Callable, Iterator
 
 import jax
-import numpy as np
 
 
 class PrefetchLoader:
@@ -58,20 +56,3 @@ class PrefetchLoader:
         except queue.Empty:
             pass
 
-
-def lm_batches(vocab: int, batch: int, seq: int, seed: int = 0,
-               table_size: int = 4096):
-    """Deterministic synthetic LM stream (Zipf-initialised bigram table —
-    learnable structure so loss curves mean something)."""
-    rng = np.random.default_rng(seed)
-    table = rng.integers(0, vocab, size=(table_size,))
-
-    def make(i: int) -> dict:
-        r = np.random.default_rng(seed * 1_000_003 + i)
-        toks = [r.integers(0, vocab, size=(batch, 1))]
-        for _ in range(seq):
-            toks.append(table[toks[-1] % table_size])
-        seq_arr = np.concatenate(toks, axis=1).astype(np.int32)
-        return {"tokens": seq_arr[:, :-1], "labels": seq_arr[:, 1:]}
-
-    return make

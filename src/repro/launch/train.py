@@ -1,19 +1,15 @@
-"""Production training launcher.
+"""Production training launcher for the paper's system.
 
-Two workload kinds share one launcher:
-  * ``--workload lda``  — the paper's system: CGS-LDA on the 1D (paper) or
-    2D (beyond-paper) partition with per-iteration phi sync, checkpointing
-    every N iterations, automatic resume, elastic restore onto whatever mesh
-    this process was launched with.
-  * ``--workload lm --arch <id>`` — transformer pretraining on the same mesh
-    machinery (FSDP x TP x SP), synthetic data pipeline.
+CGS-LDA on the 1D (paper) or 2D (beyond-paper) partition with per-iteration
+phi sync, checkpointing every N iterations, automatic resume, and elastic
+restore onto whatever mesh this process was launched with.
 
 On a real pod each host runs this same script (jax.distributed.initialize
 discovers peers from the TPU environment); on CPU use --host-devices N to
 simulate.  Fault tolerance: any host death kills the SPMD step; the job
 scheduler restarts the binary, which resumes from the newest complete
-checkpoint — state is tiny (z assignments for LDA, standard params/opt for
-LM) and partition-independent, so restarts may change the device count.
+checkpoint — the state is the z assignments, which are small and
+partition-independent, so restarts may change the device count.
 """
 from __future__ import annotations
 
@@ -24,8 +20,6 @@ import sys
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--workload", choices=["lda", "lm"], default="lda")
-    ap.add_argument("--arch", default=None)
     ap.add_argument("--mode", choices=["1d", "2d"], default="1d")
     ap.add_argument("--sampler", choices=["sq", "dense", "pallas"],
                     default="sq",
@@ -69,10 +63,7 @@ def main():
     if args.distributed:
         jax.distributed.initialize()
 
-    if args.workload == "lda":
-        run_lda(args)
-    else:
-        run_lm(args)
+    run_lda(args)
 
 
 def run_lda(args):
@@ -117,43 +108,6 @@ def run_lda(args):
         tps = sorted(res.tokens_per_sec)[len(res.tokens_per_sec) // 2]
         print(f"[done] compile {res.compile_sec:.1f}s  "
               f"median {tps / 1e6:.3f}M tok/s")
-
-
-def run_lm(args):
-    import jax
-    import jax.numpy as jnp
-    from repro.configs.archs import ARCHS, smoke
-    from repro.launch.mesh import make_mesh
-    from repro.launch.specs import make_policy
-    from repro.models import transformer as tf, zoo
-    from repro.optim import adamw
-
-    assert args.arch, "--arch required for lm workload"
-    n_dev = len(jax.devices())
-    cfg = smoke(args.arch) if n_dev < 16 else ARCHS[args.arch]
-    mesh = make_mesh((max(1, n_dev // 2), min(n_dev, 2)), ("data", "model"))
-    policy = make_policy(mesh, batch=8)
-    key = jax.random.key(0)
-    params = tf.init_params(key, cfg)
-    state = zoo.TrainState(params, adamw.init(params))
-    step = jax.jit(zoo.make_train_step(cfg, policy))
-    B, S = 8, 128
-    for i in range(args.iters):
-        # one child key per modality: consuming the same k for tokens,
-        # frames and patches would correlate the three synthetic streams
-        k_tok, k_frames, k_patch = jax.random.split(
-            jax.random.fold_in(key, i), 3)
-        toks = jax.random.randint(k_tok, (B, S + 1), 0, cfg.vocab_size)
-        batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
-        if cfg.encoder_layers:
-            batch["frames"] = jax.random.normal(
-                k_frames, (B, cfg.encoder_frames, cfg.d_model), jnp.bfloat16)
-        if cfg.vision_tokens:
-            batch["patches"] = jax.random.normal(
-                k_patch, (B, cfg.vision_tokens, cfg.d_model), jnp.bfloat16)
-        state, m = step(state, batch)
-        if (i + 1) % 10 == 0:
-            print(f"step {i + 1}: loss {float(m['loss']):.4f}")
 
 
 if __name__ == "__main__":

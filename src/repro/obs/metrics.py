@@ -4,9 +4,7 @@ Design constraints (this is the hot-path substrate every perf PR is judged
 against, so it must not perturb what it measures):
 
   * lock-cheap — each metric owns one uncontended ``threading.Lock`` taken
-    only around a couple of float ops (~100 ns per ``inc``/``observe``; the
-    observer-effect benchmark in ``benchmarks/serving.py`` pins the total
-    under 2% of the serving hot path);
+    only around a couple of float ops (~100 ns per ``inc``/``observe``);
   * bounded memory — histograms hold a fixed bucket vector plus a bounded
     sample window (for exact p50/p99; the fixed buckets feed the Prometheus
     exposition), ``WindowRate`` holds a bounded timestamp deque.  Nothing

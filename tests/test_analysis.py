@@ -717,14 +717,3 @@ class TestPrngFixRegressions:
         cfg = smoke("gemma2-27b")
         cache = init_cache(cfg, batch=1, max_len=8, key=jax.random.key(0))
         assert not np.array_equal(np.asarray(cache.k), np.asarray(cache.v))
-
-    def test_lm_modality_streams_decorrelated(self):
-        # found by prng-discipline: tokens/frames/patches consumed one key
-        import jax
-
-        key = jax.random.key(0)
-        k_tok, k_frames, k_patch = jax.random.split(
-            jax.random.fold_in(key, 0), 3)
-        a = jax.random.normal(k_frames, (8,))
-        b = jax.random.normal(k_patch, (8,))
-        assert not np.array_equal(np.asarray(a), np.asarray(b))

@@ -151,22 +151,28 @@ def expected_widths(token_doc, token_mask, ell_counts, R=32):
     return np.where(real, np.maximum(-(-most // 128), 1) * 128, 0)
 
 
-@pytest.mark.parametrize("K,classes,n_widths", [
+@pytest.mark.parametrize("K,tile_tokens,classes,P,n_widths", [
     # live extents about 20, 170 and 430 of P=640: widths 128, 256, 512
-    (640, [(8, 20), (4, 200), (2, 700)], 3),
+    (640, 64, [(8, 20), (4, 200), (2, 700)], 640, 3),
     # about 30, 290 and 570 of P=896: widths 128, 384, 640
-    (896, [(6, 30), (3, 350), (2, 900)], 3),
+    (896, 64, [(6, 30), (3, 350), (2, 900)], 896, 3),
     # every document reaches P=384: every block full width
-    (384, [(3, 1500)], 1),
+    (384, 64, [(3, 1500)], 384, 1),
+    # the benchmark's K and tiles, NYTimes' ELL width: live extents about
+    # 65, 190, 310, 440, 570, 690, 810 and 960 reach all eight widths
+    (1024, 256, [(1, 66), (1, 212), (1, 384), (1, 591), (1, 846),
+                 (1, 1190), (1, 1714), (1, 2840)], 1024, 8),
+    # PubMed's ELL width: the longest document (809) makes P=896
+    (1024, 256, [(6, 30), (3, 350), (1, 809)], 896, 3),
 ])
-def test_lda_sample_heavy_tail_bit_exact(K, classes, n_widths):
-    """Row blocks of several widths, over an ELL width that no width
-    divides: draws and stats equal ``impl="ref"`` and ``sample_sweep``."""
+def test_lda_sample_heavy_tail_bit_exact(K, tile_tokens, classes, P,
+                                         n_widths):
+    """Row blocks of several widths, over ELL widths below K and at it:
+    draws and stats equal ``impl="ref"`` and ``sample_sweep``."""
     from repro.core import sampler as core
     corpus, shard, z, phi, phi_sum, cnts, tpcs, key = heavy_tail_case(
-        K, classes)
-    P = cnts.shape[1]
-    assert P % 128 == 0 and P & (P - 1) != 0
+        K, classes, tile_tokens)
+    assert cnts.shape[1] == P
     widths = expected_widths(shard.token_doc, shard.token_mask, cnts)
     assert len(np.unique(widths[widths > 0])) == n_widths
     n, t = z.shape
@@ -349,8 +355,8 @@ def test_no_hbm_ell_gather():
     assert any(s == (t, 1, P_lanes) for s in shapes)
 
 
-@pytest.mark.parametrize("K", [128, 256])
-@pytest.mark.parametrize("tile_tokens", [16, 64])
+@pytest.mark.parametrize("tile_tokens,K", [
+    (16, 128), (16, 256), (64, 128), (64, 256), (256, 1024)])
 def test_phi_update_kernel_matches_ref(K, tile_tokens):
     corpus, shard, z, phi, phi_sum, cnts, tpcs, key = setup_case(K, tile_tokens)
     dk = phi_ops.phi_update(shard.tile_word, shard.tile_first, z,
@@ -365,10 +371,10 @@ def test_phi_update_kernel_matches_ref(K, tile_tokens):
     assert int(dk.sum()) == corpus.num_tokens
 
 
-@pytest.mark.parametrize("K", [128, 192])
-def test_phi_delta_kernel_matches_ref(K):
+@pytest.mark.parametrize("K,tile_tokens", [(128, 16), (192, 16), (1024, 256)])
+def test_phi_delta_kernel_matches_ref(K, tile_tokens):
     """Incremental MXU update == signed scatter oracle == rebuild diff."""
-    corpus, shard, z, phi, phi_sum, cnts, tpcs, key = setup_case(K, 16)
+    corpus, shard, z, phi, phi_sum, cnts, tpcs, key = setup_case(K, tile_tokens)
     n, t = z.shape
     z_new = jax.random.randint(jax.random.key(9), (n, t), 0, K,
                                jnp.int32).astype(z.dtype)

@@ -12,7 +12,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def run_train(tmp, iters, extra=()):
     env = dict(os.environ, PYTHONPATH=SRC,
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    cmd = [sys.executable, "-m", "repro.launch.train", "--workload", "lda",
+    cmd = [sys.executable, "-m", "repro.launch.train",
            "--iters", str(iters), "--topics", "16", "--scale", "0.0001",
            "--ckpt-dir", os.path.join(tmp, "ck"), "--ckpt-every", "5",
            *extra]
